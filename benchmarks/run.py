@@ -22,8 +22,11 @@ import json
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
+    use_compile_cache()
     from . import (
         bench_apps,
         bench_apps_serving,

@@ -120,6 +120,7 @@ from .program import (
     VMEM_BUDGET_DEFAULT,
     curve_partition,
     fits_vmem,
+    fused_fits,
     get_vmem_budget,
     set_vmem_budget,
 )

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import warnings
 from typing import Any, Callable, Mapping
 
 import numpy as np
@@ -37,6 +38,7 @@ __all__ = [
     "VMEM_BUDGET_DEFAULT",
     "curve_partition",
     "fits_vmem",
+    "fused_fits",
     "get_vmem_budget",
     "set_vmem_budget",
 ]
@@ -173,25 +175,37 @@ class CurveProgram:
                 f"({len(self.in_specs)}), got {len(operands)}"
             )
         total = 0
-        for spec, op in zip(self.in_specs, operands):
-            shape = tuple(
-                int(b) if b is not None else int(s)
-                for b, s in zip(spec.block_shape, op.shape)
-            )
-            total += 2 * int(np.prod(shape)) * np.dtype(op.dtype).itemsize
-        for spec, out in self._out_items():
-            shape = tuple(
-                int(b) if b is not None else int(s)
-                for b, s in zip(spec.block_shape, out.shape)
-            )
-            total += 2 * int(np.prod(shape)) * np.dtype(out.dtype).itemsize
+        items = list(zip(self.in_specs, operands)) + self._out_items()
+        for spec, arr in items:
+            total += 2 * _block_bytes(spec, arr)
         for sc in self.scratch_shapes:
             shape = getattr(sc, "shape", None)
             dtype = getattr(sc, "dtype", None)
-            if shape is None or dtype is None:  # e.g. semaphores
+            try:
+                itemsize = np.dtype(dtype).itemsize
+            except TypeError:  # semaphores
                 continue
-            total += int(np.prod(shape)) * np.dtype(dtype).itemsize
+            if shape is None:
+                continue
+            total += int(np.prod(shape)) * itemsize
         return total
+
+
+def _block_bytes(spec, arr) -> int:
+    """Bytes of one VMEM buffer of ``spec``'s block over ``arr``.  A spec
+    without a block shape covers the whole operand; if it also names a
+    memory space other than VMEM (``pl.ANY`` for HBM-resident operands
+    moved by explicit DMA, SMEM for scalars) it takes no VMEM."""
+    block = spec.block_shape
+    if block is None:
+        space = getattr(spec, "memory_space", None)
+        if space is not None and "VMEM" not in str(space).upper():
+            return 0
+        block = (None,) * len(arr.shape)
+    shape = tuple(
+        int(b) if b is not None else int(s) for b, s in zip(block, arr.shape)
+    )
+    return int(np.prod(shape)) * np.dtype(arr.dtype).itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -248,6 +262,22 @@ def fits_vmem(program: CurveProgram, *operands) -> bool:
     §Execution-layer."""
     budget = get_vmem_budget()
     return budget is None or program.vmem_bytes(*operands) <= budget
+
+
+def fused_fits(app: str, program: CurveProgram, *operands) -> bool:
+    """:func:`fits_vmem`, loud: when the fused form is past the budget it
+    warns (``RuntimeWarning``) before the caller routes ``app`` to the
+    reference path, so a run never leaves the fused kernel unseen."""
+    if fits_vmem(program, *operands):
+        return True
+    warnings.warn(
+        f"{app}: the fused kernel's VMEM residency "
+        f"({program.vmem_bytes(*operands)} B) exceeds the budget "
+        f"({get_vmem_budget()} B); running the multi-dispatch reference",
+        RuntimeWarning,
+        stacklevel=3,
+    )
+    return False
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import register_schedule_cache
 
-from .pallas_compat import CompilerParams
+from .launch import flat_kernel, flat_table_spec, vmem_limit
 
 DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
@@ -196,7 +196,7 @@ def flash_attention_swizzled(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -275,6 +275,19 @@ def decode_page_schedule_device(
     )
 
 
+def _flat_grid_spec(cols, *, grid, in_specs, out_specs, scratch_shapes):
+    """Grid spec for the paged kernels: three scalar-prefetch operands
+    (schedule, page table, positions), the schedule flattened to 1-D so
+    SMEM does not pad each row to 128 words (see kernels/launch.py)."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=grid,
+        in_specs=[flat_table_spec(sp, cols, len(grid)) for sp in in_specs],
+        out_specs=flat_table_spec(out_specs, cols, len(grid)),
+        scratch_shapes=scratch_shapes,
+    )
+
+
 def _flash_decode_kernel(
     sched_ref,
     pt_ref,
@@ -303,8 +316,8 @@ def _flash_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)  # (g, Dk)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # (ps, Dk)
-    v = v_ref[0, :, 0].astype(jnp.float32)  # (ps, Dv)
+    k = k_ref[0, 0].astype(jnp.float32)  # (ps, Dk)
+    v = v_ref[0, 0].astype(jnp.float32)  # (ps, Dv)
 
     scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
 
@@ -351,7 +364,10 @@ def flash_attention_decode(
     q: (B, Hkv, g, Dk) — the B slots' single-token queries, grouped GQA
     layout (g = H // Hkv query heads share each KV head; MLA passes
     Hkv=1, g=H and its concatenated latent ⊕ rope width as Dk).
-    k_pages/v_pages: (P, page_size, Hkv, Dk/Dv) physical page pools.
+    k_pages/v_pages: (P, Hkv, page_size, Dk/Dv) physical page pools —
+    head-major inside a page, so one (page, head) block is a contiguous
+    (page_size, D) tile whose last two dims are the array's own (the
+    TPU block-tiling rule).
     page_table: int32[B, max_pages] logical→physical page map (dynamic —
     scalar-prefetched, so allocation churn never recompiles).
     pos: int32[B] per-slot positions; the entry at pos is live, later
@@ -363,26 +379,26 @@ def flash_attention_decode(
     (B, Hkv, g, Dv).
     """
     B, Hkv, g, Dk = q.shape
-    P, ps, Hkv_k, Dk_k = k_pages.shape
+    P, Hkv_k, ps, Dk_k = k_pages.shape
     Dv = v_pages.shape[-1]
     assert (Hkv_k, Dk_k) == (Hkv, Dk), (k_pages.shape, q.shape)
-    assert v_pages.shape[:3] == (P, ps, Hkv), v_pages.shape
+    assert v_pages.shape[:3] == (P, Hkv, ps), v_pages.shape
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(Dk))
-    steps = schedule.shape[0]
+    steps, cols = schedule.shape
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+    grid_spec = _flat_grid_spec(
+        cols,
         grid=(Hkv, steps),
         in_specs=[
             pl.BlockSpec((1, 1, g, Dk), lambda h, s, sr, pt, pv: (sr[s, 0], h, 0, 0)),
             pl.BlockSpec(
-                (1, ps, 1, Dk),
-                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 1]], 0, h, 0),
+                (1, 1, ps, Dk),
+                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 1]], h, 0, 0),
             ),
             pl.BlockSpec(
-                (1, ps, 1, Dv),
-                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 1]], 0, h, 0),
+                (1, 1, ps, Dv),
+                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 1]], h, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -395,15 +411,19 @@ def flash_attention_decode(
         ],
     )
     return pl.pallas_call(
-        functools.partial(_flash_decode_kernel, sm_scale=sm_scale, page_size=ps),
+        flat_kernel(
+            functools.partial(_flash_decode_kernel, sm_scale=sm_scale, page_size=ps),
+            cols,
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, Dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode",
     )(
-        schedule,
+        schedule.reshape(-1),
         jnp.asarray(page_table, dtype=jnp.int32),
         jnp.asarray(pos, dtype=jnp.int32),
         q,
@@ -435,7 +455,12 @@ def prefill_page_schedule(
     contribute nothing.  Steps are padded to the next power of two with
     ``valid=0`` rows the kernel skips, so same-bucket cohorts share one
     compiled program (the schedule itself is a dynamic scalar-prefetch
-    operand).
+    operand).  A pad row repeats the last real row's (slot, q_tile,
+    page) with every flag 0: its output block index is the last one
+    written, so the pipeline's final write-back carries that block's
+    finished values — a pad row pointing anywhere else would write back
+    a VMEM buffer the kernel never filled (the TPU pipeline does not
+    re-fetch output blocks).
     """
     bq = page_size if bq is None else bq
     rows = []
@@ -458,9 +483,9 @@ def prefill_page_schedule(
     steps = out.shape[0]
     bucket = 1 << max(steps - 1, 0).bit_length()
     if bucket != steps:
-        out = np.concatenate(
-            [out, np.zeros((bucket - steps, 6), dtype=np.int32)], axis=0
-        )
+        pad = np.zeros((bucket - steps, 6), dtype=np.int32)
+        pad[:, :3] = out[-1, :3]
+        out = np.concatenate([out, pad], axis=0)
     return out
 
 
@@ -529,8 +554,8 @@ def _flash_prefill_kernel(
         # (bq, g, Dk) -> (bq*g, Dk): row r is query token r // g, head
         # r % g — a plain 2-D matmul the MXU can take directly
         q = q_ref[0, :, 0].astype(jnp.float32).reshape(bq * g, -1)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (ps, Dk)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (ps, Dv)
+        k = k_ref[0, 0].astype(jnp.float32)  # (ps, Dk)
+        v = v_ref[0, 0].astype(jnp.float32)  # (ps, Dv)
 
         scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
 
@@ -585,24 +610,25 @@ def flash_attention_prefill(
     ``pos0[slot] + i``; rows at i >= the slot's new-token count are
     padding whose output is undefined-but-finite).  Tq must be a
     multiple of the page size (q tiles align to kv pages).
-    k_pages/v_pages: physical pools with the cohort's new K/V already
+    k_pages/v_pages: (P, Hkv, page_size, D) physical pools (the decode
+    kernel's layout) with the cohort's new K/V already
     scattered through the page table (split-phase: XLA scatter first,
     then this kernel gathers — no write-then-read hazard inside the
     pipeline).  schedule: :func:`prefill_page_schedule`, a dynamic
     scalar-prefetch operand.  Returns (B, Tq, Hkv, g, Dv).
     """
     B, Tq, Hkv, g, Dk = q.shape
-    P, ps, Hkv_k, Dk_k = k_pages.shape
+    P, Hkv_k, ps, Dk_k = k_pages.shape
     Dv = v_pages.shape[-1]
     assert (Hkv_k, Dk_k) == (Hkv, Dk), (k_pages.shape, q.shape)
     assert Tq % ps == 0, (Tq, ps)
     bq = ps
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(Dk))
-    steps = schedule.shape[0]
+    steps, cols = schedule.shape
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+    grid_spec = _flat_grid_spec(
+        cols,
         grid=(Hkv, steps),
         in_specs=[
             pl.BlockSpec(
@@ -610,12 +636,12 @@ def flash_attention_prefill(
                 lambda h, s, sr, pt, pv: (sr[s, 0], sr[s, 1], h, 0, 0),
             ),
             pl.BlockSpec(
-                (1, ps, 1, Dk),
-                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 2]], 0, h, 0),
+                (1, 1, ps, Dk),
+                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 2]], h, 0, 0),
             ),
             pl.BlockSpec(
-                (1, ps, 1, Dv),
-                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 2]], 0, h, 0),
+                (1, 1, ps, Dv),
+                lambda h, s, sr, pt, pv: (pt[sr[s, 0], sr[s, 2]], h, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -629,21 +655,31 @@ def flash_attention_prefill(
         ],
     )
     return pl.pallas_call(
-        functools.partial(
-            _flash_prefill_kernel,
-            sm_scale=sm_scale,
-            page_size=ps,
-            bq=bq,
-            g=g,
+        flat_kernel(
+            functools.partial(
+                _flash_prefill_kernel,
+                sm_scale=sm_scale,
+                page_size=ps,
+                bq=bq,
+                g=g,
+            ),
+            cols,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Tq, Hkv, g, Dv), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(
+                # double-buffered q / out / page blocks + f32 softmax state
+                2 * bq * g * (Dk * q.dtype.itemsize + Dv * q.dtype.itemsize)
+                + 2 * ps * (Dk + Dv) * k_pages.dtype.itemsize
+                + 4 * bq * g * (Dv + 2 * 128 + 2 * ps)
+            ),
         ),
         interpret=interpret,
+        name="flash_prefill",
     )(
-        schedule,
+        schedule.reshape(-1),
         jnp.asarray(page_table, dtype=jnp.int32),
         jnp.asarray(pos0, dtype=jnp.int32),
         q,

@@ -18,8 +18,10 @@ finished L_*k panel across grid steps in VMEM scratch (``b*b + b*n``
 f32).  Phase (3), the O(n³) hot spot, consumes the panel in FGF-Hilbert
 *triangle* order (jump-over, §6.2): only lower-triangular trailing
 tiles are enumerated and one of the two L panels is VMEM-resident at
-every step.  All matrix reads go through the aliased output ref (the
-interpret-exact RMW form; DESIGN.md §Phase-fusion).
+every step.  The matrix stays in HBM (``pl.ANY``, aliased in place) and
+each step moves its tile with waited DMAs — tiles are revisited once
+per k-block, and the TPU pipeline never re-fetches a revisited output
+block (DESIGN.md §Phase-fusion).
 
 :func:`cholesky_blocked_reference` retains the per-k host loop — one
 diag + panel + trailing ``pallas_call`` per k-block — as the bit-exact
@@ -39,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 from repro.core import (
     CHOLESKY_PHASES,
     as_choice,
@@ -49,27 +49,37 @@ from repro.core import (
 )
 from repro.core.program import CurveProgram
 
-from .launch import launch
+from .launch import launch, sync_copy
+from .launch import tile_ref as tile_ref_of
 from .matmul import tile_update_swizzled
+
+
+def _pick(v, mask, axis):
+    """The one element of ``v`` on ``mask`` along ``axis`` (exact: every
+    other term of the sum is zero) — dynamic indexing of a value by
+    mask, which Mosaic lowers where ``dynamic_slice`` has no lowering."""
+    return jnp.sum(jnp.where(mask, v, 0.0), axis=axis, keepdims=True)
 
 
 def _chol_tile(a):
     """Right-looking Cholesky of one (b, b) SPD f32 tile.
 
-    Textbook column loop with masked rank-1 trailing updates (static
-    shapes, so the same code runs on host and inside the Pallas kernel).
-    Upper triangle comes back zeroed — ``jnp.linalg.cholesky``'s layout.
+    Textbook column loop with masked rank-1 trailing updates; column
+    ``t`` is picked by mask, so the same code runs on host and inside
+    the Pallas kernel.  Only the lower triangle is read; the upper
+    triangle comes back zeroed — ``jnp.linalg.cholesky``'s layout.
     """
     b = a.shape[0]
-    idx = jnp.arange(b)
+    ri = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
 
     def body(t, a):
-        d = jnp.sqrt(jax.lax.dynamic_slice(a, (t, t), (1, 1))[0, 0])
-        col = jax.lax.dynamic_slice(a, (0, t), (b, 1))[:, 0] / d
-        below = jnp.where(idx > t, col, 0.0)
-        a = a - below[:, None] * below[None, :]
-        newcol = jnp.where(idx > t, col, jnp.where(idx == t, d, 0.0))
-        return jax.lax.dynamic_update_slice(a, newcol[:, None], (0, t))
+        col = _pick(a, ci == t, 1)  # (b, 1)
+        d = jnp.sqrt(_pick(col, ri[:, :1] == t, 0))  # (1, 1)
+        below = jnp.where(ri[:, :1] > t, col / d, 0.0)
+        below_row = _pick(below, ri == ci, 0)  # the same vector as a row
+        a = a - below * below_row
+        return jnp.where(ci == t, jnp.where(ri[:, :1] == t, d, below), a)
 
     return jax.lax.fori_loop(0, b, body, a)
 
@@ -81,16 +91,16 @@ def _solve_tile(l, a):
     column loop matches the dependency order L imposes.
     """
     bm, b = a.shape
-    idx = jnp.arange(b)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (bm, b), 1)
+    lr = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+    lc = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
 
     def body(t, x):
-        lrow = jnp.where(
-            idx < t, jax.lax.dynamic_slice(l, (t, 0), (1, b))[0], 0.0
-        )
-        ltt = jax.lax.dynamic_slice(l, (t, t), (1, 1))[0, 0]
-        at = jax.lax.dynamic_slice(a, (0, t), (bm, 1))[:, 0]
-        xt = (at - x @ lrow) / ltt
-        return jax.lax.dynamic_update_slice(x, xt[:, None], (0, t))
+        lrow = _pick(l, lr == t, 0)  # (1, b)
+        ltt = _pick(lrow, lc == t, 1)  # (1, 1)
+        dot = jnp.sum(x * jnp.where(lc < t, lrow, 0.0), axis=1, keepdims=True)
+        xt = (_pick(a, ci == t, 1) - dot) / ltt
+        return jnp.where(ci == t, xt, x)
 
     return jax.lax.fori_loop(0, b, body, jnp.zeros_like(a))
 
@@ -105,50 +115,54 @@ def _panel_kernel(diag_ref, p_in, p_out):
     ).astype(p_out.dtype)
 
 
-def _fused_chol_kernel(sched_ref, a_in_ref, o_ref, diag_ref, panel_ref, *, b):
+def _fused_chol_kernel(
+    sched_ref, a_in_ref, o_ref, tile_ref, diag_ref, panel_ref, sem, *, b
+):
     """One phased-schedule step: branch on the prefetched phase id.
 
-    Same RMW discipline as the fused FW kernel: every matrix access goes
-    through the aliased output ref; L_kk and the finished L_*k panel
-    live in VMEM scratch between steps.
+    Same RMW discipline as the fused FW kernel: the matrix stays in HBM
+    and the step's tile moves by waited DMA; L_kk and the finished L_*k
+    panel live in VMEM scratch between steps.
     """
-    del a_in_ref  # aliased donor; all RMW goes through o_ref
+    del a_in_ref  # aliased donor: o_ref is the same HBM buffer
     s = pl.program_id(0)
     phase = sched_ref[s, 0]
     i = sched_ref[s, 2]
     j = sched_ref[s, 3]
+    blk = tile_ref_of(o_ref, i, j, b, b)
+    sync_copy(blk, tile_ref, sem)
 
     @pl.when(phase == 0)
     def _diag():
-        l = _chol_tile(o_ref[...].astype(jnp.float32))
-        o_ref[...] = l.astype(o_ref.dtype)
+        l = _chol_tile(tile_ref[...])
+        tile_ref[...] = l
         diag_ref[...] = l
 
     @pl.when(phase == 1)
     def _panel():
-        x = _solve_tile(diag_ref[...], o_ref[...].astype(jnp.float32))
-        o_ref[...] = x.astype(o_ref.dtype)
-        panel_ref[pl.ds(i * b, b), :] = x
+        x = _solve_tile(diag_ref[...], tile_ref[...])
+        tile_ref[...] = x
+        panel_ref[pl.ds(pl.multiple_of(i * b, b), b), :] = x
 
     @pl.when(phase == 2)
     def _trailing():
-        lik = panel_ref[pl.ds(i * b, b), :]
-        ljk = panel_ref[pl.ds(j * b, b), :]
+        lik = panel_ref[pl.ds(pl.multiple_of(i * b, b), b), :]
+        ljk = panel_ref[pl.ds(pl.multiple_of(j * b, b), b), :]
         # same expression as matmul._accum_update_kernel (alpha = -1)
-        o_ref[...] = (
-            o_ref[...]
-            + (-1.0)
-            * jnp.dot(lik, ljk.T, preferred_element_type=jnp.float32).astype(
-                o_ref.dtype
-            )
+        tile_ref[...] = tile_ref[...] + (-1.0) * jax.lax.dot_general(
+            lik, ljk, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
+
+    sync_copy(tile_ref, blk, sem)
 
 
 def cholesky_program(choice, nt: int, b: int) -> CurveProgram:
     """The fused-Cholesky declaration: L_kk plus the finished L_*k panel
-    carried in VMEM scratch (``b·b + b·n`` f32 — the residency the ops
-    wrapper gates on), every matrix access through the aliased output
-    ref, trailing SYRK tiles in FGF-Hilbert triangle order.
+    carried in VMEM scratch (``2·b·b + b·n`` f32 — the residency the
+    ops wrapper gates on), the matrix in HBM moved one tile per step by
+    waited DMA, trailing SYRK tiles in FGF-Hilbert triangle order.
 
     ``choice`` is a curve name or a ``phased:cholesky``
     :class:`repro.core.ScheduleChoice`; the normalised choice and grid
@@ -161,12 +175,14 @@ def cholesky_program(choice, nt: int, b: int) -> CurveProgram:
         name=f"cholesky_fused_{curve}",
         schedule=phased_schedule_device(curve, nt, kind="cholesky"),
         kernel=functools.partial(_fused_chol_kernel, b=b),
-        in_specs=(pl.BlockSpec((b, b), lambda s, sr: (sr[s, 2], sr[s, 3])),),
-        out_specs=pl.BlockSpec((b, b), lambda s, sr: (sr[s, 2], sr[s, 3])),
+        in_specs=(pl.BlockSpec(memory_space=pl.ANY),),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         scratch_shapes=(
+            pltpu.VMEM((b, b), jnp.float32),   # the step's (i, j) tile
             pltpu.VMEM((b, b), jnp.float32),   # L_kk
             pltpu.VMEM((n, b), jnp.float32),   # L_*k panel (absolute tiles)
+            pltpu.SemaphoreType.DMA(()),
         ),
         input_output_aliases={1: 0},
         phases=CHOLESKY_PHASES,
@@ -211,7 +227,7 @@ def cholesky_blocked_reference(
     assert a.shape == (n, n) and n % b == 0
     nt = n // b
     a = a.astype(jnp.float32)
-    params = CompilerParams(dimension_semantics=("arbitrary",))
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
     for kb in range(nt):
         spec_kk = pl.BlockSpec((b, b), lambda *_: (kb, kb))  # noqa: B023

@@ -20,23 +20,25 @@ phases of all k-blocks — into a single ``pallas_call``: the
 per grid step, the kernel predicates on the prefetched phase id
 (``pl.when``), and the closed diagonal / row / column panels are carried
 across steps in VMEM scratch (``b*b + 2*b*n`` f32 — the VMEM bound of
-the fused form).  Every read-modify-write goes through the aliased
-output ref, which interpret mode re-fetches on revisit (the
-``matmul_swizzled_3d`` idiom; see DESIGN.md §Phase-fusion for the
-phase-barrier revisit-gap analysis and the hardware caveat).
+the fused form).  The matrix itself stays in HBM (``pl.ANY``, aliased
+in place): every step reads its tile with a waited DMA and writes it
+back the same way, because each tile is revisited once per k-block and
+the TPU pipeline never re-fetches a revisited output block (see
+kernels/launch.py and DESIGN.md §Phase-fusion).
 
 :func:`floyd_warshall_blocked_reference` retains the per-k host loop
-(one diag + row + col + trailing ``pallas_call`` per k-block, O(nt)
-trace/compile/dispatch overheads) as the bit-exact oracle the fused
-kernel is validated against — both paths run the same tile math
+(one diag + row + col + trailing ``pallas_call`` per k-block, every
+block visited once per call) as the bit-exact oracle the fused kernel is
+validated against — both paths run the same tile math
 (:func:`_fw_closure`, :func:`_minplus`) on the same values in the same
-order, so interpret-mode f32 results are identical to the last bit.
+order, so their f32 results are identical to the last bit.
 
 All tiles of phase (3) are visited exactly once per k
-(``phased_schedule`` asserts order-freeness per phase), so the in-place
-(aliased) min-update is hazard-free.  Min-plus products run on the VPU
-(no MXU analogue for (min,+)); the chunked fori_loop bounds the broadcast
-working set to b×8×b f32 in VMEM.
+(``phased_schedule`` asserts order-freeness per phase).  Min-plus
+products run on the VPU (no MXU analogue for (min,+)) as a static
+unroll over the contraction index: one lane-broadcast column plus one
+sublane-broadcast row per term, no dynamic slicing of values (which has
+no Mosaic lowering).
 """
 from __future__ import annotations
 
@@ -48,8 +50,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 from repro.core import (
     FW_PHASES,
     as_choice,
@@ -59,37 +59,28 @@ from repro.core import (
 )
 from repro.core.program import CurveProgram
 
-from .launch import launch
+from .launch import launch, sync_copy
+from .launch import tile_ref as tile_ref_of
 
+# the fused kernel's tiles are f32 (8, 128)-aligned: blocks must be
+# multiples of the sublane count
 _CHUNK = 8
 
 
 def _minplus(a, b):
-    """(min,+) product of (bm, bk) x (bk, bn) via chunked broadcasts."""
-    bm, bk = a.shape
-    _, bn = b.shape
-    out0 = jnp.full((bm, bn), jnp.inf, dtype=jnp.float32)
-
-    def body(c, out):
-        t0 = c * _CHUNK
-        ac = jax.lax.dynamic_slice(a, (0, t0), (bm, _CHUNK))
-        bc = jax.lax.dynamic_slice(b, (t0, 0), (_CHUNK, bn))
-        cand = jnp.min(ac[:, :, None] + bc[None, :, :], axis=1)
-        return jnp.minimum(out, cand)
-
-    return jax.lax.fori_loop(0, bk // _CHUNK, body, out0)
+    """(min,+) product of (bm, bk) x (bk, bn): a static unroll over the
+    contraction index (min is exact, so the order of terms is free)."""
+    out = a[:, 0:1] + b[0:1, :]
+    for t in range(1, a.shape[1]):
+        out = jnp.minimum(out, a[:, t : t + 1] + b[t : t + 1, :])
+    return out
 
 
 def _fw_closure(d):
     """Min-plus transitive closure of one (b, b) tile (in-tile FW)."""
-    b = d.shape[0]
-
-    def body(t, d):
-        col = jax.lax.dynamic_slice(d, (0, t), (b, 1))
-        row = jax.lax.dynamic_slice(d, (t, 0), (1, b))
-        return jnp.minimum(d, col + row)
-
-    return jax.lax.fori_loop(0, b, body, d)
+    for t in range(d.shape[0]):
+        d = jnp.minimum(d, d[:, t : t + 1] + d[t : t + 1, :])
+    return d
 
 
 def _diag_kernel(d_in, d_out):
@@ -112,47 +103,53 @@ def _trailing_kernel(sched_ref, dik_ref, dkj_ref, d_in, d_out):
     d_out[...] = jnp.minimum(d, upd)
 
 
-def _fused_fw_kernel(sched_ref, d_in_ref, o_ref, diag_ref, row_ref, col_ref, *, b):
+def _fused_fw_kernel(
+    sched_ref, d_in_ref, o_ref, tile_ref, diag_ref, row_ref, col_ref, sem,
+    *, b,
+):
     """One phased-schedule step: branch on the prefetched phase id.
 
-    All matrix reads/writes go through ``o_ref`` (interpret mode re-fetches
-    revisited output blocks but never threads aliased-output writes back
-    into input reads, so ``d_in_ref`` exists only to donate its buffer).
-    The closed diagonal and the finished row/column panels of the current
-    k-block are carried across grid steps in VMEM scratch.
+    The matrix is HBM-resident (``o_ref`` aliases ``d_in_ref``); the
+    step's (i, j) tile is DMA'd into ``tile_ref``, updated, and DMA'd
+    back before the step ends, so a later revisit reads it from HBM.
+    The closed diagonal and the finished row/column panels of the
+    current k-block are carried across grid steps in VMEM scratch.
     """
-    del d_in_ref  # aliased donor; all RMW goes through o_ref
+    del d_in_ref  # aliased donor: o_ref is the same HBM buffer
     s = pl.program_id(0)
     phase = sched_ref[s, 0]
     i = sched_ref[s, 2]
     j = sched_ref[s, 3]
+    blk = tile_ref_of(o_ref, i, j, b, b)
+    sync_copy(blk, tile_ref, sem)
 
     @pl.when(phase == 0)
     def _diag():
-        closed = _fw_closure(o_ref[...].astype(jnp.float32))
-        o_ref[...] = closed.astype(o_ref.dtype)
+        closed = _fw_closure(tile_ref[...])
+        tile_ref[...] = closed
         diag_ref[...] = closed
 
     @pl.when(phase == 1)
     def _row():
-        p = o_ref[...].astype(jnp.float32)
-        out = jnp.minimum(p, _minplus(diag_ref[...].astype(jnp.float32), p))
-        o_ref[...] = out.astype(o_ref.dtype)
-        row_ref[:, pl.ds(j * b, b)] = out
+        p = tile_ref[...]
+        out = jnp.minimum(p, _minplus(diag_ref[...], p))
+        tile_ref[...] = out
+        row_ref[:, pl.ds(pl.multiple_of(j * b, b), b)] = out
 
     @pl.when(phase == 2)
     def _col():
-        p = o_ref[...].astype(jnp.float32)
-        out = jnp.minimum(p, _minplus(p, diag_ref[...].astype(jnp.float32)))
-        o_ref[...] = out.astype(o_ref.dtype)
-        col_ref[pl.ds(i * b, b), :] = out
+        p = tile_ref[...]
+        out = jnp.minimum(p, _minplus(p, diag_ref[...]))
+        tile_ref[...] = out
+        col_ref[pl.ds(pl.multiple_of(i * b, b), b), :] = out
 
     @pl.when(phase == 3)
     def _trailing():
-        d = o_ref[...].astype(jnp.float32)
-        dik = col_ref[pl.ds(i * b, b), :]
-        dkj = row_ref[:, pl.ds(j * b, b)]
-        o_ref[...] = jnp.minimum(d, _minplus(dik, dkj)).astype(o_ref.dtype)
+        dik = col_ref[pl.ds(pl.multiple_of(i * b, b), b), :]
+        dkj = row_ref[:, pl.ds(pl.multiple_of(j * b, b), b)]
+        tile_ref[...] = jnp.minimum(tile_ref[...], _minplus(dik, dkj))
+
+    sync_copy(tile_ref, blk, sem)
 
 
 def fw_program(choice, nt: int, b: int) -> CurveProgram:
@@ -175,13 +172,15 @@ def fw_program(choice, nt: int, b: int) -> CurveProgram:
         name=f"fw_fused_{curve}",
         schedule=phased_schedule_device(curve, nt, kind="fw"),
         kernel=functools.partial(_fused_fw_kernel, b=b),
-        in_specs=(pl.BlockSpec((b, b), lambda s, sr: (sr[s, 2], sr[s, 3])),),
-        out_specs=pl.BlockSpec((b, b), lambda s, sr: (sr[s, 2], sr[s, 3])),
+        in_specs=(pl.BlockSpec(memory_space=pl.ANY),),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         scratch_shapes=(
+            pltpu.VMEM((b, b), jnp.float32),   # the step's (i, j) tile
             pltpu.VMEM((b, b), jnp.float32),   # closed diagonal D_kk
             pltpu.VMEM((b, n), jnp.float32),   # row panel D_k*
             pltpu.VMEM((n, b), jnp.float32),   # column panel D_*k
+            pltpu.SemaphoreType.DMA(()),
         ),
         input_output_aliases={1: 0},
         phases=FW_PHASES,
@@ -228,7 +227,7 @@ def floyd_warshall_blocked_reference(
     d = d.astype(jnp.float32)
 
     full = tile_schedule(curve, nt, nt).astype(np.int32)
-    params = CompilerParams(dimension_semantics=("arbitrary",))
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
     for kb in range(nt):
         spec_kk = pl.BlockSpec((b, b), lambda *_: (kb, kb))  # noqa: B023

@@ -17,18 +17,23 @@ Two generations of the same application:
   table drives two phases off the prefetched phase id (the PR-3
   phase-fusion recipe): phase 0 visits the (i, j) metric tiles in curve
   order and read-modify-writes a running (min, argmin) keyed by point
-  tile through the output refs (interpret mode re-fetches revisited
-  output blocks; first-visit flags pick init vs merge — the
-  ``matmul_swizzled_3d`` idiom), phase 1 re-streams each point tile once
-  and accumulates per-centroid partial sums/counts into a single
-  resident output block.  Per-iteration dispatches drop from
-  1 kernel + 2 ``segment_sum`` + host merge glue to exactly 1.
+  tile in VMEM scratch (first-visit flags pick init vs merge; a point
+  tile is revisited out of order, and the TPU pipeline never re-fetches
+  a revisited output block), phase 1 re-streams each point tile once,
+  writes its finished (min, argmin) row, and accumulates per-centroid
+  partial sums/counts into a single resident output block.
+  Per-iteration dispatches drop from 1 kernel + 2 ``segment_sum`` +
+  host merge glue to exactly 1.
+
+The kernels read the points feature-major, ``xT`` (D, N): a point tile
+is a (D, bp) block whose lanes are points, so per-point results — the
+metric's min and argmin over centroids, the assignment — come out as
+lane-dense (1, bp) rows without a transpose.
 
 Both paths share the tile math (:func:`_assign_tile`,
-:func:`_update_tile`), so fused == reference is BIT-identical in
-interpret mode: min is an exact reduction, the running merge's
-(value, index) tie-break reproduces argmin's smallest-index rule under
-any visit order, and the phase-1 accumulation adds per-tile partials in
+:func:`_update_tile`), so fused == reference is BIT-identical: min is
+an exact reduction, the running merge's (value, index) tie-break
+reproduces argmin's smallest-index rule under any visit order, and the phase-1 accumulation adds per-tile partials in
 the same order the reference loop does.
 """
 from __future__ import annotations
@@ -39,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import as_choice, hilbert_sort_key, register_schedule_cache
 from repro.core.program import CurveProgram
@@ -151,45 +157,85 @@ def hilbert_point_order_cached(
 # Shared tile math (kernel == reference, bit-identical in interpret mode)
 # ---------------------------------------------------------------------------
 
-def _assign_tile(xv, cv, cnv, ct, *, bc: int, k_valid: int | None):
-    """Tile-local (min metric, global argmin) for one (bp, bc) metric tile.
+_HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = (((1,), (1,)), ((), ()))  # contract the lane (point) axes: A @ B^T
 
-    ``ct`` is the centroid-tile index (traced in the kernels, python int
-    in host-side callers); ``cnv`` the (1, bc) centroid-norm row.
+
+def centroid_norms(c):
+    """||c||² per centroid as a (K, 1) column — the kernels' third
+    operand, computed once per iteration for the whole padded set."""
+    c = c.astype(jnp.float32)
+    return jnp.sum(c * c, axis=1, keepdims=True)
+
+
+def _assign_tile(xTv, cv, cn, ct, *, bc: int, k_valid):
+    """Tile-local (min metric, global argmin) rows for one metric tile.
+
+    ``xTv`` is the (D, bp) feature-major point tile, ``cv`` the (bc, D)
+    centroid tile and ``cn`` its (bc, 1) norms, ``ct`` its index (traced
+    in the kernels, python int in host-side callers).  Returns two
+    (1, bp) rows.
     """
-    x = xv.astype(jnp.float32)
+    x = xTv.astype(jnp.float32)
     c = cv.astype(jnp.float32)
-    # metric tile: ||c||^2 - 2 x.c   (bp, bc); monotone in distance per x
-    m = cnv - 2.0 * jnp.dot(x, c.T, preferred_element_type=jnp.float32)
+    # cross term c.x as D broadcast multiply-adds in feature order: exact
+    # f32 semantics whatever the tile shape, on the chip and in the
+    # interpreter alike (an MXU dot's summation order varies with the
+    # tile's row count, which would make the K padding visible)
+    cx = c[:, 0:1] * x[0:1, :]
+    for d in range(1, c.shape[1]):
+        cx = cx + c[:, d : d + 1] * x[d : d + 1, :]
+    # metric tile m(c, x) = ||c||^2 - 2 c.x   (bc, bp); monotone in
+    # distance per point
+    m = cn - 2.0 * cx
+    row = ct * bc + jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
     if k_valid is not None:
         # ragged K: pad centroids are plain zeros (magic 1e30 coordinates
         # would square to inf and breed NaNs in the metric); push them out
         # of the min/argmin with the largest finite f32 instead
-        col = ct * bc + jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
-        m = jnp.where(col < k_valid, m, jnp.float32(np.finfo(np.float32).max))
-    tile_min = jnp.min(m, axis=1)
-    tile_arg = jnp.argmin(m, axis=1).astype(jnp.int32) + ct * bc
+        m = jnp.where(row < k_valid, m, jnp.float32(np.finfo(np.float32).max))
+    tile_min = jnp.min(m, axis=0, keepdims=True)
+    # argmin with the smallest-index tie-break, from the min itself
+    big = jnp.int32(np.iinfo(np.int32).max)
+    tile_arg = jnp.min(jnp.where(m == tile_min, row, big), axis=0, keepdims=True)
     return tile_min, tile_arg
 
 
-def _update_tile(xv, av, i, *, Kp: int, n_valid: int | None):
-    """Per-centroid partial (sums (Kp, D), counts (1, Kp)) of one point tile.
+def _update_tile(xTv, av, i, *, Kp: int, n_valid):
+    """Per-centroid partials of one point tile: transposed sums
+    (D, Kp) and counts (1, Kp).
 
-    ``av`` are global centroid assignments for the tile's rows, ``i`` the
-    point-tile index (for the ragged-N row mask).  The one-hot matmul is
-    the tile-math twin of ``segment_sum`` restricted to one tile.
+    ``av`` is the (1, bp) row of global assignments, ``i`` the point
+    tile index (for the ragged-N mask).  The one-hot matmul is the
+    tile-math twin of ``segment_sum`` restricted to one tile.
     """
-    bp = xv.shape[0]
-    onehot = (
-        av[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bp, Kp), 1)
-    ).astype(jnp.float32)
+    bp = xTv.shape[1]
+    cid = jax.lax.broadcasted_iota(jnp.int32, (Kp, bp), 0)
+    onehot = (cid == av).astype(jnp.float32)  # (Kp, bp)
     if n_valid is not None:
-        # ragged N: zero-pad rows must not count toward any centroid
-        row = i * bp + jax.lax.broadcasted_iota(jnp.int32, (bp, Kp), 0)
-        onehot = jnp.where(row < n_valid, onehot, 0.0)
-    part_sum = jnp.dot(onehot.T, xv, preferred_element_type=jnp.float32)
-    part_cnt = jnp.sum(onehot, axis=0)[None, :]
+        # ragged N: zero-pad points must not count toward any centroid
+        col = i * bp + jax.lax.broadcasted_iota(jnp.int32, (Kp, bp), 1)
+        onehot = jnp.where(col < n_valid, onehot, 0.0)
+    part_sum = jax.lax.dot_general(
+        xTv.astype(jnp.float32), onehot, _LANES,
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
+    part_cnt = jax.lax.dot_general(
+        jnp.ones((1, bp), jnp.float32), onehot, _LANES,
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
     return part_sum, part_cnt
+
+
+def _phase_block(phase: int):
+    """Index map of a per-point-tile output written only in ``phase``
+    (1): elsewhere it points at the block the phase writes first, so the
+    pipeline never writes back a block the kernel did not fill."""
+
+    def index_map(s, sr):
+        return (jnp.where(sr[s, 0] == phase, sr[s, 1], sr[0, 1]), 0, 0)
+
+    return index_map
 
 
 # ---------------------------------------------------------------------------
@@ -197,13 +243,13 @@ def _update_tile(xv, av, i, *, Kp: int, n_valid: int | None):
 # ---------------------------------------------------------------------------
 
 def _assign_kernel(
-    sched_ref, x_ref, c_ref, cn_ref, min_out, arg_out, *, bc: int,
+    sched_ref, xT_ref, c_ref, cn_ref, min_out, arg_out, *, bc: int,
     k_valid: int | None,
 ):
     s = pl.program_id(0)
     tile_min, tile_arg = _assign_tile(
-        x_ref[...], c_ref[...], cn_ref[...], sched_ref[s, 1],
-        bc=bc, k_valid=k_valid,
+        xT_ref[...], c_ref[...], cn_ref[...], sched_ref[s, 1], bc=bc,
+        k_valid=k_valid,
     )
     min_out[0, 0] = tile_min
     arg_out[0, 0] = tile_arg
@@ -234,28 +280,29 @@ def kmeans_assign_swizzled(
     pt, ctn = N // bp, K // bc
     assert schedule.shape == (pt * ctn, 2)
 
-    cnorm = jnp.sum(c.astype(jnp.float32) ** 2, axis=1)[None, :]  # (1, K)
-
     program = CurveProgram(
         name="kmeans_assign",
         schedule=schedule,
         kernel=functools.partial(_assign_kernel, bc=bc, k_valid=k_valid),
         in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
+            pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, 0])),
             pl.BlockSpec((bc, D), lambda s, sr: (sr[s, 1], 0)),
-            pl.BlockSpec((1, bc), lambda s, sr: (0, sr[s, 1])),
+            pl.BlockSpec((bc, 1), lambda s, sr: (sr[s, 1], 0)),
         ),
         out_specs=[
-            pl.BlockSpec((1, 1, bp), lambda s, sr: (sr[s, 0], sr[s, 1], 0)),
-            pl.BlockSpec((1, 1, bp), lambda s, sr: (sr[s, 0], sr[s, 1], 0)),
+            pl.BlockSpec((1, 1, 1, bp), lambda s, sr: (sr[s, 0], sr[s, 1], 0, 0)),
+            pl.BlockSpec((1, 1, 1, bp), lambda s, sr: (sr[s, 0], sr[s, 1], 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((pt, ctn, bp), jnp.float32),
-            jax.ShapeDtypeStruct((pt, ctn, bp), jnp.int32),
+            jax.ShapeDtypeStruct((pt, ctn, 1, bp), jnp.float32),
+            jax.ShapeDtypeStruct((pt, ctn, 1, bp), jnp.int32),
         ],
         columns=("i", "j"),
     )
-    tile_min, tile_arg = launch(program, x, c, cnorm, interpret=interpret)
+    tile_min, tile_arg = launch(
+        program, x.T, c, centroid_norms(c), interpret=interpret
+    )
+    tile_min, tile_arg = tile_min[:, :, 0], tile_arg[:, :, 0]
 
     # O(N * ct) merge of the per-centroid-tile partials
     best_ct = jnp.argmin(tile_min, axis=1)  # (pt, bp)
@@ -268,53 +315,70 @@ def kmeans_assign_swizzled(
 # Fused Lloyd iteration: ONE pallas_call per iteration, scan over iters
 # ---------------------------------------------------------------------------
 
-def _fused_lloyd_kernel(
-    sched_ref, x_ref, c_ref, cn_ref, min_ref, arg_ref, sum_ref, cnt_ref,
-    *, bc: int, Kp: int, k_valid: int | None, n_valid: int | None,
+def _lloyd_phases(
+    sched_ref, xT_ref, c_ref, cn_ref, run_min, run_arg, *, bc, k_valid,
+    write_row, update,
 ):
-    """One :func:`repro.core.kmeans_schedule` step, branched on phase.
+    """The two phases of one :func:`repro.core.kmeans_schedule` step,
+    shared by the single-core and the shard-local Lloyd kernels.
 
-    All RMW goes through the output refs (interpret mode re-fetches
-    revisited output blocks): phase 0 merges a running (min, arg) keyed
-    by point tile — the (value, index) tie-break makes the merge
-    order-independent AND equal to argmin's smallest-index rule — and
-    phase 1 reads the finished assignments back through ``arg_ref``
-    (phase barrier: every phase-0 visit of a tile precedes phase 1) and
-    accumulates sums/counts into the single resident (Kp, D) / (1, Kp)
-    output blocks.
+    Phase 0 merges a running (min, arg) row per point tile in VMEM
+    scratch — the (value, index) tie-break makes the merge
+    order-independent AND equal to argmin's smallest-index rule.  Phase
+    1 (every phase-0 visit of a tile precedes it) hands the finished row
+    to ``write_row`` and the tile's update partials to ``update``.
     """
     s = pl.program_id(0)
     phase = sched_ref[s, 0]
     i = sched_ref[s, 1]
     j = sched_ref[s, 2]
     first = sched_ref[s, 3]
+    row = pl.ds(i, 1)
 
     @pl.when(phase == 0)
     def _assign():
         tile_min, tile_arg = _assign_tile(
-            x_ref[...], c_ref[...], cn_ref[...], j, bc=bc, k_valid=k_valid
+            xT_ref[...], c_ref[...], cn_ref[...], j, bc=bc, k_valid=k_valid
         )
 
         @pl.when(first == 1)
         def _init():
-            min_ref[0] = tile_min
-            arg_ref[0] = tile_arg
+            run_min[row, :] = tile_min
+            run_arg[row, :] = tile_arg
 
         @pl.when(first == 0)
         def _merge():
-            cur_min = min_ref[0]
-            cur_arg = arg_ref[0]
+            cur_min = run_min[row, :]
+            cur_arg = run_arg[row, :]
             better = (tile_min < cur_min) | (
                 (tile_min == cur_min) & (tile_arg < cur_arg)
             )
-            min_ref[0] = jnp.where(better, tile_min, cur_min)
-            arg_ref[0] = jnp.where(better, tile_arg, cur_arg)
+            run_min[row, :] = jnp.where(better, tile_min, cur_min)
+            run_arg[row, :] = jnp.where(better, tile_arg, cur_arg)
 
     @pl.when(phase == 1)
     def _update():
+        arg = run_arg[row, :]
+        write_row(run_min[row, :], arg)
+        update(i, first, arg)
+
+
+def _fused_lloyd_kernel(
+    sched_ref, xT_ref, c_ref, cn_ref, min_ref, arg_ref, sum_ref, cnt_ref,
+    run_min, run_arg, *, bc: int, Kp: int, k_valid: int | None,
+    n_valid: int | None,
+):
+    """One fused Lloyd step (:func:`_lloyd_phases`): phase 1
+    accumulates the per-centroid sums/counts into the single resident
+    (D, Kp) / (1, Kp) output blocks."""
+
+    def write_row(mn, arg):
+        min_ref[0] = mn
+        arg_ref[0] = arg
+
+    def update(i, first, arg):
         part_sum, part_cnt = _update_tile(
-            x_ref[...].astype(jnp.float32), arg_ref[0], i,
-            Kp=Kp, n_valid=n_valid,
+            xT_ref[...], arg, i, Kp=Kp, n_valid=n_valid
         )
 
         @pl.when(first == 1)
@@ -327,6 +391,11 @@ def _fused_lloyd_kernel(
             sum_ref[...] += part_sum
             cnt_ref[...] += part_cnt
 
+    _lloyd_phases(
+        sched_ref, xT_ref, c_ref, cn_ref, run_min, run_arg, bc=bc,
+        k_valid=k_valid, write_row=write_row, update=update,
+    )
+
 
 def kmeans_lloyd_program(
     schedule, *, pt: int, ct: int, bp: int, bc: int, D: int,
@@ -334,10 +403,14 @@ def kmeans_lloyd_program(
 ) -> CurveProgram:
     """The fused-Lloyd declaration (one iteration = one dispatch).
 
-    Streams (bp, D) point / (bc, D) centroid panels, RMWs the running
-    per-point-tile (min, argmin) blocks through the output refs, and
-    accumulates into a single resident (Kp, D) + (1, Kp) f32 block pair
-    — the ``K·D + K`` f32 residency the ops wrapper gates on.
+    Operands: the feature-major points ``xT`` (D, N), the centroids
+    (Kp, D) and their :func:`centroid_norms`.  Streams (D, bp) point /
+    (bc, D) centroid panels, keeps
+    the running per-point-tile (min, argmin) rows in VMEM scratch
+    (8 bytes per point), and accumulates into a single resident
+    (D, Kp) + (1, Kp) f32 block pair — the residency the ops wrapper
+    gates on.  Outputs: (min, arg) rows (pt, 1, bp) and the transposed
+    sums (D, Kp) plus counts (1, Kp) — see :func:`lloyd_update`.
 
     ``choice`` (a ``kmeans``-kind :class:`repro.core.ScheduleChoice` or
     curve name) records which curve generated ``schedule``; the grid
@@ -359,28 +432,39 @@ def kmeans_lloyd_program(
             _fused_lloyd_kernel, bc=bc, Kp=Kp, k_valid=k_valid, n_valid=n_valid
         ),
         in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 1], 0)),
+            pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, 1])),
             pl.BlockSpec((bc, D), lambda s, sr: (sr[s, 2], 0)),
-            pl.BlockSpec((1, bc), lambda s, sr: (0, sr[s, 2])),
+            pl.BlockSpec((bc, 1), lambda s, sr: (sr[s, 2], 0)),
         ),
         out_specs=[
-            pl.BlockSpec((1, bp), lambda s, sr: (sr[s, 1], 0)),
-            pl.BlockSpec((1, bp), lambda s, sr: (sr[s, 1], 0)),
-            pl.BlockSpec((Kp, D), lambda s, sr: (0, 0)),
+            pl.BlockSpec((1, 1, bp), _phase_block(1)),
+            pl.BlockSpec((1, 1, bp), _phase_block(1)),
+            pl.BlockSpec((D, Kp), lambda s, sr: (0, 0)),
             pl.BlockSpec((1, Kp), lambda s, sr: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((pt, bp), jnp.float32),
-            jax.ShapeDtypeStruct((pt, bp), jnp.int32),
-            jax.ShapeDtypeStruct((Kp, D), jnp.float32),
+            jax.ShapeDtypeStruct((pt, 1, bp), jnp.float32),
+            jax.ShapeDtypeStruct((pt, 1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((D, Kp), jnp.float32),
             jax.ShapeDtypeStruct((1, Kp), jnp.float32),
         ],
+        scratch_shapes=(
+            pltpu.VMEM((pt, bp), jnp.float32),  # running min per point
+            pltpu.VMEM((pt, bp), jnp.int32),  # running argmin per point
+        ),
         phases=("assign", "update"),
         columns=("phase", "i", "j", "first_visit"),
         reference=lambda *a, **kw: kmeans_lloyd_reference(*a, **kw),
         choice=choice,
         schedule_args=(int(pt), int(ct)),
     )
+
+
+def lloyd_update(c, sums_t, cnt):
+    """New centroids from one iteration's transposed sums (D, Kp) and
+    counts (1, Kp); empty clusters keep their centroid."""
+    cw = cnt[0][:, None]
+    return jnp.where(cw > 0, sums_t.T / jnp.maximum(cw, 1.0), c)
 
 
 @functools.partial(
@@ -406,7 +490,7 @@ def kmeans_lloyd_fused(
     (ops.py pads; ``k_valid`` / ``n_valid`` are the true counts when the
     padding exists).  Returns (centroids f32[K, D], assign int32[N]).
     VMEM bound of the fused step: the resident accumulators are
-    K*D + K f32 on top of the streamed (bp, D) / (bc, D) panels.
+    K*D + K f32, plus 8 bytes per point of running (min, argmin).
     """
     Np, D = x.shape
     Kp, D2 = c0.shape
@@ -419,25 +503,24 @@ def kmeans_lloyd_fused(
         schedule, pt=pt, ct=ct, bp=bp, bc=bc, D=D,
         k_valid=k_valid, n_valid=n_valid,
     )
+    xT = x.T
 
     def step(carry, _):
         c, _assign = carry
-        cnorm = jnp.sum(c**2, axis=1)[None, :]  # (1, Kp)
-        _min_m, arg, sums, cnt = launch(program, x, c, cnorm, interpret=interpret)
-        cw = cnt[0][:, None]
-        c_new = jnp.where(cw > 0, sums / jnp.maximum(cw, 1.0), c)
-        return (c_new, arg.reshape(Np)), None
+        _min_m, arg, sums_t, cnt = launch(
+            program, xT, c, centroid_norms(c), interpret=interpret
+        )
+        return (lloyd_update(c, sums_t, cnt), arg.reshape(Np)), None
 
     init = (c0.astype(jnp.float32), jnp.zeros((Np,), jnp.int32))
     (c, assign), _ = jax.lax.scan(step, init, None, length=iters)
     return c, assign
 
 
-def _update_kernel(sched_ref, x_ref, a_ref, sum_ref, cnt_ref, *, Kp, n_valid):
+def _update_kernel(sched_ref, xT_ref, a_ref, sum_ref, cnt_ref, *, Kp, n_valid):
     s = pl.program_id(0)
     part_sum, part_cnt = _update_tile(
-        x_ref[...].astype(jnp.float32), a_ref[0], sched_ref[s, 0],
-        Kp=Kp, n_valid=n_valid,
+        xT_ref[...], a_ref[0], sched_ref[s, 0], Kp=Kp, n_valid=n_valid,
     )
 
     @pl.when(sched_ref[s, 1] == 1)
@@ -464,14 +547,15 @@ def kmeans_update_swizzled(
     n_valid: int | None = None,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Per-centroid (sums f32[Kp, D], counts f32[1, Kp]) of an assignment.
+    """Per-centroid (transposed sums f32[D, Kp], counts f32[1, Kp]) of an
+    assignment.
 
     schedule: int32[pt, 2] rows ``(point_tile, first_visit)`` — the
     phase-1 slice of :func:`repro.core.kmeans_schedule`.  The standalone
     dispatch twin of the fused kernel's update phase (identical
     :func:`_update_tile` math, identical accumulation order), used by the
     Lloyd reference oracle in place of ``segment_sum`` so fused ==
-    reference stays bit-identical in interpret mode.
+    reference stays bit-identical.
     """
     Np, D = x.shape
     assert Np % bp == 0
@@ -482,20 +566,20 @@ def kmeans_update_swizzled(
         schedule=schedule,
         kernel=functools.partial(_update_kernel, Kp=Kp, n_valid=n_valid),
         in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
-            pl.BlockSpec((1, bp), lambda s, sr: (sr[s, 0], 0)),
+            pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, 0])),
+            pl.BlockSpec((1, 1, bp), lambda s, sr: (sr[s, 0], 0, 0)),
         ),
         out_specs=[
-            pl.BlockSpec((Kp, D), lambda s, sr: (0, 0)),
+            pl.BlockSpec((D, Kp), lambda s, sr: (0, 0)),
             pl.BlockSpec((1, Kp), lambda s, sr: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Kp, D), jnp.float32),
+            jax.ShapeDtypeStruct((D, Kp), jnp.float32),
             jax.ShapeDtypeStruct((1, Kp), jnp.float32),
         ],
         columns=("i", "first_visit"),
     )
-    return launch(program, x, assign.reshape(pt, bp), interpret=interpret)
+    return launch(program, x.T, assign.reshape(pt, 1, bp), interpret=interpret)
 
 
 def kmeans_lloyd_reference(
@@ -528,12 +612,11 @@ def kmeans_lloyd_reference(
             schedule2d, x, c, bp=bp, bc=bc, k_valid=k_valid,
             interpret=interpret,
         )
-        sums, cnt = kmeans_update_swizzled(
+        sums_t, cnt = kmeans_update_swizzled(
             update_schedule, x, assign, bp=bp, Kp=Kp, n_valid=n_valid,
             interpret=interpret,
         )
-        cw = cnt[0][:, None]
-        c = jnp.where(cw > 0, sums / jnp.maximum(cw, 1.0), c)
+        c = lloyd_update(c, sums_t, cnt)
     return c, assign
 
 
@@ -554,61 +637,41 @@ def kmeans_init(x: jax.Array, k: int, seed: int) -> jax.Array:
 
 
 def _shard_lloyd_kernel(
-    sched_ref, x_ref, c_ref, cn_ref, lim_ref, min_ref, arg_ref, sum_ref,
-    cnt_ref, *, bc: int, Kp: int,
+    sched_ref, xT_ref, c_ref, cn_ref, lim_ref, min_ref, arg_ref, sum_ref, cnt_ref,
+    run_min, run_arg, *, bc: int, Kp: int,
 ):
     """One :func:`repro.core.kmeans_schedule` step on a shard's tiles.
 
-    Identical phase-0 assign math to :func:`_fused_lloyd_kernel` (same
-    :func:`_assign_tile`, same (value, index) merge), but phase 1 writes
-    each point tile's *per-tile* partial (sums, counts) to its own
-    output block instead of folding into a resident accumulator — every
-    phase-1 block is written exactly once (revisit-free, so this form
-    is also the hardware-safe one), and the cross-shard fold happens
-    outside the kernel in the single-core accumulation order (see
-    kernels/sharded.py).  Ragged masks are *dynamic*: ``lim_ref`` is an
-    int32[1, 2] ``(n_valid_local, k_valid)`` operand, so one traced
-    program serves every shard of an SPMD ``shard_map`` (masking with
-    the full extent is a bitwise no-op, which keeps padded and unpadded
-    shards bit-identical to the statically-masked single-core kernel).
+    Identical phases to :func:`_fused_lloyd_kernel` (same
+    :func:`_lloyd_phases`, same tile math), but phase 1 writes each
+    point tile's *per-tile* partial (sums, counts) to its own output
+    block instead of folding into a resident accumulator — the
+    cross-shard fold happens outside the kernel in the single-core
+    accumulation order (see kernels/sharded.py).  Ragged masks are
+    *dynamic*: ``lim_ref`` is an int32[1, 2] ``(n_valid_local,
+    k_valid)`` SMEM operand, so one traced program serves every shard of
+    an SPMD ``shard_map`` (masking with the full extent is a bitwise
+    no-op, which keeps padded and unpadded shards bit-identical to the
+    statically-masked single-core kernel).
     """
-    s = pl.program_id(0)
-    phase = sched_ref[s, 0]
-    i = sched_ref[s, 1]
-    j = sched_ref[s, 2]
-    first = sched_ref[s, 3]
     n_valid = lim_ref[0, 0]
-    k_valid = lim_ref[0, 1]
 
-    @pl.when(phase == 0)
-    def _assign():
-        tile_min, tile_arg = _assign_tile(
-            x_ref[...], c_ref[...], cn_ref[...], j, bc=bc, k_valid=k_valid
-        )
+    def write_row(mn, arg):
+        min_ref[0] = mn
+        arg_ref[0] = arg
 
-        @pl.when(first == 1)
-        def _init():
-            min_ref[0] = tile_min
-            arg_ref[0] = tile_arg
-
-        @pl.when(first == 0)
-        def _merge():
-            cur_min = min_ref[0]
-            cur_arg = arg_ref[0]
-            better = (tile_min < cur_min) | (
-                (tile_min == cur_min) & (tile_arg < cur_arg)
-            )
-            min_ref[0] = jnp.where(better, tile_min, cur_min)
-            arg_ref[0] = jnp.where(better, tile_arg, cur_arg)
-
-    @pl.when(phase == 1)
-    def _update():
+    def update(i, first, arg):
+        del first
         part_sum, part_cnt = _update_tile(
-            x_ref[...].astype(jnp.float32), arg_ref[0], i,
-            Kp=Kp, n_valid=n_valid,
+            xT_ref[...], arg, i, Kp=Kp, n_valid=n_valid
         )
         sum_ref[0] = part_sum
         cnt_ref[0] = part_cnt
+
+    _lloyd_phases(
+        sched_ref, xT_ref, c_ref, cn_ref, run_min, run_arg, bc=bc,
+        k_valid=lim_ref[0, 1], write_row=write_row, update=update,
+    )
 
 
 def kmeans_shard_program(
@@ -616,12 +679,12 @@ def kmeans_shard_program(
 ) -> CurveProgram:
     """Shard-local Lloyd-step declaration over a ``pt``-tile point shard.
 
-    Outputs: running (min, argmin) per point tile plus PER-TILE update
-    partials ``sums f32[pt, Kp, D]`` / ``counts f32[pt, 1, Kp]`` (each
-    block written exactly once in phase 1).  Operands: x shard, the
-    replicated centroids + their norm row, and the int32[1, 2]
-    ``(n_valid_local, k_valid)`` limits row described in
-    :func:`_shard_lloyd_kernel`.
+    Outputs: (min, argmin) rows per point tile plus PER-TILE update
+    partials ``sums f32[pt, D, Kp]`` (transposed) / ``counts
+    f32[pt, 1, Kp]`` (each block written once, in phase 1).  Operands:
+    the feature-major x shard (D, N_local), the replicated centroids and
+    their norms, and the int32[1, 2] ``(n_valid_local, k_valid)`` limits row
+    described in :func:`_shard_lloyd_kernel`.
     """
     Kp = ct * bc
     return CurveProgram(
@@ -629,23 +692,27 @@ def kmeans_shard_program(
         schedule=schedule,
         kernel=functools.partial(_shard_lloyd_kernel, bc=bc, Kp=Kp),
         in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 1], 0)),
+            pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, 1])),
             pl.BlockSpec((bc, D), lambda s, sr: (sr[s, 2], 0)),
-            pl.BlockSpec((1, bc), lambda s, sr: (0, sr[s, 2])),
-            pl.BlockSpec((1, 2), lambda s, sr: (0, 0)),
+            pl.BlockSpec((bc, 1), lambda s, sr: (sr[s, 2], 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
         out_specs=[
-            pl.BlockSpec((1, bp), lambda s, sr: (sr[s, 1], 0)),
-            pl.BlockSpec((1, bp), lambda s, sr: (sr[s, 1], 0)),
-            pl.BlockSpec((1, Kp, D), lambda s, sr: (sr[s, 1], 0, 0)),
-            pl.BlockSpec((1, 1, Kp), lambda s, sr: (sr[s, 1], 0, 0)),
+            pl.BlockSpec((1, 1, bp), _phase_block(1)),
+            pl.BlockSpec((1, 1, bp), _phase_block(1)),
+            pl.BlockSpec((1, D, Kp), _phase_block(1)),
+            pl.BlockSpec((1, 1, Kp), _phase_block(1)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((pt, bp), jnp.float32),
-            jax.ShapeDtypeStruct((pt, bp), jnp.int32),
-            jax.ShapeDtypeStruct((pt, Kp, D), jnp.float32),
+            jax.ShapeDtypeStruct((pt, 1, bp), jnp.float32),
+            jax.ShapeDtypeStruct((pt, 1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((pt, D, Kp), jnp.float32),
             jax.ShapeDtypeStruct((pt, 1, Kp), jnp.float32),
         ],
+        scratch_shapes=(
+            pltpu.VMEM((pt, bp), jnp.float32),
+            pltpu.VMEM((pt, bp), jnp.int32),
+        ),
         phases=("assign", "update"),
         columns=("phase", "i", "j", "first_visit"),
         reference=lambda *a, **kw: kmeans_lloyd_fused(*a, **kw),

@@ -9,13 +9,22 @@ kernels, and the pallas-call spy the single-dispatch tests count.
 :class:`repro.core.CurveProgram` declaration plus the operands and
 issues exactly one ``pallas_call``.
 
-Execution semantics the launcher inherits (and every program relies
-on): **interpret mode re-fetches revisited output blocks but never
-threads ``input_output_aliases`` writes back into later aliased-input
-reads** — so programs route all RMW through output refs and use donor
-inputs only to give up their buffers.  On Mosaic the revisited-output
-re-fetch is undocumented; the hardware audit has ONE place to look now
-(DESIGN.md §Execution-layer).
+Execution contract (what a TPU v5e showed, DESIGN.md §Execution-layer):
+a pipelined output block is written back to HBM when its block index
+changes and is **never re-fetched** — a later, non-consecutive revisit
+of the same block sees whatever the VMEM buffer last held, not the HBM
+contents.  Only consecutive steps on one block index may accumulate in
+place.  So every kernel that revisits a tile out of order keeps that
+tile in ``memory_space=pl.ANY`` (HBM) and moves it with explicit,
+waited DMAs (fused FW/Cholesky, 3-D matmul), or keeps the running
+state in VMEM scratch (fused Lloyd).  Interpret mode happens to
+re-fetch revisited output blocks, so it cannot tell the two apart;
+only the chip can.
+
+The schedule table is scalar-prefetched into SMEM flattened to one
+dimension: a 2-D int32 table is padded to 128 words per row there, so
+a (steps, 4) table would cost 32x its size.  Kernels and index maps
+still read ``sched[s, c]`` through :class:`FlatTable`.
 
 The dispatch spy (:class:`PallasCallCounter`) is re-exported here as
 part of the execution layer's public surface; it keeps working because
@@ -24,21 +33,29 @@ time), exactly like the pre-refactor kernels did.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.program import CurveProgram
 
-from .pallas_compat import CompilerParams, PallasCallCounter
+from .pallas_compat import PallasCallCounter
 
 __all__ = [
+    "FlatTable",
     "PallasCallCounter",
     "collective_volume",
     "count_collectives",
+    "flat_kernel",
+    "flat_table_spec",
     "launch",
     "on_tpu",
     "resolve_interpret",
+    "sync_copy",
+    "tile_ref",
+    "vmem_limit",
 ]
 
 
@@ -46,13 +63,85 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def resolve_interpret(flag: bool | None) -> bool:
+def resolve_interpret(flag):
     """The interpret/TPU switch: ``None`` means "interpret unless the
-    default backend is a real TPU" (the project's CPU-container
-    charter); an explicit bool is passed through."""
-    if flag is None:
-        return not on_tpu()
-    return bool(flag)
+    default backend is a real TPU"; an explicit bool is passed through,
+    and so is a ``pltpu.InterpretParams`` — the TPU-semantics
+    interpreter, which (unlike ``interpret=True``) refuses a revisited
+    output block the way the chip's pipeline would mishandle it."""
+    return not on_tpu() if flag is None else flag
+
+
+# VMEM a kernel may claim without asking; past it the limit is raised
+# to the program's own estimate plus headroom (v5e has 128 MiB per core)
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+_MAX_SCOPED_VMEM = 100 * 2**20
+
+
+def vmem_limit(need: int) -> int | None:
+    """Scoped-VMEM limit for a kernel whose buffers need ``need`` bytes:
+    ``None`` (the compiler default) while that leaves room for compiler
+    temporaries, else the need plus headroom, capped below the chip's
+    VMEM."""
+    if need <= _DEFAULT_SCOPED_VMEM * 3 // 4:
+        return None
+    return min(need + need // 2 + 4 * 2**20, _MAX_SCOPED_VMEM)
+
+
+class FlatTable:
+    """2-D ``table[s, c]`` view of a schedule table that was flattened
+    to 1-D for SMEM (``cols`` columns per row)."""
+
+    def __init__(self, ref, cols: int):
+        self.ref = ref
+        self.cols = cols
+
+    def __getitem__(self, idx):
+        s, c = idx
+        return self.ref[s * self.cols + c]
+
+
+def flat_table_spec(spec, cols: int, n_grid: int):
+    """``spec`` with its index map reading the first prefetch operand
+    (the schedule, right after the ``n_grid`` grid indices) through
+    :class:`FlatTable`."""
+    if spec.index_map is None:
+        return spec
+    fn = spec.index_map
+
+    def index_map(*args):
+        args = list(args)
+        args[n_grid] = FlatTable(args[n_grid], cols)
+        return fn(*args)
+
+    return dataclasses.replace(spec, index_map=index_map)
+
+
+def flat_kernel(kernel, cols: int):
+    """``kernel`` with its first ref, the flattened schedule, read
+    through :class:`FlatTable`."""
+
+    def run(sched_ref, *refs):
+        return kernel(FlatTable(sched_ref, cols), *refs)
+
+    return run
+
+
+def sync_copy(src, dst, sem) -> None:
+    """One DMA, started and waited: the RMW kernels' tile moves between
+    an HBM (``pl.ANY``) operand and VMEM scratch.  Waiting before the
+    step ends is what makes a later revisit read the written bytes."""
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def tile_ref(ref, i, j, bm: int, bn: int):
+    """The (bm, bn) tile (i, j) of a 2-D HBM ref, for :func:`sync_copy`."""
+    return ref.at[
+        pl.ds(pl.multiple_of(i * bm, bm), bm),
+        pl.ds(pl.multiple_of(j * bn, bn), bn),
+    ]
 
 
 def launch(
@@ -82,24 +171,33 @@ def launch(
 
         program = resolve_program_choice(program, choice, operands)
     grid = program.grid if program.grid is not None else (program.steps,)
+    cols = int(program.schedule.shape[1])
+    flat = lambda spec: flat_table_spec(spec, cols, len(grid))  # noqa: E731
+    out_specs = program.out_specs
+    out_specs = (
+        [flat(o) for o in out_specs] if isinstance(out_specs, (list, tuple))
+        else flat(out_specs)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
-        in_specs=list(program.in_specs),
-        out_specs=program.out_specs,
+        in_specs=[flat(spec) for spec in program.in_specs],
+        out_specs=out_specs,
         scratch_shapes=list(program.scratch_shapes),
     )
     call = pl.pallas_call(
-        program.kernel,
+        flat_kernel(program.kernel, cols),
         grid_spec=grid_spec,
         out_shape=program.out_shape,
         input_output_aliases=dict(program.input_output_aliases),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=vmem_limit(program.vmem_bytes(*operands)),
         ),
         interpret=resolve_interpret(interpret),
+        name=program.name,
     )
-    return call(program.schedule, *operands)
+    return call(program.schedule.reshape(-1), *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +218,7 @@ _COLLECTIVE_PRIMS = frozenset(
 
 
 def _sub_jaxprs(value):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr

@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.program import CurveProgram
 
-from .launch import launch
+from .launch import launch, sync_copy, tile_ref
 
 
 def _matmul_kernel(sched_ref, a_ref, b_ref, o_ref, acc_ref, *, k_tiles: int):
@@ -91,16 +91,42 @@ def matmul_swizzled(
     return launch(program, a, b, interpret=interpret)
 
 
-def _matmul3d_kernel(sched_ref, a_ref, b_ref, o_ref):
+def _matmul3d_kernel(sched_ref, a_ref, b_ref, o_ref, acc_ref, sem, *,
+                     steps: int, bm: int, bn: int):
+    """One (i, j, k) tile product accumulated into C(i, j).
+
+    C stays in HBM (``pl.ANY``): the f32 accumulator tile is loaded by
+    waited DMA when the step starts a run on a revisited (i, j) and
+    stored when the run ends, so a later revisit reads the stored
+    partial sum.  Consecutive steps on one (i, j) accumulate in VMEM.
+    """
     s = pl.program_id(0)
+    i = sched_ref[s, 0]
+    j = sched_ref[s, 1]
+    prv = jnp.maximum(s - 1, 0)
+    nxt = jnp.minimum(s + 1, steps - 1)
+    run_starts = (s == 0) | (sched_ref[prv, 0] != i) | (sched_ref[prv, 1] != j)
+    run_ends = (
+        (s == steps - 1) | (sched_ref[nxt, 0] != i) | (sched_ref[nxt, 1] != j)
+    )
+    first = sched_ref[s, 3] == 1  # first visit of this (i, j) output tile
+    blk = tile_ref(o_ref, i, j, bm, bn)
 
-    @pl.when(sched_ref[s, 3] == 1)
-    def _init():  # first visit of this (i, j) output tile
-        o_ref[...] = jnp.zeros_like(o_ref)
+    @pl.when(first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    o_ref[...] += jnp.dot(
+    @pl.when(run_starts & jnp.logical_not(first))
+    def _load():
+        sync_copy(blk, acc_ref, sem)
+
+    acc_ref[...] += jnp.dot(
         a_ref[...], b_ref[...], preferred_element_type=jnp.float32
     )
+
+    @pl.when(run_ends)
+    def _store():
+        sync_copy(acc_ref, blk, sem)
 
 
 @functools.partial(
@@ -130,27 +156,12 @@ def matmul_swizzled_3d(
     schedule table because under a 3-D curve the k digits of one output
     tile are not contiguous in the grid.
 
-    Revisit-safety: while the (i, j) index is unchanged the output block
-    stays VMEM-resident and ``+=`` accumulates in place; when it changes,
-    the block is flushed, and interpret mode re-fetches it on revisit
-    (asserted against the jnp.dot oracle in tests).  On real TPU the
-    Mosaic pipeline is NOT documented to re-fetch revisited *output*
-    windows — before production use the hardware path must be validated,
-    and if the re-fetch does not hold, the hardware-correct twin is the
-    ``input_output_aliases`` + aliased-input read of
-    :func:`tile_update_swizzled` (whose HBM writes genuine input
-    re-fetches do observe; that variant is in turn unverifiable in
-    interpret mode, which never feeds outputs back to aliased inputs —
-    see DESIGN.md §Changed-assumptions).  For *unit-step* schedules
-    (power-of-two tile cubes) an (i, j) projection is never revisited
-    with a gap under 3 grid steps (two consecutive moves returning to
-    the same (i, j) with the same k would repeat a grid point,
-    contradicting bijectivity), so a revisit's fetch never races the
-    preceding flush.  Clipped covers of non-power-of-two grids are NOT
-    unit-step and can produce gap-2 revisits — audit with
-    :func:`repro.core.schedule.min_revisit_gap(sched, (0, 1))` before
-    trusting such a schedule on hardware (interpret mode is exact
-    regardless).
+    Revisit-safety: the TPU pipeline never re-fetches a revisited
+    output block, so C is HBM-resident (``pl.ANY``) and moved by waited
+    DMAs — loaded when a run of steps on one (i, j) starts on a tile
+    already visited, stored when the run ends (:func:`_matmul3d_kernel`).
+    Every store completes before the step ends, so any revisit gap is
+    exact, on the chip and in interpret mode alike.
 
     The payoff (paper §1, generalised): a unit-step 3-D schedule
     changes one of (i, j, k) per step, so of the tiles A(i,k) / B(k,j) /
@@ -174,13 +185,19 @@ def matmul_swizzled_3d(
     program = CurveProgram(
         name="matmul3d",
         schedule=schedule,
-        kernel=_matmul3d_kernel,
+        kernel=functools.partial(
+            _matmul3d_kernel, steps=mt * nt * kt, bm=bm, bn=bn
+        ),
         in_specs=(
             pl.BlockSpec((bm, bk), lambda s, sr: (sr[s, 0], sr[s, 2])),
             pl.BlockSpec((bk, bn), lambda s, sr: (sr[s, 2], sr[s, 1])),
         ),
-        out_specs=pl.BlockSpec((bm, bn), lambda s, sr: (sr[s, 0], sr[s, 1])),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        scratch_shapes=(
+            pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.SemaphoreType.DMA(()),
+        ),
         columns=("i", "j", "k", "first_visit"),
         reference=matmul_swizzled,
     )
@@ -192,13 +209,11 @@ def _accum_update_kernel(sched_ref, o_in_ref, a_ref, b_ref, o_ref, *, alpha: flo
     """o += alpha * (a @ b^T) — single-shot tile update (SYRK/GEMM trailing
     updates for Cholesky; o is input/output-aliased, each tile visited
     exactly once so the read-modify-write is hazard-free)."""
-    o_ref[...] = (
-        o_in_ref[...]
-        + alpha
-        * jnp.dot(
-            a_ref[...], b_ref[...].T, preferred_element_type=jnp.float32
-        ).astype(o_ref.dtype)
-    )
+    o_ref[...] = o_in_ref[...] + alpha * jax.lax.dot_general(
+        a_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).astype(o_ref.dtype)
 
 
 @functools.partial(

@@ -2,18 +2,18 @@
 
 These handle what the raw kernels don't: schedule construction (curve
 choice), padding to block multiples, GQA head expansion, dtype policy and
-the interpret/compiled dispatch (interpret=True on CPU — the kernels are
-TPU-targeted and validated in interpret mode per the project charter).
+the interpret/compiled dispatch (compiled on a TPU, the Pallas
+interpreter elsewhere — the CPU tests).
 
 Two execution-layer policies live here too (DESIGN.md §Execution-layer,
 §Scale-out):
 
-* **VMEM-budget fallback** — every fused app's :class:`CurveProgram`
-  estimates its residency (``vmem_bytes``); when a budget is configured
+* **VMEM-budget fallback** — the fused FW, Cholesky and Lloyd programs
+  estimate their residency (``vmem_bytes``); when a budget is configured
   (:func:`repro.core.set_vmem_budget` / ``REPRO_VMEM_BUDGET``) and the
-  fused form exceeds it, the wrapper silently takes the program's
-  retained multi-dispatch reference path instead (correct at any size;
-  O(nt) dispatches instead of 1).
+  fused form exceeds it, the wrapper takes the program's retained
+  multi-dispatch reference path instead (correct at any size; O(nt)
+  dispatches instead of 1) and says so with a :class:`RuntimeWarning`.
 * **mesh= scale-out** — ``kmeans_lloyd`` and ``simjoin_pairs`` accept a
   1-D device mesh (``repro.launch.mesh.make_app_mesh``) and run the
   curve-range-sharded shard_map variants from
@@ -29,7 +29,7 @@ import numpy as np
 
 from repro.core import (
     ScheduleChoice,
-    fits_vmem,
+    fused_fits,
     get_curve,
     kmeans_schedule,
     kmeans_schedule_device,
@@ -328,7 +328,7 @@ def attention_decode(
 
     q: (B, Hkv, g, Dk) grouped single-token queries (GQA: g = H // Hkv;
     MLA: Hkv=1, g=H over the latent ⊕ rope width).  k_pages/v_pages:
-    (P, page_size, Hkv, Dk/Dv) physical pools; ``page_table`` int32[B,
+    (P, Hkv, page_size, Dk/Dv) physical pools; ``page_table`` int32[B,
     max_pages] and ``pos`` int32[B] are dynamic operands — allocation
     churn and ragged per-slot depths never recompile.  Returns
     (B, Hkv, g, Dv).
@@ -370,7 +370,7 @@ def attention_prefill(
     must already be scattered into the pools (split-phase; the models
     layer does the masked scatter first).  Returns (B, Tq, Hkv, g, Dv).
     """
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     max_pages = page_table.shape[1]
     if schedule is None:
         if n_new is None:
@@ -460,11 +460,11 @@ def kmeans_lloyd(
     ``iters`` loop under ``jax.lax.scan`` (the kernel traces once).
     ``fused=False`` is the retained multi-dispatch reference (one
     assignment kernel + host-side merge + per-tile update per
-    iteration); the two are bit-identical in interpret mode.  When the
-    fused program's VMEM residency (``K·D + K`` f32 resident
-    accumulators + streamed panels) exceeds the configured budget
-    (:func:`repro.core.set_vmem_budget`), the wrapper falls back to the
-    reference path automatically.
+    iteration); the two are bit-identical.  When the fused program's
+    VMEM residency (``K·D + K`` f32 resident accumulators, 8 bytes per
+    point of running (min, argmin), streamed panels) exceeds the
+    configured budget (:func:`repro.core.set_vmem_budget`), the wrapper
+    warns and falls back to the reference path.
 
     ``mesh=`` (a 1-D mesh from ``repro.launch.mesh.make_app_mesh``)
     runs the curve-range-sharded shard_map variant instead: point tiles
@@ -526,8 +526,9 @@ def kmeans_lloyd(
             sched, pt=pt, ct=ct, bp=bp, bc=bc, D=D,
             k_valid=k_valid, n_valid=n_valid, choice=curve,
         )
-        cnorm_probe = jax.ShapeDtypeStruct((1, cp.shape[0]), jnp.float32)
-        fused = fits_vmem(prog, xp, cp, cnorm_probe)
+        xT_probe = jax.ShapeDtypeStruct(xp.shape[::-1], xp.dtype)
+        cn_probe = jax.ShapeDtypeStruct((cp.shape[0], 1), jnp.float32)
+        fused = fused_fits("kmeans_lloyd", prog, xT_probe, cp, cn_probe)
     if fused:
         c, assign = kmeans_lloyd_fused(sched, xp, cp, **kw)
     else:
@@ -609,31 +610,24 @@ def simjoin_pairs(
     :class:`repro.core.ScheduleChoice`) overrides ``curve`` and ``bp``
     as one tunable value (autotuner contract; defaults on a miss).
 
-    Classic two-pass emission, both passes FGF-Hilbert tile-scheduled:
-    pass 1 is the count kernel (:func:`simjoin_tile_hits_swizzled`),
-    whose per-tile totals feed an exclusive prefix sum; pass 2
-    (:func:`simjoin_emit_swizzled`) writes each tile's pairs into a
-    preallocated buffer at its prefetched offset.  Ragged N is handled by
-    the same zero-pad + index-mask rule as the counts.  With
+    Two-pass emission, both passes FGF-Hilbert tile-scheduled: pass 1
+    is the count kernel (:func:`simjoin_tile_hits_swizzled`), whose
+    per-tile totals give the exact pair count and the non-empty tiles;
+    pass 2 (:func:`simjoin_emit_swizzled`) writes each non-empty tile's
+    hit mask, and one exact-size ``nonzero`` compacts the masks into
+    pairs in schedule-then-row-major order.  Ragged N is handled by the
+    same zero-pad + index-mask rule as the counts.  With
     ``hilbert_order=True`` the join runs on Hilbert-sorted points and the
     emitted indices are mapped back through the (cached) permutation, so
     pairs always refer to the original point order.
 
     ``mesh=`` (a 1-D mesh from ``repro.launch.mesh.make_app_mesh``) runs
     the distributed two-pass variant: the triangle schedule's rows are
-    curve-range partitioned across devices, per-shard counts feed the
-    global host-side prefix sum, and each shard emits at local offsets
-    into its own buffer — the concatenated result is identical to the
-    single-core output (see :mod:`repro.kernels.sharded`).
-
-    When the emission buffer's VMEM residency (``p_pad · 2`` int32)
-    exceeds the configured budget (:func:`repro.core.set_vmem_budget`),
-    the wrapper falls back to the dense O(N²) oracle — correct but
-    quadratic-memory on host, and returned in *lexicographic* order
-    rather than the kernel paths' schedule order (the pair SET is
-    identical; sort before comparing across paths).  The sharded path
-    applies the same gate to its per-shard buffer, which is ~mesh-size
-    times smaller — so sharding is the way to keep big joins fused.
+    curve-range partitioned across devices, per-shard counts give the
+    global pair count, and each shard emits the masks of its non-empty
+    tiles — the compacted result is identical to the single-core output
+    (see :mod:`repro.kernels.sharded`).  Neither pass keeps a
+    data-sized buffer in VMEM, so the join has no VMEM-budget fallback.
 
     The output size is data-dependent, so this wrapper host-syncs the
     pass-1 totals between the two dispatches — it cannot run under an
@@ -666,19 +660,13 @@ def simjoin_pairs(
     n_valid = N if pn else None
     interp = _interpret(interpret)
     tri = triangle_schedule(curve, pt, strict=False)
-    # the two-pass hits → prefix-sum → emit machinery is the shared
+    # the two-pass hits → non-empty tiles → emit machinery is the shared
     # driver (kernels/simjoin.py), reused verbatim by the streaming
     # join's per-tick probe dispatch (serve/apps.py)
     pairs = simjoin_pairs_scheduled(
         tri, xp, eps=float(eps), bp=bp, n_valid=n_valid, interpret=interp
     )
-    if pairs is None:
-        # the resident pair buffer would blow the VMEM budget: fall back
-        # to the dense oracle (documented; shard via mesh= to stay fused)
-        pairs = jnp.asarray(ref.simjoin_pairs(x, float(eps)))
     if perm is not None:
-        # (if the oracle ran, it ran on sorted points; map back the same
-        # way as the kernel path)
         pairs = map_pairs_back(pairs, perm)
     return pairs
 
@@ -699,8 +687,8 @@ def floyd_warshall(
     as one tunable value (autotuner contract; defaults on a miss).
 
     ``fused=True`` (default) runs the phase-fused single-``pallas_call``
-    kernel; ``fused=False`` the per-k-block reference (bit-identical in
-    interpret mode).  Any n is accepted: a block size is auto-picked
+    kernel; ``fused=False`` the per-k-block reference (bit-identical).
+    Any n is accepted: a block size is auto-picked
     (largest divisor of n that is a multiple of 8 near ``b``, else the
     matrix is padded with unreachable +inf border nodes and the result
     sliced back).
@@ -718,8 +706,9 @@ def floyd_warshall(
         border = jnp.arange(n, npad)
         dp = dp.at[border, border].set(0.0)  # pad nodes: self-loops only
     if fused:
-        # VMEM-budget gate on the fused form's b·b + 2·b·n f32 scratch
-        fused = fits_vmem(fw_program(curve, npad // bb, bb), dp)
+        # VMEM-budget gate on the fused form's 2·b·b + 2·b·n f32 scratch
+        prog = fw_program(curve, npad // bb, bb)
+        fused = fused_fits("floyd_warshall", prog, dp)
     fn = floyd_warshall_blocked if fused else floyd_warshall_blocked_reference
     out = fn(dp, b=bb, curve=curve, interpret=_interpret(interpret))
     return out[:n, :n] if npad != n else out
@@ -741,8 +730,8 @@ def cholesky(
     as one tunable value (autotuner contract; defaults on a miss).
 
     ``fused=True`` (default) runs the phase-fused single-``pallas_call``
-    kernel; ``fused=False`` the per-k-block reference (bit-identical in
-    interpret mode).  Any n is accepted: a block size is auto-picked
+    kernel; ``fused=False`` the per-k-block reference (bit-identical).
+    Any n is accepted: a block size is auto-picked
     (largest divisor of n near ``b``, else the matrix is padded with an
     identity border — chol([[A, 0], [0, I]]) = [[L, 0], [0, I]] — and
     the factor sliced back).
@@ -762,8 +751,9 @@ def cholesky(
         border = jnp.arange(n, npad)
         ap = ap.at[border, border].set(1.0)
     if fused:
-        # VMEM-budget gate on the fused form's b·b + b·n f32 scratch
-        fused = fits_vmem(cholesky_program(curve, npad // bb, bb), ap)
+        # VMEM-budget gate on the fused form's 2·b·b + b·n f32 scratch
+        prog = cholesky_program(curve, npad // bb, bb)
+        fused = fused_fits("cholesky", prog, ap)
     fn = cholesky_blocked if fused else cholesky_blocked_reference
     out = fn(ap, b=bb, curve=curve, interpret=_interpret(interpret))
     return out[:n, :n] if npad != n else out

@@ -1,18 +1,7 @@
-"""Version-compat shims and introspection helpers for the Pallas TPU API.
-
-The kernels target the current Pallas API (``pltpu.CompilerParams``); on
-older jaxlibs the same object is exported as ``pltpu.TPUCompilerParams``.
-Import ``CompilerParams`` from here so every kernel works across the
-versions the container may carry.
-"""
+"""Introspection helpers for the Pallas API (the dispatch spy)."""
 from __future__ import annotations
 
 from jax.experimental import pallas as _pl
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 class PallasCallCounter:
@@ -42,4 +31,4 @@ class PallasCallCounter:
         return False
 
 
-__all__ = ["CompilerParams", "PallasCallCounter"]
+__all__ = ["PallasCallCounter"]

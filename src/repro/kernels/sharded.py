@@ -34,13 +34,13 @@ reduction is split by exactness class:
 **ε-join** (:func:`simjoin_pairs_sharded`): the distributed two-pass
 join, in two data-distribution modes.  Both share the schedule split:
 pass 1 counts hits over each shard's curve range of the triangle
-schedule; the host turns the per-step totals into a global exclusive
-prefix sum (the single-core path already host-syncs here — output size
-is data-dependent); pass 2 emits with *local* offsets into per-shard
-(p_pad, 2) buffers that concatenate (a host-side gather in the halo
-case) into the global pair list **in exactly the single-core emission
-order** (shards hold contiguous schedule ranges of the global pruned
-triangle).
+schedule; the host reads the per-step totals (the single-core path
+already host-syncs here — output size is data-dependent) and keeps the
+non-empty rows; pass 2 has each shard write the hit masks of its
+non-empty rows, which one exact-size compaction (a host-side gather of
+mask positions in the halo case) turns into the global pair list **in
+exactly the single-core emission order** (shards hold contiguous
+schedule ranges of the global pruned triangle).
 
 * ``halo=True`` (default): x is POINT-sharded ``P(axis, None)``.  The
   ε-pruned schedule (tile reach from :func:`repro.core.
@@ -80,24 +80,20 @@ from repro.core import (
 
 from .kmeans import (
     _quantise_points,
+    centroid_norms,
     hilbert_point_order_cached,
     kmeans_init,
     kmeans_shard_program,
+    lloyd_update,
 )
 from .launch import collective_volume, launch, resolve_interpret
 from .simjoin import (
     check_pair_offsets,
     map_pairs_back,
-    simjoin_emit_halo_program,
+    pairs_from_masks,
     simjoin_emit_program,
     simjoin_hits_rows_program,
 )
-
-# jax >= 0.5 exports shard_map at top level; 0.4.x only has the
-# experimental module (same compat rule as models/moe.py)
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 __all__ = [
     "kmeans_lloyd_sharded",
@@ -185,17 +181,16 @@ def _lloyd_fn(mesh, axis, *, curve, iters, pt, ptl, ct, bp, bc, D,
     program_args = dict(pt=ptl, ct=ct, bp=bp, bc=bc, D=D)
     _, num = mesh_axis(mesh)
 
-    def body(x_l, c0, lim):
+    def body(xT_l, c0, lim):
         program = kmeans_shard_program(sched, **program_args)
 
         def step(carry, _):
             c, _assign = carry
-            cnorm = jnp.sum(c**2, axis=1)[None, :]  # (1, Kp)
             _min_m, arg, psums, pcnts = launch(
-                program, x_l, c, cnorm, lim, interpret=interpret
+                program, xT_l, c, centroid_norms(c), lim, interpret=interpret
             )
             # counts: integer-valued f32 — psum is exact in any grouping
-            cnt = jax.lax.psum(jnp.sum(pcnts[:, 0, :], axis=0), axis)
+            cnt = jax.lax.psum(jnp.sum(pcnts, axis=0), axis)  # (1, Kp)
             if reduce == "exact":
                 # sums: reproduce the fused kernel's left fold over the
                 # global per-tile partials, in its own phase-1 order
@@ -214,20 +209,18 @@ def _lloyd_fn(mesh, axis, *, curve, iters, pt, ptl, ct, bp, bc, D,
                 sums = _tree_reduce(local, axis, num)
             else:  # "psum"
                 sums = jax.lax.psum(jnp.sum(psums, axis=0), axis)
-            cw = cnt[:, None]
-            c_new = jnp.where(cw > 0, sums / jnp.maximum(cw, 1.0), c)
-            return (c_new, arg.reshape(-1)), None
+            return (lloyd_update(c, sums, cnt), arg.reshape(-1)), None
 
-        init = (c0.astype(jnp.float32), jnp.zeros((x_l.shape[0],), jnp.int32))
+        init = (c0.astype(jnp.float32), jnp.zeros((xT_l.shape[1],), jnp.int32))
         (c, assign), _ = jax.lax.scan(step, init, None, length=iters)
         return c, assign
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(P(axis, None), P(None, None), P(axis, None)),
+        in_specs=(P(None, axis), P(None, None), P(axis, None)),
         out_specs=(P(None, None), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -277,7 +270,7 @@ def _lloyd_setup(
         bp=bp, bc=bc, D=D, interpret=resolve_interpret(interpret),
         reduce=reduce,
     )
-    return fn, (xp, cp, jnp.asarray(limits)), (inv, N, k)
+    return fn, (xp.T, cp, jnp.asarray(limits)), (inv, N, k)
 
 
 def kmeans_lloyd_sharded(
@@ -400,34 +393,37 @@ def _join_pass1_fn(mesh, axis, *, eps, bp, D, n_valid, interpret):
         program = simjoin_hits_rows_program(
             sched_l, eps=eps, bp=bp, D=D, n_valid=n_valid
         )
-        return launch(program, x, x, interpret=interpret)
+        return launch(program, x, x.T, interpret=interpret)[:, 0]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis, None), P(None, None)),
         out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
 
 @register_schedule_cache
 @functools.lru_cache(maxsize=64)
-def _join_pass2_fn(mesh, axis, *, eps, bp, D, cap, p_pad, n_valid, interpret):
+def _join_pass2_fn(mesh, axis, *, eps, bp, D, n_valid, halo, interpret):
+    """Pass 2 on every shard: the hit masks of its live table rows
+    (``halo`` picks the 5-col slot/global table over a resident+halo
+    buffer instead of the 3-col table over the replicated points)."""
+
     def body(table_l, x):
         program = simjoin_emit_program(
-            table_l, eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad,
-            n_valid=n_valid,
+            table_l, eps=eps, bp=bp, D=D, n_valid=n_valid, halo=halo,
         )
-        return launch(program, x, x, interpret=interpret)
+        return launch(program, x, x.T, interpret=interpret)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(P(axis, None), P(None, None)),
-        out_specs=P(axis, None),
-        check_rep=False,
+        in_specs=(P(axis, None), P(axis, None) if halo else P(None, None)),
+        out_specs=P(axis, None, None),
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -460,35 +456,15 @@ def _halo_pass1_fn(mesh, axis, *, eps, bp, D, n_valid, plan, interpret):
         program = simjoin_hits_rows_program(
             sched_l, eps=eps, bp=bp, D=D, n_valid=n_valid, halo=True
         )
-        return launch(program, buf, buf, interpret=interpret), buf
+        return launch(program, buf, buf.T, interpret=interpret)[:, 0], buf
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None))
         + tuple(P(axis, None) for _ in plan),
         out_specs=(P(axis, None), P(axis, None)),
-        check_rep=False,
-    )
-    return jax.jit(fn)
-
-
-@register_schedule_cache
-@functools.lru_cache(maxsize=64)
-def _halo_pass2_fn(mesh, axis, *, eps, bp, D, cap, p_pad, n_valid, interpret):
-    def body(table_l, buf_l):
-        program = simjoin_emit_halo_program(
-            table_l, eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad,
-            n_valid=n_valid,
-        )
-        return launch(program, buf_l, buf_l, interpret=interpret)
-
-    fn = _shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(axis, None), P(axis, None)),
-        out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -608,9 +584,10 @@ def simjoin_pairs_sharded(
     runs on the shard owning its *i* tile, and the only cross-device
     data motion is a ``ppermute`` of the boundary strips the reach mask
     names — a fixed-size halo buffer per shard, reused by pass 2.
-    Per-shard hit counts → global exclusive prefix sum on the host (the
-    inherent host sync of an exact-size join) → per-shard emission at
-    *local* offsets → host gather back into the global schedule order.
+    Per-shard hit counts → the non-empty rows and the exact pair count
+    on the host (the inherent host sync of an exact-size join) →
+    per-shard hit masks of those rows → one compaction in the global
+    schedule order.
     Pruned rows contribute zero pairs by construction of the reach
     mask, so the result is array-equal (not just set-equal) to
     ``ops.simjoin_pairs`` on every mesh size.
@@ -677,43 +654,31 @@ def _join_replicated(
     if P_total == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P_total, bp)
-    cap = min(max(8, -(-int(tot.max()) // 8) * 8), bp * bp)
-    offs = np.concatenate([[0], np.cumsum(tot)[:-1]])
+    # pass 2: each shard emits the masks of the non-empty tiles of its
+    # own contiguous range, padded to a uniform per-shard row count
     tot_pad = np.concatenate([tot, np.zeros(pad_rows, np.int64)])
-    offs_pad = np.concatenate([offs, np.zeros(pad_rows, np.int64)])
-    shard_tot = tot_pad.reshape(num, per).sum(axis=1)
-    base = np.concatenate([[0], np.cumsum(shard_tot)[:-1]])
-    local_off = offs_pad - np.repeat(base, per)
-    local_off[steps:] = 0  # sentinel rows never write
-    p_pad = -(-(int(shard_tot.max()) + cap) // 8) * 8
-    table = np.column_stack([tri_pad, local_off, tot_pad]).astype(np.int32)
-
-    # same VMEM-budget gate as the single-core wrapper, on the per-shard
-    # buffer (≈ mesh-size times smaller): past it, fall back to the dense
-    # oracle (pair SET equal, lexicographic order — see ops.simjoin_pairs)
-    probe = simjoin_emit_program(
-        table[:per], eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad,
-        n_valid=n_valid,
-    )
-    from repro.core import fits_vmem
-
-    if not fits_vmem(probe, xp, xp):
-        from . import ref
-
-        return jnp.asarray(ref.simjoin_pairs(x, eps))
-
+    live = (tot_pad > 0).reshape(num, per)
+    per2 = max(1, int(live.sum(axis=1).max()))
+    table = np.zeros((num, per2, 3), np.int32)
+    rows = []
+    for sh in range(num):
+        idx = np.nonzero(live[sh])[0]
+        table[sh, : len(idx), :2] = tri_pad[sh * per + idx]
+        table[sh, : len(idx), 2] = 1
+        rows.append(sh * per2 + np.arange(len(idx)))
     pass2 = _join_pass2_fn(
-        mesh, axis, eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad,
-        n_valid=n_valid, interpret=interp,
+        mesh, axis, eps=eps, bp=bp, D=D, n_valid=n_valid, halo=False,
+        interpret=interp,
     )
-    table_dev = jnp.asarray(table)
+    table_dev = jnp.asarray(table.reshape(num * per2, 3))
     if volume is not None:
         _acc_volume(volume, pass2, table_dev, xp, replicated=xp.nbytes)
-    out = pass2(table_dev, xp)  # (num * p_pad, 2)
-    parts = [
-        out[s * p_pad : s * p_pad + int(shard_tot[s])] for s in range(num)
-    ]
-    return jnp.concatenate(parts, axis=0)
+    masks = pass2(table_dev, xp)  # (num * per2, bp, bp)
+    # shards hold contiguous ranges of the global schedule, so shard
+    # order IS global order
+    return pairs_from_masks(
+        masks, np.concatenate(rows), tri[tot > 0], P_total, bp
+    )
 
 
 def _join_halo(
@@ -758,56 +723,30 @@ def _join_halo(
     if P_total == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P_total, bp)
-    cap = min(max(8, -(-int(tot.max()) // 8) * 8), bp * bp)
-    shard_tot = np.array(
-        [int(tot[row_ids[s]].sum()) for s in range(num)], dtype=np.int64
+    # pass 2: each shard emits the masks of its non-empty rows (slot +
+    # global columns), padded to a uniform per-shard row count
+    nz_rows = [r[tot[r] > 0] for r in row_ids]
+    per2 = max(1, max(len(r) for r in nz_rows))
+    table = np.zeros((num, per2, 5), np.int32)
+    pos = np.zeros(len(pruned), np.int64)  # row -> position in the masks
+    for sh in range(num):
+        local = np.searchsorted(row_ids[sh], nz_rows[sh])
+        table[sh, : len(local), :4] = sched[sh * per_h + local]
+        table[sh, : len(local), 4] = 1
+        pos[nz_rows[sh]] = sh * per2 + np.arange(len(local))
+    pass2 = _join_pass2_fn(
+        mesh, axis, eps=eps, bp=bp, D=D, n_valid=n_valid, halo=True,
+        interpret=interp,
     )
-    p_pad = -(-(int(shard_tot.max()) + cap) // 8) * 8
-    start = np.zeros(len(pruned), np.int64)  # row start, global buffer coords
-    table = np.zeros((num * per_h, 6), np.int32)
-    for s in range(num):
-        k = len(row_ids[s])
-        rt = tot[row_ids[s]]
-        loff = np.zeros(k, np.int64)
-        if k:
-            loff[1:] = np.cumsum(rt)[:-1]
-        start[row_ids[s]] = s * p_pad + loff
-        table[s * per_h : s * per_h + k, :4] = sched[s * per_h : s * per_h + k]
-        table[s * per_h : s * per_h + k, 4] = loff
-        table[s * per_h : s * per_h + k, 5] = rt
-
-    # VMEM gate on the per-shard program; the operands are the per-shard
-    # resident+halo buffer, not the full point set
-    probe = simjoin_emit_halo_program(
-        table[:per_h], eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad,
-        n_valid=n_valid,
-    )
-    bufl = jax.ShapeDtypeStruct((n_buf * bp, D), xs.dtype)
-    from repro.core import fits_vmem
-
-    if not fits_vmem(probe, bufl, bufl):
-        from . import ref
-
-        return jnp.asarray(ref.simjoin_pairs(x, eps))
-
-    pass2 = _halo_pass2_fn(
-        mesh, axis, eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad,
-        n_valid=n_valid, interpret=interp,
-    )
-    table_dev = jnp.asarray(table)
+    table_dev = jnp.asarray(table.reshape(num * per2, 5))
     if volume is not None:
         _acc_volume(volume, pass2, table_dev, buf)
-    out = pass2(table_dev, buf)  # (num * p_pad, 2)
-    # gather the shards' windows back into the GLOBAL pruned-row order —
+    masks = pass2(table_dev, buf)  # (num * per2, bp, bp)
+    # gather the shards' masks back into the GLOBAL pruned-row order —
     # which equals the full triangle order because pruned rows are
     # provably pair-free — so the result is array-equal to single-core
     nz = tot > 0
-    reps = tot[nz]
-    starts = start[nz]
-    csum = np.zeros(len(reps), np.int64)
-    csum[1:] = np.cumsum(reps)[:-1]
-    src = np.repeat(starts - csum, reps) + np.arange(int(reps.sum()))
-    return out[jnp.asarray(src)]
+    return pairs_from_masks(masks, pos[nz], pruned[nz], P_total, bp)
 
 
 # ---------------------------------------------------------------------------

@@ -13,25 +13,24 @@ hilbert_point_order` (d-dimensional ``hilbert_sort_key``) can pre-sort
 the *points* so ε-neighbours concentrate near the tile-grid diagonal
 (``hilbert_order=True`` in ops.py).
 
-Two outputs, one hit predicate (:func:`_hit_tile`, shared so counts and
-emitted pairs can never disagree):
+Two passes, one hit predicate (:func:`_hit_tile`, shared so counts and
+emitted pairs can never disagree).  The kernels read the i tile
+point-major (bp, D) and the j tile feature-major (D, bp), so the
+distance tile needs no transpose and per-point sums come out as
+lane-dense rows:
 
 * :func:`simjoin_tile_hits_swizzled` — per-step partial row/column hit
   sums (each output block written exactly once → safe under any
   schedule); ops.py scatter-adds them onto the point axis for
   ``simjoin_counts``, and their row-sum per step is the per-tile hit
-  total that drives pair emission.
-* :func:`simjoin_emit_swizzled` — the classic two-pass pair *emission*:
-  given per-tile exclusive offsets (prefix sum of pass-1 totals), each
-  grid step recomputes its hit tile, compacts the hit coordinates to the
-  front (stable argsort on the flattened mask → row-major in-tile order),
-  and masked-read-modify-writes a fixed-size window of the single
-  VMEM-resident (P_pad, 2) pair buffer at its offset.  Offsets partition
-  [0, P), so every row is validly written by exactly one step and the
-  masked tail writes preserve other steps' regions — order-free, in
-  FGF-Hilbert tile order.  The buffer must fit in VMEM (P_pad · 2 int32);
-  the last-dim-2 layout is interpret-validated (a TPU lowering would
-  lane-pad it).
+  total that sizes pair emission.
+* :func:`simjoin_emit_swizzled` — pass 2: for the tiles pass 1 found
+  non-empty, each grid step writes its (bp, bp) int8 hit mask to its own
+  output block (write-once, order-free); :func:`pairs_from_masks`
+  compacts the masks into (i, j) pairs with one exact-size
+  ``jnp.nonzero`` — schedule-then-row-major order, the order of the
+  tiles' rows.  Compaction by sort inside the kernel has no TPU
+  lowering; compaction of masks is one XLA pass.
 
 A diagonal tile counts each unordered pair once via a strict i<j mask; an
 off-diagonal (i_tile > j_tile) tile contributes row sums to the i side
@@ -48,17 +47,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import as_choice
-from repro.core.program import CurveProgram, fits_vmem
+from repro.core.program import CurveProgram
 
 from .launch import launch
 
 
 def check_pair_offsets(P_total: int, bp: int) -> None:
-    """Raise if the join's pair total would overflow the int32 offset
-    columns of the emission table (``p_pad = P + cap ≤ P + bp²`` must be
-    int32-addressable).  A raised :class:`ValueError`, not ``assert`` —
-    the guard must survive ``python -O``.  Shared by the single-core and
-    both sharded emission paths."""
+    """Raise if the join's pair total would overflow int32 (``P + bp²``
+    must stay int32-addressable).  A raised :class:`ValueError`, not
+    ``assert`` — the guard must survive ``python -O``.  Shared by the
+    single-core and both sharded emission paths."""
     if P_total + bp * bp >= 2**31:
         raise ValueError(
             f"pair count {P_total} overflows the int32 offsets "
@@ -80,24 +78,32 @@ def map_pairs_back(pairs: jax.Array, perm: jax.Array) -> jax.Array:
     )
 
 
-def _hit_tile(xiv, xjv, ti, tj, *, eps2: float, n_valid: int | None):
+_LANES = (((1,), (1,)), ((), ()))  # contract the lane axes: A @ B^T
+
+
+def _hit_tile(xiv, xjTv, ti, tj, *, eps2: float, n_valid):
     """Boolean (bp, bp) hit mask of tile pair (ti, tj), pairs counted once.
 
-    Shared by the count and emit kernels — the single source of truth for
-    what an ε-hit is (threshold form, diagonal strictness, ragged-N
-    masking), so pass-1 totals always equal pass-2 emission counts.
+    ``xiv`` is the (bp, D) i tile, ``xjTv`` the feature-major (D, bp) j
+    tile.  Shared by the count and emit kernels — the single source of
+    truth for what an ε-hit is (threshold form, diagonal strictness,
+    ragged-N masking), so pass-1 totals always equal pass-2 emission
+    counts.
     """
     xi = xiv.astype(jnp.float32)  # (bp, d)
-    xj = xjv.astype(jnp.float32)  # (bp, d)
+    xjT = xjTv.astype(jnp.float32)  # (d, bp)
     d2 = (
-        jnp.sum(xi**2, axis=1)[:, None]
-        - 2.0 * jnp.dot(xi, xj.T, preferred_element_type=jnp.float32)
-        + jnp.sum(xj**2, axis=1)[None, :]
+        jnp.sum(xi * xi, axis=1, keepdims=True)
+        - 2.0 * jnp.dot(
+            xi, xjT, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        + jnp.sum(xjT * xjT, axis=0, keepdims=True)
     )
     hit = d2 <= eps2
     ii = jax.lax.broadcasted_iota(jnp.int32, hit.shape, 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, hit.shape, 1)
-    hit = jnp.logical_and(hit, jnp.where(ti == tj, ii > jj, True))
+    hit = jnp.logical_and(hit, (ii > jj) | (ti != tj))
     if n_valid is not None:
         # ragged N: the pad rows are plain zeros (which WOULD ε-join each
         # other — and huge magic values would overflow f32); mask them by
@@ -109,16 +115,26 @@ def _hit_tile(xiv, xjv, ti, tj, *, eps2: float, n_valid: int | None):
     return hit
 
 
+def _hit_sums(hit):
+    """(row sums over j, column sums over i) of a hit tile as (1, bp)
+    int32 rows — ones-vector matmuls on the MXU, exact for 0/1 terms."""
+    h = hit.astype(jnp.float32)
+    ones = jnp.ones((1, h.shape[0]), jnp.float32)
+    rows = jax.lax.dot_general(ones, h, _LANES, preferred_element_type=jnp.float32)
+    cols = jnp.dot(ones, h, preferred_element_type=jnp.float32)
+    return rows.astype(jnp.int32), cols.astype(jnp.int32)
+
+
 def _join_kernel(
-    sched_ref, xi_ref, xj_ref, hi_out, hj_out, *, eps2: float, n_valid: int | None
+    sched_ref, xi_ref, xjT_ref, hi_out, hj_out, *, eps2: float,
+    n_valid: int | None,
 ):
     s = pl.program_id(0)
     hit = _hit_tile(
-        xi_ref[...], xj_ref[...], sched_ref[s, 0], sched_ref[s, 1],
+        xi_ref[...], xjT_ref[...], sched_ref[s, 0], sched_ref[s, 1],
         eps2=eps2, n_valid=n_valid,
     )
-    hi_out[0] = jnp.sum(hit.astype(jnp.int32), axis=1)
-    hj_out[0] = jnp.sum(hit.astype(jnp.int32), axis=0)
+    hi_out[0], hj_out[0] = _hit_sums(hit)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
@@ -143,7 +159,8 @@ def simjoin_tile_hits_swizzled(
     program = simjoin_hits_program(
         schedule, eps=eps, bp=bp, D=D, n_valid=n_valid
     )
-    return launch(program, x, x, interpret=interpret)
+    hi, hj = launch(program, x, x.T, interpret=interpret)
+    return hi[:, 0], hj[:, 0]
 
 
 def simjoin_hits_program(
@@ -151,7 +168,8 @@ def simjoin_hits_program(
     choice=None,
 ) -> CurveProgram:
     """Pass-1 declaration: one (1, bp) row/col partial pair per schedule
-    step, each written exactly once — safe under any order, so the SAME
+    step (operands: points (N, D) and their transpose (D, N)), each
+    written exactly once — safe under any order, so the SAME
     program serves the single-core triangle schedule and each shard's
     curve-range slice of it (kernels/sharded.py).  ``choice`` (a
     ``triangle``-kind :class:`repro.core.ScheduleChoice` or curve name)
@@ -168,46 +186,53 @@ def simjoin_hits_program(
         kernel=functools.partial(
             _join_kernel, eps2=float(eps) ** 2, n_valid=n_valid
         ),
-        in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 1], 0)),
-        ),
+        in_specs=_tile_pair_specs(bp, D, 0, 1),
         out_specs=[
-            pl.BlockSpec((1, bp), lambda s, sr: (s, 0)),
-            pl.BlockSpec((1, bp), lambda s, sr: (s, 0)),
+            pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
+            pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((steps, bp), jnp.int32),
-            jax.ShapeDtypeStruct((steps, bp), jnp.int32),
+            jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
         ],
         columns=("i", "j"),
     )
 
 
+def _tile_pair_specs(bp: int, D: int, ci: int, cj: int):
+    """Block specs of the (x, xT) operand pair: the i tile (bp, D) from
+    schedule column ``ci``, the feature-major j tile (D, bp) from ``cj``."""
+    return (
+        pl.BlockSpec((bp, D), lambda s, sr: (sr[s, ci], 0)),
+        pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, cj])),
+    )
+
+
 def _join_rows_kernel(
-    sched_ref, xi_ref, xj_ref, hi_out, *, eps2: float, n_valid: int | None,
+    sched_ref, xi_ref, xjT_ref, hi_out, *, eps2: float, n_valid: int | None,
     gi_col: int, gj_col: int,
 ):
     s = pl.program_id(0)
     hit = _hit_tile(
-        xi_ref[...], xj_ref[...], sched_ref[s, gi_col], sched_ref[s, gj_col],
+        xi_ref[...], xjT_ref[...], sched_ref[s, gi_col], sched_ref[s, gj_col],
         eps2=eps2, n_valid=n_valid,
     )
-    hi_out[0] = jnp.sum(hit.astype(jnp.int32), axis=1)
+    hi_out[0] = _hit_sums(hit)[0]
 
 
 def simjoin_hits_rows_program(
     schedule, *, eps: float, bp: int, D: int, n_valid: int | None,
     halo: bool = False,
 ) -> CurveProgram:
-    """Pass-1 declaration emitting ONLY the per-step row sums — the pair
-    emission's prefix-sum input.  The sharded wrapper uses this instead
+    """Pass-1 declaration emitting ONLY the per-step row sums — whose
+    totals pick the tiles pair emission visits.  The sharded wrapper uses this instead
     of :func:`simjoin_hits_program` so the shard_map never materialises
     (or transfers) the unused column partials.
 
-    ``halo=False``: 2-col ``(i, j)`` schedule over one global point
-    buffer.  ``halo=True``: 4-col ``(i_slot, j_slot, i, j)`` schedule
-    over a shard's resident+halo buffer — the *slot* columns drive the
+    Operands: the points and their transpose.  ``halo=False``: 2-col
+    ``(i, j)`` schedule over one global point buffer.  ``halo=True``:
+    4-col ``(i_slot, j_slot, i, j)`` schedule over a shard's
+    resident+halo buffer — the *slot* columns drive the
     BlockSpec index maps (where a tile lives in the local buffer), the
     *global* tile ids drive :func:`_hit_tile`'s diagonal strictness and
     ragged-N masking, which are defined on global point indices.
@@ -221,12 +246,9 @@ def simjoin_hits_rows_program(
             _join_rows_kernel, eps2=float(eps) ** 2, n_valid=n_valid,
             gi_col=gi_col, gj_col=gj_col,
         ),
-        in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 1], 0)),
-        ),
-        out_specs=pl.BlockSpec((1, bp), lambda s, sr: (s, 0)),
-        out_shape=jax.ShapeDtypeStruct((steps, bp), jnp.int32),
+        in_specs=_tile_pair_specs(bp, D, 0, 1),
+        out_specs=pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
         columns=("i_slot", "j_slot", "i", "j") if halo else ("i", "j"),
     )
 
@@ -259,116 +281,122 @@ def simjoin_counts_swizzled(
 
 
 # ---------------------------------------------------------------------------
-# Pass 2: pair emission at prefetched per-tile offsets
+# Pass 2: hit masks of the non-empty tiles, compacted into pairs
 # ---------------------------------------------------------------------------
 
-def _emit_tile(
-    xi, xj, ti, tj, off, tot, o_ref, *, eps2: float, n_valid: int | None,
-    cap: int, bp: int,
-):
-    """Shared emission body: recompute the hit tile, compact, masked-RMW a
-    cap-row window at ``off``.  ``ti``/``tj`` are GLOBAL tile ids (pair
-    indices and the hit mask are defined on global point indices); the
-    caller's BlockSpecs decide where ``xi``/``xj`` came from."""
-    hit = _hit_tile(xi, xj, ti, tj, eps2=eps2, n_valid=n_valid)
-    # compact hit coordinates to the front: stable sort on the flattened
-    # miss mask keeps hits first, in row-major in-tile order
-    lin = jnp.where(hit.reshape(-1), 0, 1).astype(jnp.int32)
-    idx = jnp.argsort(lin, stable=True)[:cap].astype(jnp.int32)
-    gi = ti * bp + idx // bp
-    gj = tj * bp + idx % bp
-    pairs = jnp.stack([gi, gj], axis=1)  # (cap, 2)
-    valid = jax.lax.broadcasted_iota(jnp.int32, (cap, 2), 0) < tot
-    # masked RMW of this tile's window of the resident pair buffer: rows
-    # past `tot` belong to other steps (offsets partition [0, P)) and are
-    # written back unchanged
-    window = o_ref[pl.ds(off, cap), :]
-    o_ref[pl.ds(off, cap), :] = jnp.where(valid, pairs, window)
-
-
 def _emit_kernel(
-    sched_ref, xi_ref, xj_ref, o_ref, *, eps2: float, n_valid: int | None,
-    cap: int, bp: int,
+    sched_ref, xi_ref, xjT_ref, o_ref, *, eps2: float, n_valid: int | None,
+    gi_col: int, gj_col: int, live_col: int,
 ):
     s = pl.program_id(0)
-    _emit_tile(
-        xi_ref[...], xj_ref[...], sched_ref[s, 0], sched_ref[s, 1],
-        sched_ref[s, 2], sched_ref[s, 3], o_ref,
-        eps2=eps2, n_valid=n_valid, cap=cap, bp=bp,
+    hit = _hit_tile(
+        xi_ref[...], xjT_ref[...], sched_ref[s, gi_col], sched_ref[s, gj_col],
+        eps2=eps2, n_valid=n_valid,
+    )
+    hit = jnp.logical_and(hit, sched_ref[s, live_col] == 1)
+    o_ref[0] = hit.astype(jnp.int32).astype(o_ref.dtype)
+
+
+def simjoin_emit_program(
+    table, *, eps: float, bp: int, D: int, n_valid: int | None,
+    halo: bool = False, choice=None,
+) -> CurveProgram:
+    """Pass-2 declaration: one (bp, bp) int8 hit mask per table row,
+    each written once.  ``halo=False``: 3-col rows ``(i, j, live)``
+    over one point buffer; ``halo=True``: 5-col rows ``(i_slot, j_slot,
+    i, j, live)`` over a shard's resident+halo buffer (slots drive the
+    index maps, global ids the hit predicate).  ``live == 0`` rows are
+    SPMD / bucket padding and write all-zero masks.  Operands: the
+    points and their transpose."""
+    if choice is not None:
+        choice = as_choice(choice, kind="triangle").with_(block=(int(bp),))
+    steps = table.shape[0]
+    gi_col, gj_col, live_col = (2, 3, 4) if halo else (0, 1, 2)
+    return CurveProgram(
+        name="simjoin_emit_halo" if halo else "simjoin_emit",
+        schedule=table,
+        choice=choice,
+        kernel=functools.partial(
+            _emit_kernel, eps2=float(eps) ** 2, n_valid=n_valid,
+            gi_col=gi_col, gj_col=gj_col, live_col=live_col,
+        ),
+        in_specs=_tile_pair_specs(bp, D, 0, 1),
+        out_specs=pl.BlockSpec((1, bp, bp), lambda s, sr: (s, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps, bp, bp), jnp.int8),
+        columns=(
+            ("i_slot", "j_slot", "i", "j", "live") if halo
+            else ("i", "j", "live")
+        ),
     )
 
 
-def _emit_halo_kernel(
-    sched_ref, xi_ref, xj_ref, o_ref, *, eps2: float, n_valid: int | None,
-    cap: int, bp: int,
-):
-    s = pl.program_id(0)
-    _emit_tile(
-        xi_ref[...], xj_ref[...], sched_ref[s, 2], sched_ref[s, 3],
-        sched_ref[s, 4], sched_ref[s, 5], o_ref,
-        eps2=eps2, n_valid=n_valid, cap=cap, bp=bp,
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("eps", "bp", "cap", "p_pad", "n_valid", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
 def simjoin_emit_swizzled(
     table: jax.Array,
     x: jax.Array,
     *,
     eps: float,
     bp: int,
-    cap: int,
-    p_pad: int,
     n_valid: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Emit the ε-join's (i, j) index pairs, i > j, into a (p_pad, 2) buffer.
-
-    table: int32[steps, 4] rows ``(i_tile, j_tile, offset, total)`` where
-    ``offset`` is the exclusive prefix sum of the pass-1 per-tile totals
-    and ``cap`` a static per-tile capacity >= max total (ops.py derives
-    both from :func:`simjoin_tile_hits_swizzled`).  Rows [0, sum(total))
-    of the result are the pairs in schedule-then-row-major order; the
-    tail is garbage to slice off.  ``p_pad`` must be >= sum(total) + cap
-    so every step's window is in bounds.
-    """
+    """Hit masks int8[rows, bp, bp] of the ``(i_tile, j_tile, live)``
+    rows of ``table`` (ops.py passes the tiles pass 1 found non-empty,
+    padded to a power-of-two row count with ``live=0``).
+    :func:`pairs_from_masks` turns them into pairs."""
     N, D = x.shape
-    assert N % bp == 0 and cap <= bp * bp and p_pad >= cap
-    program = simjoin_emit_program(
-        table, eps=eps, bp=bp, D=D, cap=cap, p_pad=p_pad, n_valid=n_valid
-    )
-    return launch(program, x, x, interpret=interpret)
+    assert N % bp == 0
+    program = simjoin_emit_program(table, eps=eps, bp=bp, D=D, n_valid=n_valid)
+    return launch(program, x, x.T, interpret=interpret)
 
 
-def simjoin_emit_program(
-    table, *, eps: float, bp: int, D: int, cap: int, p_pad: int,
-    n_valid: int | None, choice=None,
-) -> CurveProgram:
-    """Pass-2 declaration: the single resident (p_pad, 2) pair buffer is
-    masked-RMW'd a cap-row window per step at prefetched offsets.  The
-    ``p_pad·2`` int32 residency is what the ops wrapper gates against
-    the VMEM budget (falling back to the dense oracle).  With per-shard
-    tables carrying *local* offsets, the same program is the emission
-    half of the distributed two-pass join."""
-    if choice is not None:
-        choice = as_choice(choice, kind="triangle").with_(block=(int(bp),))
-    return CurveProgram(
-        name="simjoin_emit",
-        schedule=table,
-        choice=choice,
-        kernel=functools.partial(
-            _emit_kernel, eps2=float(eps) ** 2, n_valid=n_valid, cap=cap, bp=bp
-        ),
-        in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 1], 0)),
-        ),
-        out_specs=pl.BlockSpec((p_pad, 2), lambda s, sr: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p_pad, 2), jnp.int32),
-        columns=("i", "j", "offset", "total"),
+def _bucket(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+@functools.partial(jax.jit, static_argnames=("bp", "size"))
+def _compact(masks, rows, tiles, *, bp: int, size: int):
+    flat = masks[rows].reshape(-1)
+    (f,) = jnp.nonzero(flat, size=size, fill_value=0)
+    t = tiles[f // (bp * bp)]
+    r = f % (bp * bp)
+    return jnp.stack([t[:, 0] * bp + r // bp, t[:, 1] * bp + r % bp], axis=1)
+
+
+def pairs_from_masks(masks, rows, tiles, P: int, bp: int) -> jax.Array:
+    """int32[P, 2] pairs of the hit masks ``masks[rows]``, in row order
+    then row-major in-tile order.  ``tiles`` int[len(rows), 2] holds the
+    global (i_tile, j_tile) of each listed row; ``P`` is the pass-1
+    total, so the compaction has its exact size."""
+    rows = np.asarray(rows, dtype=np.int32)
+    if len(rows) * bp * bp >= 2**31:
+        raise ValueError(
+            f"{len(rows)} non-empty tiles of {bp}x{bp} exceed int32 mask "
+            f"indexing; reduce eps or join in chunks"
+        )
+    n = _bucket(len(rows))
+    rows_p = np.zeros(n, np.int32)
+    rows_p[: len(rows)] = rows
+    tiles_p = np.zeros((n, 2), np.int32)
+    tiles_p[: len(rows)] = tiles
+    # padded rows repeat mask 0, but the compaction stops at P hits —
+    # all of them in the listed rows, which come first
+    out = _compact(
+        masks, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp, size=_bucket(P)
     )
+    return out[:P]
+
+
+def emission_table(tiles, live) -> np.ndarray:
+    """Pass-2 table: the rows of ``tiles`` (int[n, C]) plus a ``live``
+    column, padded with dead rows to a power of two so few row counts
+    compile."""
+    tiles = np.asarray(tiles, dtype=np.int32).reshape(len(live), -1)
+    n = _bucket(max(len(tiles), 1))
+    out = np.zeros((n, tiles.shape[1] + 1), np.int32)
+    out[: len(tiles), :-1] = tiles
+    out[: len(tiles), -1] = np.asarray(live, dtype=np.int32)
+    return out
 
 
 def simjoin_pairs_scheduled(
@@ -379,27 +407,23 @@ def simjoin_pairs_scheduled(
     bp: int,
     n_valid: int | None = None,
     interpret: bool = False,
-) -> jax.Array | None:
+) -> jax.Array:
     """Two-pass pair emission over an ARBITRARY lower-triangle tile-pair
     schedule: int32[P, 2] local-index pairs, i > j, in schedule-then-
-    row-major order — or ``None`` when the resident (p_pad, 2) emission
-    buffer would exceed the configured VMEM budget (callers choose their
-    own fallback oracle).
+    row-major order.
 
     ``schedule`` is any int32[steps, 2] set of (i_tile >= j_tile) pairs
     — the FGF-Hilbert triangle for the one-shot join (ops.py), or the
     halo-pruned cohort×resident restriction the streaming service
-    builds each tick (serve/apps.py).  This driver owns the prefix-sum
-    / cap / padding arithmetic BETWEEN the two kernel dispatches
-    (pass-1 totals → host exclusive prefix sum → 4-column emission
-    table), so the batch and streaming joins cannot diverge on it.
-    ``xp``: (Np, D) with Np % bp == 0 (callers pad; ``n_valid`` is the
-    true row count when padding exists).
+    builds each tick (serve/apps.py).  This function owns the host step
+    BETWEEN the two kernel dispatches (pass-1 totals → the non-empty
+    tiles and the exact pair count), so the batch and streaming joins
+    cannot diverge on it.  ``xp``: (Np, D) with Np % bp == 0 (callers
+    pad; ``n_valid`` is the true row count when padding exists).
     """
     tri = np.asarray(schedule, dtype=np.int32)
     if tri.shape[0] == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
-    D = xp.shape[1]
     hits_i, _ = simjoin_tile_hits_swizzled(
         jnp.asarray(tri), xp, eps=float(eps), bp=bp, n_valid=n_valid,
         interpret=interpret,
@@ -409,48 +433,10 @@ def simjoin_pairs_scheduled(
     if P == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P, bp)
-    # static per-tile window: max per-tile total, rounded up but never
-    # past the bp*bp tile size (the argsort compaction's slice bound)
-    cap = min(max(8, -(-int(tot.max()) // 8) * 8), bp * bp)
-    offs = np.concatenate([[0], np.cumsum(tot)[:-1]])
-    p_pad = -(-(P + cap) // 8) * 8
-    table = np.column_stack([tri, offs, tot]).astype(np.int32)
-    emit_prog = simjoin_emit_program(
-        jnp.asarray(table), eps=float(eps), bp=bp, D=D, cap=cap,
-        p_pad=p_pad, n_valid=n_valid,
+    nz = tri[tot > 0]
+    table = emission_table(nz, np.ones(len(nz)))
+    masks = simjoin_emit_swizzled(
+        jnp.asarray(table), xp, eps=float(eps), bp=bp, n_valid=n_valid,
+        interpret=interpret,
     )
-    if not fits_vmem(emit_prog, xp, xp):
-        return None
-    out = simjoin_emit_swizzled(
-        jnp.asarray(table), xp, eps=float(eps), bp=bp, cap=cap,
-        p_pad=p_pad, n_valid=n_valid, interpret=interpret,
-    )
-    return out[:P]
-
-
-def simjoin_emit_halo_program(
-    table, *, eps: float, bp: int, D: int, cap: int, p_pad: int,
-    n_valid: int | None,
-) -> CurveProgram:
-    """Pass-2 declaration for the halo-exchange join: 6-col rows
-    ``(i_slot, j_slot, i, j, offset, total)``.  Slot columns index a
-    shard's resident+halo point buffer, global tile ids produce the pair
-    indices, ``offset`` is shard-LOCAL (each shard owns its own
-    (p_pad, 2) buffer; the host re-gathers the shards' windows back into
-    the global schedule order).  Zero-``total`` sentinel rows never
-    write, so SPMD row padding is inert."""
-    return CurveProgram(
-        name="simjoin_emit_halo",
-        schedule=table,
-        kernel=functools.partial(
-            _emit_halo_kernel, eps2=float(eps) ** 2, n_valid=n_valid,
-            cap=cap, bp=bp,
-        ),
-        in_specs=(
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
-            pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 1], 0)),
-        ),
-        out_specs=pl.BlockSpec((p_pad, 2), lambda s, sr: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p_pad, 2), jnp.int32),
-        columns=("i_slot", "j_slot", "i", "j", "offset", "total"),
-    )
+    return pairs_from_masks(masks, np.arange(len(nz)), nz, P, bp)

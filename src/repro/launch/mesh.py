@@ -23,7 +23,10 @@ def make_production_mesh(*, multi_pod: bool = False, hilbert_layout: bool = Fals
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     if not hilbert_layout:
-        return jax.make_mesh(shape, axes)
+        # Auto axes: the models place arrays with sharding constraints,
+        # which jax.make_mesh's default Explicit axes refuse
+        auto = (jax.sharding.AxisType.Auto,) * len(axes)
+        return jax.make_mesh(shape, axes, axis_types=auto)
     # Hilbert layout: permute devices so the logical grid walk is a
     # Hilbert walk over the physical (row-major) torus coordinates.
     from jax.sharding import Mesh

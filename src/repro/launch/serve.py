@@ -12,11 +12,13 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, applicable_shapes, get_config, get_reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serve import ServeEngine
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true", default=True)

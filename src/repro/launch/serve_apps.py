@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import StreamKMeans, StreamSimJoin
 
 
@@ -32,6 +33,7 @@ def _drive(svc, submit, chunks, ticks_after: int = 0):
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", choices=("kmeans", "simjoin", "both"),
                     default="both")

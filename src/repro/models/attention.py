@@ -269,7 +269,7 @@ def gqa_decode(params, x, cfg: ModelConfig, cache, pos):
 # paged decode (GQA)
 # ---------------------------------------------------------------------------
 #
-# The serving cache is a physical page pool (P, page_size, Hkv, D) shared
+# The serving cache is a physical page pool (P, Hkv, page_size, D) shared
 # by all slots, addressed through an int32[B, max_pages] page table
 # (see repro.serve.kv_pages).  Physical page 0 is the reserved trash
 # page: unallocated table entries point at it, and writes from masked
@@ -281,35 +281,45 @@ def gqa_decode(params, x, cfg: ModelConfig, cache, pos):
 def _paged_write(pages, new, page_table, pos, write_mask):
     """Scatter one token per slot into the physical pool.
 
-    pages: (P, ps, Hkv, D); new: (B, Hkv, D); pos: int32[B].  Slots with
+    pages: (P, Hkv, ps, D); new: (B, Hkv, D); pos: int32[B].  Slots with
     ``write_mask == False`` write to the trash page instead (scatter
     collisions inside page 0 are harmless — it is never attended)."""
-    ps = pages.shape[1]
+    ps = pages.shape[2]
     B = pos.shape[0]
     rows = jnp.arange(B, dtype=jnp.int32)
     phys = page_table[rows, pos // ps]
     if write_mask is not None:
         phys = jnp.where(write_mask, phys, 0)
-    return pages.at[phys, pos % ps].set(new.astype(pages.dtype))
+    return pages.at[phys, :, pos % ps].set(new.astype(pages.dtype))
 
 
 def _paged_write_many(pages, new, page_table, pos0, write_mask):
     """Scatter T tokens per slot into the physical pool (the prefill
     twin of :func:`_paged_write`).
 
-    pages: (P, ps, Hkv, D); new: (B, T, Hkv, D) with token i of slot b
+    pages: (P, Hkv, ps, D); new: (B, T, Hkv, D) with token i of slot b
     at absolute position ``pos0[b] + i``; write_mask: bool (B, T) —
     padded / inactive lanes are diverted to the trash page (their
     logical page index is also clamped so out-of-range pad positions
     never index past the table)."""
-    ps = pages.shape[1]
+    ps = pages.shape[2]
     MP = page_table.shape[1]
     B, T = new.shape[:2]
     positions = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
     lp = jnp.minimum(positions // ps, MP - 1)
     phys = page_table[jnp.arange(B, dtype=jnp.int32)[:, None], lp]
     phys = jnp.where(write_mask, phys, 0)
-    return pages.at[phys, positions % ps].set(new.astype(pages.dtype))
+    return pages.at[phys, :, positions % ps].set(new.astype(pages.dtype))
+
+
+def _gather_pages(pages, page_table):
+    """XLA gather of every slot's pages into a (B, MP*ps, Hkv, D)
+    sequence view — the reference path's twin of the flash kernels'
+    page-by-page DMA."""
+    B, MP = page_table.shape
+    _, Hkv, ps, D = pages.shape
+    g = pages[page_table]  # (B, MP, Hkv, ps, D)
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, MP * ps, Hkv, D)
 
 
 def _sdpa_prefix(q, k, v, mask):
@@ -334,8 +344,8 @@ def _sdpa_prefix(q, k, v, mask):
 def gqa_init_pages(cfg: ModelConfig, num_pages: int, page_size: int, dtype):
     hkv, dh = cfg.num_kv_heads, cfg.attn_head_dim
     return {
-        "k_pages": jnp.zeros((num_pages, page_size, hkv, dh), dtype),
-        "v_pages": jnp.zeros((num_pages, page_size, hkv, dh), dtype),
+        "k_pages": jnp.zeros((num_pages, hkv, page_size, dh), dtype),
+        "v_pages": jnp.zeros((num_pages, hkv, page_size, dh), dtype),
     }
 
 
@@ -368,11 +378,10 @@ def gqa_decode_paged(params, x, cfg: ModelConfig, pools, pos, page_table, *,
         )
         out = out.reshape(B, 1, H * Dh).astype(x.dtype)
     else:
-        ps = pools["k_pages"].shape[1]
-        MP = page_table.shape[1]
-        k_all = pools["k_pages"][page_table].reshape(B, MP * ps, Hkv, Dh)
-        v_all = pools["v_pages"][page_table].reshape(B, MP * ps, Hkv, Dh)
-        valid = jnp.arange(MP * ps, dtype=jnp.int32)[None] <= pos[:, None]
+        k_all = _gather_pages(pools["k_pages"], page_table)
+        v_all = _gather_pages(pools["v_pages"], page_table)
+        kv_len = k_all.shape[1]
+        valid = jnp.arange(kv_len, dtype=jnp.int32)[None] <= pos[:, None]
         out = _sdpa(q, k_all, v_all, causal=False, kv_len_mask=valid)
         out = out.reshape(B, 1, -1)
     return out @ params["wo"], pools
@@ -410,12 +419,11 @@ def gqa_prefill_paged(params, x, cfg: ModelConfig, pools, pos0, n_new,
         )
         out = out.reshape(B, T, H * Dh).astype(x.dtype)
     else:
-        ps = pools["k_pages"].shape[1]
-        MP = page_table.shape[1]
-        k_all = pools["k_pages"][page_table].reshape(B, MP * ps, Hkv, Dh)
-        v_all = pools["v_pages"][page_table].reshape(B, MP * ps, Hkv, Dh)
+        k_all = _gather_pages(pools["k_pages"], page_table)
+        v_all = _gather_pages(pools["v_pages"], page_table)
+        kv_len = k_all.shape[1]
         mask = (
-            jnp.arange(MP * ps, dtype=jnp.int32)[None, None]
+            jnp.arange(kv_len, dtype=jnp.int32)[None, None]
             <= positions[:, :, None]
         )
         out = _sdpa_prefix(q, k_all, v_all, mask)
@@ -605,9 +613,9 @@ def mla_init_pages(cfg: ModelConfig, num_pages: int, page_size: int, dtype):
     """MLA pages the *compressed* latent: one pool leaf of width
     kv_lora_rank + qk_rope_head_dim per position (c_kv ⊕ k_rope), with a
     singleton kv-head axis so the pool shape matches the decode kernel's
-    (P, ps, Hkv, D) contract."""
+    (P, Hkv, ps, D) contract."""
     w = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-    return {"kv_pages": jnp.zeros((num_pages, page_size, 1, w), dtype)}
+    return {"kv_pages": jnp.zeros((num_pages, 1, page_size, w), dtype)}
 
 
 def mla_decode_paged(params, x, cfg: ModelConfig, pools, pos, page_table, *,
@@ -653,9 +661,8 @@ def mla_decode_paged(params, x, cfg: ModelConfig, pools, pos, page_table, *,
         out = jnp.einsum("bhr,rhd->bhd", ctx, w_v.astype(jnp.float32))
         out = out[:, None].astype(x.dtype)  # (B,1,h,dv)
     else:
-        ps = pools["kv_pages"].shape[1]
-        MP = page_table.shape[1]
-        kv_all = pools["kv_pages"][page_table].reshape(B, MP * ps, r + dr)
+        kv_all = _gather_pages(pools["kv_pages"], page_table)[:, :, 0]
+        kv_len = kv_all.shape[1]
         c_all, kr_all = kv_all[..., :r], kv_all[..., r:]
         scores = (
             jnp.einsum("bqhr,bkr->bhqk", q_lat, c_all.astype(jnp.float32))
@@ -663,7 +670,7 @@ def mla_decode_paged(params, x, cfg: ModelConfig, pools, pos, page_table, *,
                 "bqhd,bkd->bhqk", q_rope.astype(jnp.float32), kr_all.astype(jnp.float32)
             )
         ) * scale
-        valid = (jnp.arange(MP * ps, dtype=jnp.int32)[None] <= pos[:, None])[:, None, None]
+        valid = (jnp.arange(kv_len, dtype=jnp.int32)[None] <= pos[:, None])[:, None, None]
         scores = jnp.where(valid, scores, NEG_INF)
         p = jax.nn.softmax(scores, axis=-1)
         ctx = jnp.einsum("bhqk,bkr->bqhr", p, c_all.astype(jnp.float32))
@@ -711,9 +718,8 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, pools, pos0, n_new,
         out = jnp.einsum("bqhr,rhd->bqhd", ctx, w_v.astype(jnp.float32))
         out = out.astype(x.dtype)
     else:
-        ps = pools["kv_pages"].shape[1]
-        MP = page_table.shape[1]
-        kv_all = pools["kv_pages"][page_table].reshape(B, MP * ps, r + dr)
+        kv_all = _gather_pages(pools["kv_pages"], page_table)[:, :, 0]
+        kv_len = kv_all.shape[1]
         c_all, kr_all = kv_all[..., :r], kv_all[..., r:]
         scores = (
             jnp.einsum("bqhr,bkr->bhqk", q_lat, c_all.astype(jnp.float32))
@@ -722,7 +728,7 @@ def mla_prefill_paged(params, x, cfg: ModelConfig, pools, pos0, n_new,
             )
         ) * scale
         mask = (
-            jnp.arange(MP * ps, dtype=jnp.int32)[None, None]
+            jnp.arange(kv_len, dtype=jnp.int32)[None, None]
             <= positions[:, :, None]
         )[:, None]  # (B, 1, T, S) over the head axis
         scores = jnp.where(mask, scores, NEG_INF)

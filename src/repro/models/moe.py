@@ -22,13 +22,6 @@ from jax.sharding import PartitionSpec as P
 from .config import ModelConfig
 from .layers import dense_init, matrix_spec
 
-# jax >= 0.5 exports shard_map at top level; 0.4.x only has the
-# experimental module (jax.shard_map raises AttributeError there, so the
-# getattr default — not a try/except around the attribute — is required)
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def init_moe(key, cfg: ModelConfig, dtype):
     d, E, f = cfg.d_model, cfg.num_experts, cfg.d_ff_expert
@@ -215,7 +208,7 @@ def moe_forward(params, x, cfg: ModelConfig, token_mask=None, lossless=False):
             out = jax.lax.psum(out.astype(x.dtype), "model")
             return out.reshape(Bl, -1, d)
 
-        out_bsd = _shard_map(
+        out_bsd = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(

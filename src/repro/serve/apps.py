@@ -50,7 +50,7 @@ import numpy as np
 
 from repro.core import hilbert_encode_nd
 from repro.core.neighbors import halo_ranges
-from repro.core.program import fits_vmem
+from repro.core.program import fused_fits
 from repro.core.schedule import (
     kmeans_schedule,
     kmeans_schedule_device,
@@ -59,11 +59,13 @@ from repro.core.schedule import (
 )
 from repro.kernels.kmeans import (
     _OrderCache,
+    centroid_norms,
     hilbert_point_order_cached,
     kmeans_assign_swizzled,
     kmeans_init,
     kmeans_lloyd_fused,
     kmeans_lloyd_program,
+    lloyd_update,
     kmeans_lloyd_reference,
 )
 from repro.kernels.launch import launch, resolve_interpret
@@ -114,13 +116,12 @@ def _decayed_lloyd_step(
         schedule, pt=Np // bp, ct=Kp // bc, bp=bp, bc=bc, D=D,
         k_valid=k_valid, n_valid=n_valid,
     )
-    cnorm = jnp.sum(cp**2, axis=1)[None, :]
-    _min_m, arg, sums, cnt = launch(prog, xp, cp, cnorm, interpret=interpret)
-    S = (1.0 - decay) * S + sums
+    _min_m, arg, sums_t, cnt = launch(
+        prog, xp.T, cp, centroid_norms(cp), interpret=interpret
+    )
+    S = (1.0 - decay) * S + sums_t
     C = (1.0 - decay) * C + cnt
-    cw = C[0][:, None]
-    c_new = jnp.where(cw > 0, S / jnp.maximum(cw, 1.0), cp)
-    return c_new, arg.reshape(Np), S, C
+    return lloyd_update(cp, S, C), arg.reshape(Np), S, C
 
 
 class StreamKMeans:
@@ -293,7 +294,7 @@ class StreamKMeans:
                 jnp.pad(c0, ((0, pc), (0, 0))) if pc else c0
             ).astype(jnp.float32)
             Kp = self._c.shape[0]
-            self._S = jnp.zeros((Kp, D), jnp.float32)
+            self._S = jnp.zeros((D, Kp), jnp.float32)  # transposed sums
             self._C = jnp.zeros((1, Kp), jnp.float32)
         pt, ct = xp.shape[0] // bp, self._c.shape[0] // bc
         k_valid = self.k if pc else None
@@ -307,7 +308,7 @@ class StreamKMeans:
             # bench can separate compile ticks from warm ticks
             self._signatures.add(prog.signature)
             self.core.count("new_tick_shape")
-        cnorm_probe = jax.ShapeDtypeStruct((1, self._c.shape[0]), jnp.float32)
+        xT_probe = jax.ShapeDtypeStruct(xp.shape[::-1], xp.dtype)
         kw = dict(
             bp=bp, bc=bc, k_valid=k_valid, n_valid=n_valid,
             interpret=self.interpret,
@@ -317,7 +318,8 @@ class StreamKMeans:
             # schedule, same jitted glue as ops.kmeans_lloyd, same
             # fused-vs-reference VMEM gate, so T ticks == iters=T
             # bit-identically
-            if fits_vmem(prog, xp, self._c, cnorm_probe):
+            if fused_fits("StreamKMeans", prog, xT_probe, self._c,
+                          self._cn_probe()):
                 c, arg = kmeans_lloyd_fused(sched, xp, self._c, iters=1, **kw)
             else:
                 sched2d = tile_schedule_device(self.curve, (pt, ct))
@@ -336,6 +338,9 @@ class StreamKMeans:
         self._c = c
         self._assign = np.asarray(arg)[:N]
         self.core.count("lloyd_dispatch")
+
+    def _cn_probe(self):
+        return jax.ShapeDtypeStruct((self._c.shape[0], 1), jnp.float32)
 
     # -- periodic empty-cluster repair (tick core's every(n) trigger) ---
     def _reseed_empty(self) -> None:
@@ -371,7 +376,7 @@ class StreamKMeans:
             # seed back on the next decayed step
             S = np.array(self._S)
             C = np.array(self._C)
-            S[empty[:n]] = 0.0
+            S[:, empty[:n]] = 0.0
             C[0, empty[:n]] = 0.0
             self._S, self._C = jnp.asarray(S), jnp.asarray(C)
         self.core.count("reseeded", float(n))

@@ -267,6 +267,7 @@ class ServeEngine:
         self.next_token = np.zeros((num_slots,), dtype=np.int32)
         self.active = np.zeros((num_slots,), dtype=bool)
         self.key = jax.random.PRNGKey(seed)
+        self.last_logits: np.ndarray | None = None
         self._rid = 0
         if admitted_log < 1:
             raise ValueError(f"admitted_log must be >= 1, got {admitted_log}")
@@ -507,6 +508,7 @@ class ServeEngine:
                 jnp.asarray(self.pos), jnp.asarray(self.active), cfg=self.cfg,
             )
         logits = np.asarray(logits)
+        self.last_logits = logits  # (B, V) of the latest decode tick
         if self.temperature > 0:
             self.key, sub = jax.random.split(self.key)
             sampled = np.asarray(

@@ -201,12 +201,15 @@ class TestStreamingLloydExactness:
         svc = StreamKMeans(4, bp=64, bc=8, interpret=True)
         svc.insert(_points(14, 120))
         svc.tick()
+        # a tick admits its commands BEFORE its Lloyd step, so assign
+        # answers against the centroids as they were at admission
+        at_admission = svc.centroids()
         probes = _points(15, 17)
         t1 = svc.assign(probes[:9])
         t2 = svc.assign(probes[9:])
         svc.tick()
         _, want = ref.kmeans_assign(
-            jnp.asarray(probes), jnp.asarray(svc.centroids())
+            jnp.asarray(probes), jnp.asarray(at_admission)
         )
         got = np.concatenate([t1.result, t2.result])
         np.testing.assert_array_equal(got, np.asarray(want))

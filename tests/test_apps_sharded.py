@@ -301,8 +301,8 @@ def test_mesh_rejects_fused_false():
 
 
 def test_sharded_join_budget_fallback_set_equal():
-    # the per-shard emit buffer is gated on the same VMEM budget as the
-    # single-core path; past it both fall back to the dense oracle
+    # the sharded emission keeps no data-sized VMEM buffer either: a
+    # tiny budget changes nothing, and the pair set equals the oracle's
     from repro.core import set_vmem_budget
     from repro.kernels import ref
 

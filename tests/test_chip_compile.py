@@ -1,0 +1,205 @@
+"""Compile rehearsal: every main-path Pallas kernel, at real widths,
+compiled (``interpret=False``) for a described TPU v5e chip.
+
+Nothing runs — the TPU compiler only lowers and compiles against a
+topology that is described, not attached.  That catches what interpret
+mode cannot: blocks that break the (8, 128) tiling rule, primitives with
+no Mosaic lowering, and VMEM overruns.  The topology is described inside
+a module-scoped fixture (never at import, in a ``parametrize`` or in a
+``skipif``): only one process at a time may hold the TPU library, so a
+worker that describes it while collecting would starve the others.
+Every compile happens in this process; no child process is started.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import (
+    kmeans_schedule,
+    phased_schedule,
+    tile_schedule,
+    tile_schedule_nd,
+    triangle_schedule,
+)
+from repro.core.schedule import mark_first_visits
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    # compiles written to the persistent cache cannot be read back
+    # without a chip; keep the cache out of the rehearsal
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ---------------------------------------------------------------------------
+# paged attention: tinyllama-1.1b GQA widths and the MLA latent width
+# ---------------------------------------------------------------------------
+
+# (Hkv, g, Dk, Dv, dtype of queries and pools, as the engine passes
+# them): tinyllama-1.1b (32 q / 4 kv heads, head
+# dim 64) and DeepSeek-V2 MLA (one latent "head", 128 query heads,
+# kv_lora_rank 512 + rope 64 = 576)
+ATTN_WIDTHS = {
+    "gqa-tinyllama": (4, 8, 64, 64, jnp.bfloat16),
+    "mla-deepseek": (1, 128, 576, 576, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("width", sorted(ATTN_WIDTHS))
+def test_flash_decode_compiles(one_chip, width):
+    from repro.kernels.attention import decode_page_schedule, flash_attention_decode
+
+    Hkv, g, Dk, Dv, dt = ATTN_WIDTHS[width]
+    B, ps, max_len = 8, 16, 2048
+    MP = max_len // ps
+    P = B * MP + 1
+    sched = decode_page_schedule(B, MP)
+    args = (
+        _spec(sched.shape, jnp.int32, one_chip),
+        _spec((B, MP), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        _spec((B, Hkv, g, Dk), dt, one_chip),
+        _spec((P, Hkv, ps, Dk), dt, one_chip),
+        _spec((P, Hkv, ps, Dv), dt, one_chip),
+    )
+    _compile(lambda *a: flash_attention_decode(*a, interpret=False), *args)
+
+
+@pytest.mark.parametrize("width", sorted(ATTN_WIDTHS))
+def test_flash_prefill_compiles(one_chip, width):
+    from repro.kernels.attention import (
+        flash_attention_prefill,
+        prefill_page_schedule,
+    )
+
+    Hkv, g, Dk, Dv, dt = ATTN_WIDTHS[width]
+    B, ps, max_len, T = 8, 16, 2048, 512
+    MP = max_len // ps
+    P = B * MP + 1
+    sched = prefill_page_schedule([0] * B, [T] * B, ps, MP)
+    args = (
+        _spec(sched.shape, jnp.int32, one_chip),
+        _spec((B, MP), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        _spec((B, T, Hkv, g, Dk), dt, one_chip),
+        _spec((P, Hkv, ps, Dk), dt, one_chip),
+        _spec((P, Hkv, ps, Dv), dt, one_chip),
+    )
+    _compile(lambda *a: flash_attention_prefill(*a, interpret=False), *args)
+
+
+# ---------------------------------------------------------------------------
+# fused §7 apps at working sets above VMEM
+# ---------------------------------------------------------------------------
+
+def test_fused_floyd_warshall_compiles(one_chip):
+    from repro.kernels.floyd_warshall import floyd_warshall_blocked
+
+    n = 4096
+    d = _spec((n, n), jnp.float32, one_chip)
+    _compile(lambda d: floyd_warshall_blocked(d, b=128, interpret=False), d)
+
+
+def test_fused_cholesky_compiles(one_chip):
+    from repro.kernels.cholesky import cholesky_blocked
+
+    n = 4096
+    a = _spec((n, n), jnp.float32, one_chip)
+    _compile(lambda a: cholesky_blocked(a, b=128, interpret=False), a)
+
+
+@pytest.mark.parametrize("schedule_ndim", [2, 3])
+def test_matmul_compiles(one_chip, schedule_ndim):
+    from repro.core import tile_schedule_device
+    from repro.kernels.matmul import matmul_swizzled, matmul_swizzled_3d
+
+    n, blk = 4096, 256
+    nt = n // blk
+    a = _spec((n, n), jnp.bfloat16, one_chip)
+    if schedule_ndim == 2:
+        sched = tile_schedule("fur", nt, nt)
+        fn = matmul_swizzled
+    else:
+        sched = mark_first_visits(tile_schedule_nd("hilbert", (nt, nt, nt)), (0, 1))
+        fn = matmul_swizzled_3d
+    sd = _spec(sched.shape, jnp.int32, one_chip)
+    _compile(
+        lambda s, a, b: fn(s, a, b, bm=blk, bn=blk, bk=blk, interpret=False),
+        sd, a, a,
+    )
+
+
+def test_fused_lloyd_compiles(one_chip):
+    from repro.kernels.kmeans import kmeans_lloyd_fused
+
+    N, D, K, bp, bc = 2**18, 16, 256, 256, 128
+    sched = kmeans_schedule("fur", N // bp, K // bc)
+    args = (
+        _spec(sched.shape, jnp.int32, one_chip),
+        _spec((N, D), jnp.float32, one_chip),
+        _spec((K, D), jnp.float32, one_chip),
+    )
+    _compile(
+        lambda s, x, c: kmeans_lloyd_fused(
+            s, x, c, iters=1, bp=bp, bc=bc, interpret=False
+        ),
+        *args,
+    )
+
+
+@pytest.mark.parametrize("pass_", ["hits", "emit"])
+def test_simjoin_compiles(one_chip, pass_):
+    from repro.kernels.simjoin import (
+        simjoin_emit_swizzled,
+        simjoin_tile_hits_swizzled,
+    )
+
+    N, D, bp = 2**16, 8, 256
+    tri = triangle_schedule("hilbert", N // bp, strict=False)
+    x = _spec((N, D), jnp.float32, one_chip)
+    if pass_ == "hits":
+        sd = _spec(tri.shape, jnp.int32, one_chip)
+        fn = lambda s, x: simjoin_tile_hits_swizzled(  # noqa: E731
+            s, x, eps=0.1, bp=bp, interpret=False
+        )
+    else:
+        sd = _spec((1024, 3), jnp.int32, one_chip)  # (i, j, live) rows
+        fn = lambda s, x: simjoin_emit_swizzled(  # noqa: E731
+            s, x, eps=0.1, bp=bp, interpret=False
+        )
+    _compile(fn, sd, x)

@@ -2,6 +2,8 @@
 small-scale lower+compile of every mode on the production mesh topology
 (run in a subprocess so the 512-device XLA flag applies)."""
 import json
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -13,6 +15,19 @@ from jax.sharding import PartitionSpec as P
 # >100 s on CPU (the tinyllama production-mesh compile alone runs minutes);
 # tier-1 runs `-m "not slow"`, CI still runs everything
 pytestmark = pytest.mark.slow
+
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _child_env():
+    """The parent's environment, held to the CPU: the child never loads
+    the TPU library (one process at a time may hold it)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(REPO / "src")] + [p for p in [env.get("PYTHONPATH")] if p]
+    )
+    return env
 
 
 class TestResolveSpec:
@@ -93,9 +108,8 @@ def test_production_mesh_compiles_all_modes(arch):
     res = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=1200,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root"},
-        cwd="/root/repo",
+        env=_child_env(),
+        cwd=REPO,
     )
     assert res.returncode == 0, res.stderr[-3000:]
     payload = [l for l in res.stdout.splitlines() if l.startswith("RESULT::")]

@@ -1,6 +1,8 @@
 """shard_map expert-parallel MoE dispatch: exactness vs the single-device
 path, gradient flow, and load conservation — on an 8-device submesh
 (subprocess, so the device-count flag doesn't leak into other tests)."""
+import os
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -10,6 +12,19 @@ import pytest
 # ~8 min of 8-device jit+grad compile on CPU; tier-1 runs `-m "not slow"`,
 # CI still runs everything
 pytestmark = pytest.mark.slow
+
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _child_env():
+    """The parent's environment, held to the CPU: the child never loads
+    the TPU library (one process at a time may hold it)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(REPO / "src")] + [p for p in [env.get("PYTHONPATH")] if p]
+    )
+    return env
 
 _CODE = textwrap.dedent("""
     import os
@@ -48,8 +63,8 @@ def test_shard_map_ep_matches_dense():
     res = subprocess.run(
         [sys.executable, "-c", _CODE],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
-        cwd="/root/repo",
+        env=_child_env(),
+        cwd=REPO,
     )
     assert res.returncode == 0, res.stderr[-3000:]
     assert "EP-OK" in res.stdout

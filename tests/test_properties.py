@@ -9,6 +9,9 @@ ANY input, not just the curated cases.
 * work-range splitting: Hilbert-keyed work-stealing ranges cover exactly;
 * elastic reshard: trainer state survives a mesh change bit-exactly.
 """
+import os
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,6 +98,19 @@ class TestWorkRanges:
             assert b == c and a <= b
 
 
+REPO = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _child_env():
+    """The parent's environment, held to the CPU: the child never loads
+    the TPU library (one process at a time may hold it)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(REPO / "src")] + [p for p in [env.get("PYTHONPATH")] if p]
+    )
+    return env
+
+
 def test_elastic_reshard_roundtrip():
     """Trainer state survives a simulated topology change bit-exactly
     (8 placeholder devices, 4x2 -> 2x4 mesh)."""
@@ -113,15 +129,16 @@ def test_elastic_reshard_roundtrip():
         cfg = get_reduced("tinyllama-1.1b", num_layers=2, d_model=64,
                           num_heads=2, num_kv_heads=2, head_dim=32,
                           d_ff=128, vocab_size=128)
+        auto = (jax.sharding.AxisType.Auto,) * 2
         with tempfile.TemporaryDirectory() as d:
             tcfg = TrainerConfig(micro_batch=8, seq_len=16, ckpt_dir=d)
-            m1 = jax.make_mesh((4, 2), ("data", "model"))
+            m1 = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
             tr = Trainer(cfg, tcfg, mesh=m1)
             state = tr.init_state(0)
             state, _ = tr._step_fn(state, tr.batch_at(0))
             before = jax.device_get(state["params"])
 
-            m2 = jax.make_mesh((2, 4), ("data", "model"))
+            m2 = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto)
             state2 = tr.reshard(state, m2)
             after = jax.device_get(state2["params"])
             for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
@@ -134,8 +151,8 @@ def test_elastic_reshard_roundtrip():
     res = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
-        cwd="/root/repo",
+        env=_child_env(),
+        cwd=REPO,
     )
     assert res.returncode == 0, res.stderr[-3000:]
     assert "RESHARD-OK" in res.stdout

@@ -52,16 +52,19 @@ class TestDecodeKernel:
         pt[1, :2] = [5, 1]
         pt[2, :] = [7, 2, 9, 4]
         q = jnp.asarray(rng.normal(size=(B, Hkv, g, Dk)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, Dk)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, Dk)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(P, Hkv, ps, Dk)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(P, Hkv, ps, Dk)), jnp.float32)
         sched = jnp.asarray(decode_page_schedule(B, MP))
         out = flash_attention_decode(
             sched, jnp.asarray(pt), pos, q, kp, vp, interpret=True
         )
         for b in range(B):
             n = int(pos[b]) + 1
-            ks = np.concatenate([np.asarray(kp)[pt[b, i]] for i in range(MP)])[:n]
-            vs = np.concatenate([np.asarray(vp)[pt[b, i]] for i in range(MP)])[:n]
+            # (ps, Hkv, Dk) token-major view of each head-major page
+            kt = np.asarray(kp).transpose(0, 2, 1, 3)
+            vt = np.asarray(vp).transpose(0, 2, 1, 3)
+            ks = np.concatenate([kt[pt[b, i]] for i in range(MP)])[:n]
+            vs = np.concatenate([vt[pt[b, i]] for i in range(MP)])[:n]
             for h in range(Hkv):
                 s = np.asarray(q)[b, h] @ ks[:, h].T / np.sqrt(Dk)
                 p = np.exp(s - s.max(-1, keepdims=True))
@@ -82,8 +85,8 @@ class TestDecodeKernel:
         pt[0, 0] = 1
         pt[1, :2] = [2, 3]
         q = jnp.asarray(rng.normal(size=(B, Hkv, g, Dk)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, Dk)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, Dk)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(P, Hkv, ps, Dk)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(P, Hkv, ps, Dk)), jnp.float32)
         sched = jnp.asarray(decode_page_schedule(B, MP))
         out = flash_attention_decode(
             sched, jnp.asarray(pt), pos, q, kp, vp, interpret=True
@@ -374,8 +377,8 @@ class TestPrefillKernel:
         pt[1, :2] = [7, 2]
         T = 16  # two q tiles of bq=ps
         q = rng.normal(size=(B, T, Hkv, g, Dk)).astype(np.float32)
-        kp = rng.normal(size=(P, ps, Hkv, Dk)).astype(np.float32)
-        vp = rng.normal(size=(P, ps, Hkv, Dk)).astype(np.float32)
+        kp = rng.normal(size=(P, Hkv, ps, Dk)).astype(np.float32)
+        vp = rng.normal(size=(P, Hkv, ps, Dk)).astype(np.float32)
         return B, Hkv, g, Dk, ps, MP, pos0, n_new, pt, q, kp, vp
 
     def _run(self, pos0, n_new, ps, MP, pt, q, kp, vp):
@@ -394,8 +397,9 @@ class TestPrefillKernel:
         B, Hkv, g, Dk, ps, MP, pos0, n_new, pt, q, kp, vp = self._setup()
         out = np.asarray(self._run(pos0, n_new, ps, MP, pt, q, kp, vp))
         for b in range(B):
-            ks = np.concatenate([kp[pt[b, i]] for i in range(MP)])
-            vs = np.concatenate([vp[pt[b, i]] for i in range(MP)])
+            # (ps, Hkv, Dk) token-major view of each head-major page
+            ks = np.concatenate([kp[pt[b, i]].transpose(1, 0, 2) for i in range(MP)])
+            vs = np.concatenate([vp[pt[b, i]].transpose(1, 0, 2) for i in range(MP)])
             for i in range(int(n_new[b])):
                 qpos = int(pos0[b]) + i
                 for h in range(Hkv):
