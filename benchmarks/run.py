@@ -12,9 +12,7 @@
 
 Prints ``bench,name,value,derived`` CSV.  ``--json [PATH]`` additionally
 records the rows as JSON (default ``BENCH_curves.json``) so the perf
-trajectory is tracked across PRs.  Roofline terms come from
-``python -m repro.launch.dryrun`` (they need the 512-device env), not
-from here.
+trajectory is tracked across PRs.
 """
 from __future__ import annotations
 

@@ -53,5 +53,5 @@ def applicable_shapes(arch: str) -> list[str]:
 
 
 def cell_list(archs: list[str]) -> list[tuple[str, str]]:
-    """All runnable (arch, shape) dry-run cells."""
+    """All runnable (arch, shape) cells."""
     return [(a, s) for a in archs for s in applicable_shapes(a)]

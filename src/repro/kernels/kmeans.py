@@ -48,6 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import as_choice, hilbert_sort_key, register_schedule_cache
 from repro.core.program import CurveProgram
+from repro.core.tracing import count, counters
 
 from .launch import launch
 
@@ -67,12 +68,25 @@ def _quantise_points(
     # keeps d*nbits <= 31 (int32 order values on device)
     cap = max((31 // d) // d * d, 1)
     nbits = min(nbits, cap)
+    return hilbert_quantise(x, nbits=nbits, d=d), nbits
+
+
+@functools.partial(jax.jit, static_argnames=("nbits", "d"))
+def hilbert_quantise(x: jax.Array, *, nbits: int, d: int) -> jax.Array:
+    """The device stage of :func:`_quantise_points`: its first ``d``
+    features on the 2^nbits grid, int32[N, d]."""
     xf = x[:, :d].astype(jnp.float32)
     lo = jnp.min(xf, axis=0)
     hi = jnp.max(xf, axis=0)
     scale = ((1 << nbits) - 1) / jnp.maximum(hi - lo, 1e-9)
-    q = jnp.clip((xf - lo) * scale, 0, (1 << nbits) - 1).astype(jnp.int32)
-    return q, nbits
+    return jnp.clip((xf - lo) * scale, 0, (1 << nbits) - 1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("nbits",))
+def hilbert_sort(q: jax.Array, *, nbits: int) -> jax.Array:
+    """Permutation sorting the grid points ``q`` by their Hilbert key
+    (a stable argsort)."""
+    return jnp.argsort(hilbert_sort_key(q, nbits))
 
 
 def hilbert_point_order(
@@ -87,45 +101,53 @@ def hilbert_point_order(
     of feature space.  Used by the k-means and ε-join wrappers in ops.py.
     """
     q, nbits = _quantise_points(x, nbits=nbits, dims=dims)
-    return jnp.argsort(hilbert_sort_key(q, nbits))
+    return hilbert_sort(q, nbits=nbits)
 
 
 class _OrderCache:
     """Tiny LRU for point-order permutations, keyed on a digest of the
     quantised grid (keying on the raw N·d·4 grid bytes would pin them in
-    host memory for the cache's lifetime)."""
+    host memory for the cache's lifetime).  Its hits and misses are the
+    counters ``<name>.hits`` and ``<name>.misses``."""
 
-    def __init__(self, maxsize: int = 64):
+    def __init__(self, name: str, maxsize: int = 64):
         self.maxsize = maxsize
         self._store: dict = {}
-        self.hits = self.misses = 0
+        self._names = (f"{name}.hits", f"{name}.misses")
+        self._base = self._counts()  # the counters at the last clear
 
     def get(self, key, compute):
+        hits, misses = self._names
         if key in self._store:
-            self.hits += 1
+            count(hits)
             self._store[key] = self._store.pop(key)  # move to back (MRU)
             return self._store[key]
-        self.misses += 1
+        count(misses)
         val = compute()
         self._store[key] = val
         if len(self._store) > self.maxsize:
             self._store.pop(next(iter(self._store)))
         return val
 
+    def _counts(self) -> tuple[int, int]:
+        c = counters()
+        return tuple(c.get(n, 0) for n in self._names)
+
     def cache_clear(self):
         self._store.clear()
-        self.hits = self.misses = 0
+        self._base = self._counts()
 
     def cache_info(self):
         import collections
 
         info = collections.namedtuple("CacheInfo", "hits misses maxsize currsize")
-        return info(self.hits, self.misses, self.maxsize, len(self._store))
+        hits, misses = (v - b for v, b in zip(self._counts(), self._base))
+        return info(hits, misses, self.maxsize, len(self._store))
 
 
 # registered so core.schedule_cache_clear() drops it too (it caches on
 # the quantised grid, which changes meaning when curves are re-registered)
-_cached_order = register_schedule_cache(_OrderCache())
+_cached_order = register_schedule_cache(_OrderCache("order_cache"))
 
 
 def hilbert_point_order_cached(
@@ -148,9 +170,7 @@ def hilbert_point_order_cached(
     q, nbits = _quantise_points(x, nbits=nbits, dims=dims)
     qh = np.ascontiguousarray(np.asarray(q))
     key = (hashlib.sha256(qh.tobytes()).digest(), qh.shape, nbits)
-    return _cached_order.get(
-        key, lambda: jnp.argsort(hilbert_sort_key(jnp.asarray(qh), nbits))
-    )
+    return _cached_order.get(key, lambda: hilbert_sort(q, nbits=nbits))
 
 
 # ---------------------------------------------------------------------------

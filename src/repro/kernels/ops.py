@@ -36,6 +36,8 @@ from repro.core import (
     tile_schedule_device,
     triangle_schedule,
 )
+from repro.core.tracing import count, span
+
 from . import ref
 from .attention import (
     causal_schedule,
@@ -65,9 +67,10 @@ from .kmeans import (
 from .launch import resolve_interpret as _interpret
 from .matmul import matmul_swizzled, matmul_swizzled_3d
 from .simjoin import (
-    map_pairs_back,
     simjoin_counts_swizzled,
+    simjoin_map_back,
     simjoin_pairs_scheduled,
+    simjoin_permute,
 )
 
 DEFAULT_CURVE = "fur"  # overlay-grid Hilbert: native n×m, unit steps
@@ -639,36 +642,40 @@ def simjoin_pairs(
         curve = ch.curve
         if ch.block:
             bp = ch.block[0]
-    if mesh is not None:
-        from .sharded import simjoin_pairs_sharded
+    with span("simjoin.pairs", join=count("simjoin.joins") - 1):
+        if mesh is not None:
+            from .sharded import simjoin_pairs_sharded
 
-        return simjoin_pairs_sharded(
-            x, eps, mesh=mesh, curve=curve, bp=bp,
-            hilbert_order=hilbert_order, interpret=interpret,
+            return simjoin_pairs_sharded(
+                x, eps, mesh=mesh, curve=curve, bp=bp,
+                hilbert_order=hilbert_order, interpret=interpret,
+            )
+        N, D = x.shape
+        if N == 0:
+            return jnp.zeros((0, 2), dtype=jnp.int32)
+        perm = None
+        if hilbert_order:
+            with span("simjoin.order"):
+                perm = hilbert_point_order_cached(x)
+                x = simjoin_permute(x, perm)
+        bp = min(bp, N)
+        pn = (-N) % bp
+        xp = jnp.pad(x, ((0, pn), (0, 0))) if pn else x
+        pt = xp.shape[0] // bp
+        n_valid = N if pn else None
+        interp = _interpret(interpret)
+        with span("simjoin.schedule"):
+            tri = triangle_schedule(curve, pt, strict=False)
+        # the two-pass hits → non-empty tiles → emit machinery is the
+        # shared driver (kernels/simjoin.py), reused verbatim by the
+        # streaming join's per-tick probe dispatch (serve/apps.py)
+        pairs = simjoin_pairs_scheduled(
+            tri, xp, eps=float(eps), bp=bp, n_valid=n_valid, interpret=interp
         )
-    N, D = x.shape
-    if N == 0:
-        return jnp.zeros((0, 2), dtype=jnp.int32)
-    perm = None
-    if hilbert_order:
-        perm = hilbert_point_order_cached(x)
-        x = x[perm]
-    bp = min(bp, N)
-    pn = (-N) % bp
-    xp = jnp.pad(x, ((0, pn), (0, 0))) if pn else x
-    pt = xp.shape[0] // bp
-    n_valid = N if pn else None
-    interp = _interpret(interpret)
-    tri = triangle_schedule(curve, pt, strict=False)
-    # the two-pass hits → non-empty tiles → emit machinery is the shared
-    # driver (kernels/simjoin.py), reused verbatim by the streaming
-    # join's per-tick probe dispatch (serve/apps.py)
-    pairs = simjoin_pairs_scheduled(
-        tri, xp, eps=float(eps), bp=bp, n_valid=n_valid, interpret=interp
-    )
-    if perm is not None:
-        pairs = map_pairs_back(pairs, perm)
-    return pairs
+        if perm is not None:
+            with span("simjoin.map_back"):
+                pairs = simjoin_map_back(pairs, perm)
+        return pairs
 
 
 def floyd_warshall(

@@ -77,6 +77,7 @@ from repro.core import (
     register_schedule_cache,
     triangle_schedule,
 )
+from repro.core.tracing import count
 
 from .kmeans import (
     _quantise_points,
@@ -89,10 +90,10 @@ from .kmeans import (
 from .launch import collective_volume, launch, resolve_interpret
 from .simjoin import (
     check_pair_offsets,
-    map_pairs_back,
     pairs_from_masks,
     simjoin_emit_program,
     simjoin_hits_rows_program,
+    simjoin_map_back,
 )
 
 __all__ = [
@@ -624,7 +625,7 @@ def simjoin_pairs_sharded(
             n_valid=n_valid, tri=tri, interp=interp, volume=_volume,
         )
     if perm is not None:
-        pairs = map_pairs_back(pairs, perm)
+        pairs = simjoin_map_back(pairs, perm)
     return pairs
 
 
@@ -650,6 +651,8 @@ def _join_replicated(
         _acc_volume(volume, pass1, sched_dev, xp, replicated=xp.nbytes)
     hits_i = pass1(sched_dev, xp)
     tot = np.asarray(jnp.sum(hits_i, axis=1)).astype(np.int64)[:steps]
+    count("simjoin.tile_pairs", steps)
+    count("simjoin.tiles_live", int(np.count_nonzero(tot)))
     P_total = int(tot.sum())
     if P_total == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
@@ -719,6 +722,8 @@ def _join_halo(
     for s in range(num):
         k = len(row_ids[s])
         tot[row_ids[s]] = rows_tot[s * per_h : s * per_h + k]
+    count("simjoin.tile_pairs", len(pruned))
+    count("simjoin.tiles_live", int(np.count_nonzero(tot)))
     P_total = int(tot.sum())
     if P_total == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)

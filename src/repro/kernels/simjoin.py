@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 
 from repro.core import as_choice
 from repro.core.program import CurveProgram
+from repro.core.tracing import count, span
 
 from .launch import launch
 
@@ -65,7 +66,14 @@ def check_pair_offsets(P_total: int, bp: int) -> None:
         )
 
 
-def map_pairs_back(pairs: jax.Array, perm: jax.Array) -> jax.Array:
+@jax.jit
+def simjoin_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
+    """The points in the order ``perm`` (the join's Hilbert order)."""
+    return x[perm]
+
+
+@jax.jit
+def simjoin_map_back(pairs: jax.Array, perm: jax.Array) -> jax.Array:
     """Map (i, j) pairs emitted on Hilbert-sorted points back to the
     original point ids, re-canonicalised to i > j (sorting can flip the
     order within a pair).  Shared by every emission path — single-core
@@ -254,6 +262,24 @@ def simjoin_hits_rows_program(
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
+def simjoin_totals(
+    schedule: jax.Array,
+    x: jax.Array,
+    *,
+    eps: float,
+    bp: int,
+    n_valid: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Pass 1 of pair emission: the number of unordered pairs in each
+    schedule step's tile, int32[steps]."""
+    hits_i, _ = simjoin_tile_hits_swizzled(
+        schedule, x, eps=eps, bp=bp, n_valid=n_valid, interpret=interpret
+    )
+    return jnp.sum(hits_i, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
 def simjoin_counts_swizzled(
     schedule: jax.Array,
     x: jax.Array,
@@ -354,13 +380,16 @@ def _bucket(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
-@functools.partial(jax.jit, static_argnames=("bp", "size"))
-def _compact(masks, rows, tiles, *, bp: int, size: int):
+@functools.partial(jax.jit, static_argnames=("bp", "size", "P"))
+def simjoin_compact(masks, rows, tiles, *, bp: int, size: int, P: int):
+    """The first ``P`` hits of ``masks[rows]`` as pairs, int32[P, 2]
+    (``size`` >= P bounds the ``nonzero``)."""
     flat = masks[rows].reshape(-1)
     (f,) = jnp.nonzero(flat, size=size, fill_value=0)
     t = tiles[f // (bp * bp)]
     r = f % (bp * bp)
-    return jnp.stack([t[:, 0] * bp + r // bp, t[:, 1] * bp + r % bp], axis=1)
+    out = jnp.stack([t[:, 0] * bp + r // bp, t[:, 1] * bp + r % bp], axis=1)
+    return out[:P]
 
 
 def pairs_from_masks(masks, rows, tiles, P: int, bp: int) -> jax.Array:
@@ -374,17 +403,21 @@ def pairs_from_masks(masks, rows, tiles, P: int, bp: int) -> jax.Array:
             f"{len(rows)} non-empty tiles of {bp}x{bp} exceed int32 mask "
             f"indexing; reduce eps or join in chunks"
         )
-    n = _bucket(len(rows))
-    rows_p = np.zeros(n, np.int32)
-    rows_p[: len(rows)] = rows
-    tiles_p = np.zeros((n, 2), np.int32)
-    tiles_p[: len(rows)] = tiles
-    # padded rows repeat mask 0, but the compaction stops at P hits —
-    # all of them in the listed rows, which come first
-    out = _compact(
-        masks, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp, size=_bucket(P)
-    )
-    return out[:P]
+    with span("simjoin.compact"):
+        n = _bucket(len(rows))
+        count("simjoin.pairs_out", P)
+        count("simjoin.mask_rows_scanned", n)
+        count("simjoin.mask_cells_scanned", n * bp * bp)
+        rows_p = np.zeros(n, np.int32)
+        rows_p[: len(rows)] = rows
+        tiles_p = np.zeros((n, 2), np.int32)
+        tiles_p[: len(rows)] = tiles
+        # padded rows repeat mask 0, but the compaction stops at P hits —
+        # all of them in the listed rows, which come first
+        return simjoin_compact(
+            masks, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp,
+            size=_bucket(P), P=P,
+        )
 
 
 def emission_table(tiles, live) -> np.ndarray:
@@ -424,19 +457,25 @@ def simjoin_pairs_scheduled(
     tri = np.asarray(schedule, dtype=np.int32)
     if tri.shape[0] == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
-    hits_i, _ = simjoin_tile_hits_swizzled(
-        jnp.asarray(tri), xp, eps=float(eps), bp=bp, n_valid=n_valid,
-        interpret=interpret,
-    )
-    tot = np.asarray(jnp.sum(hits_i, axis=1)).astype(np.int64)
+    with span("simjoin.pass1"):
+        tot = simjoin_totals(
+            jnp.asarray(tri), xp, eps=float(eps), bp=bp, n_valid=n_valid,
+            interpret=interpret,
+        )
+    with span("simjoin.sync"):
+        tot = np.asarray(tot).astype(np.int64)
     P = int(tot.sum())
+    count("simjoin.tile_pairs", len(tri))
     if P == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P, bp)
-    nz = tri[tot > 0]
-    table = emission_table(nz, np.ones(len(nz)))
-    masks = simjoin_emit_swizzled(
-        jnp.asarray(table), xp, eps=float(eps), bp=bp, n_valid=n_valid,
-        interpret=interpret,
-    )
+    with span("simjoin.table"):
+        nz = tri[tot > 0]
+        table = jnp.asarray(emission_table(nz, np.ones(len(nz))))
+    count("simjoin.tiles_live", len(nz))
+    with span("simjoin.pass2"):
+        masks = simjoin_emit_swizzled(
+            table, xp, eps=float(eps), bp=bp, n_valid=n_valid,
+            interpret=interpret,
+        )
     return pairs_from_masks(masks, np.arange(len(nz)), nz, P, bp)

@@ -1,1 +1,1 @@
-"""repro.launch — mesh construction, dry-run, train/serve launchers."""
+"""repro.launch — mesh construction, train/serve launchers."""

@@ -1,4 +1,4 @@
-"""Pure step functions + abstract input specs for the dry-run and launchers.
+"""Pure step functions + abstract input specs for the launchers and compile tests.
 
 ``input_specs(cfg, shape)`` returns weak-type-correct ShapeDtypeStruct
 stand-ins for every input of the step being lowered — no device

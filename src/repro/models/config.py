@@ -61,9 +61,6 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # training
     remat: bool = True
-    # unroll the layer scan (straight-line HLO): used by the dry-run cost
-    # pass because XLA cost_analysis counts while-loop bodies once
-    scan_unroll: bool = False
     # technique knobs (the paper's contribution wired into the stack)
     use_hilbert_kernels: bool = False  # Pallas kernels in MLP/attention
     tile_curve: str = "fur"

@@ -1,6 +1,6 @@
 """LMModel: embed → blocks → head, with train/prefill/decode entry points.
 
-Public surface used by the trainer, server, dry-run and tests:
+Public surface used by the trainer, server and tests:
 
   init_params(key, cfg)          -> params pytree
   param_specs(cfg)               -> matching PartitionSpec pytree

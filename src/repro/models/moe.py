@@ -5,7 +5,7 @@ with the standard drop-on-overflow capacity discipline.  Dispatch is
 sort-based (argsort by expert id → ranked slots → batched expert GEMMs on
 an (E, C, d) buffer), which is jit-friendly and shards: the expert axis E
 maps to the "model" mesh axis, so XLA lowers the scatter/gather pair into
-the EP all-to-alls visible in the dry-run HLO.
+the EP all-to-alls visible in the compiled HLO.
 
 Beyond-paper hook: the dispatch *slot order* within each expert is a free
 permutation — ``repro.core`` Hilbert keys over (expert, token-position)

@@ -234,10 +234,9 @@ def stack_forward(stacked, x, cfg: ModelConfig, positions, shared_attn=None):
         x, a = body(layer_params, x)
         return (x, aux + a), None
 
-    unroll = cfg.num_layers if cfg.scan_unroll else 1
     if not cfg.hybrid_attn_every:
         (x, aux), _ = jax.lax.scan(
-            scan_fn, (x, jnp.zeros((), jnp.float32)), stacked, unroll=unroll
+            scan_fn, (x, jnp.zeros((), jnp.float32)), stacked
         )
         return x, aux
 
@@ -250,7 +249,7 @@ def stack_forward(stacked, x, cfg: ModelConfig, positions, shared_attn=None):
     for s in range(n_seg):
         lo, hi = s * every, min((s + 1) * every, L)
         seg = jax.tree.map(lambda a: a[lo:hi], stacked)
-        (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), seg, unroll=(hi - lo) if cfg.scan_unroll else 1)
+        (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), seg)
         h = rms_norm(x, shared_attn["norm"], cfg.norm_eps)
         x = x + attn.gqa_forward(shared_attn["attn"], h, cfg, positions)
         x = _shared_block_tail(shared_attn, x, cfg)
@@ -267,7 +266,7 @@ def stack_decode(stacked, x, cfg: ModelConfig, caches, pos, shared_attn=None,
         return x, new_cache
 
     if not cfg.hybrid_attn_every:
-        x, new_caches = jax.lax.scan(scan_fn, x, (stacked, caches), unroll=cfg.num_layers if cfg.scan_unroll else 1)
+        x, new_caches = jax.lax.scan(scan_fn, x, (stacked, caches))
         return x, new_caches, shared_caches
 
     every = cfg.hybrid_attn_every
@@ -279,7 +278,7 @@ def stack_decode(stacked, x, cfg: ModelConfig, caches, pos, shared_attn=None,
         lo, hi = s * every, min((s + 1) * every, L)
         seg_p = jax.tree.map(lambda a: a[lo:hi], stacked)
         seg_c = jax.tree.map(lambda a: a[lo:hi], caches)
-        x, seg_c_new = jax.lax.scan(scan_fn, x, (seg_p, seg_c), unroll=(hi - lo) if cfg.scan_unroll else 1)
+        x, seg_c_new = jax.lax.scan(scan_fn, x, (seg_p, seg_c))
         new_parts.append(seg_c_new)
         h = rms_norm(x, shared_attn["norm"], cfg.norm_eps)
         sc = jax.tree.map(lambda a: a[s], shared_caches)
@@ -308,10 +307,7 @@ def stack_decode_paged(stacked, x, cfg: ModelConfig, pools, pos, page_table, *,
         )
         return x, new_pool
 
-    x, new_pools = jax.lax.scan(
-        scan_fn, x, (stacked, pools),
-        unroll=cfg.num_layers if cfg.scan_unroll else 1,
-    )
+    x, new_pools = jax.lax.scan(scan_fn, x, (stacked, pools))
     return x, new_pools
 
 
@@ -332,10 +328,7 @@ def stack_prefill_paged(stacked, x, cfg: ModelConfig, pools, pos0, n_new,
         )
         return x, new_pool
 
-    x, new_pools = jax.lax.scan(
-        scan_fn, x, (stacked, pools),
-        unroll=cfg.num_layers if cfg.scan_unroll else 1,
-    )
+    x, new_pools = jax.lax.scan(scan_fn, x, (stacked, pools))
     return x, new_pools
 
 

@@ -81,7 +81,7 @@ __all__ = ["StreamKMeans", "StreamSimJoin"]
 # radius); a warm stream re-probes the same cohort key ranges, so the
 # tree walks are memoised — registered so schedule_cache_clear() stays
 # complete (satellite: new LRUs must join the registry)
-_halo_cache = register_schedule_cache(_OrderCache(maxsize=1024))
+_halo_cache = register_schedule_cache(_OrderCache("halo_cache", maxsize=1024))
 
 
 def _halo_ranges_cached(lo: int, hi: int, *, ndim: int, nbits: int,

@@ -102,8 +102,7 @@ _SUBPROC = textwrap.dedent("""
 
 @pytest.mark.parametrize("arch", ["tinyllama-1.1b", "mamba2-2.7b"])
 def test_production_mesh_compiles_all_modes(arch):
-    """Reduced-size lower+compile across (mode × mesh) — the fast twin of
-    the full dry-run (which runs the real shapes via __main__)."""
+    """Reduced-size lower+compile across (mode × mesh)."""
     code = _SUBPROC.replace("%ARCH%", arch)
     res = subprocess.run(
         [sys.executable, "-c", code],

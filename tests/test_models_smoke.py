@@ -1,6 +1,5 @@
 """Per-arch smoke tests: reduced config, one forward + one grad step on CPU,
-shape and finiteness asserts.  The FULL configs are exercised only via the
-dry-run (ShapeDtypeStruct, no allocation).
+shape and finiteness asserts.
 """
 import jax
 import jax.numpy as jnp
