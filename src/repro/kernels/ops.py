@@ -616,10 +616,10 @@ def simjoin_pairs(
     Two-pass emission, both passes FGF-Hilbert tile-scheduled: pass 1
     is the count kernel (:func:`simjoin_tile_hits_swizzled`), whose
     per-tile totals give the exact pair count and the non-empty tiles;
-    pass 2 (:func:`simjoin_emit_swizzled`) writes each non-empty tile's
-    hit mask, and one exact-size ``nonzero`` compacts the masks into
-    pairs in schedule-then-row-major order.  Ragged N is handled by the
-    same zero-pad + index-mask rule as the counts.  With
+    pass 2 (:func:`simjoin_emit_swizzled`) packs each non-empty tile's
+    hit rows and counts them, and a row flatten of exact size turns the
+    packed rows into pairs in schedule-then-row-major order.  Ragged N
+    is handled by the same zero-pad + index-mask rule as the counts.  With
     ``hilbert_order=True`` the join runs on Hilbert-sorted points and the
     emitted indices are mapped back through the (cached) permutation, so
     pairs always refer to the original point order.
@@ -627,7 +627,7 @@ def simjoin_pairs(
     ``mesh=`` (a 1-D mesh from ``repro.launch.mesh.make_app_mesh``) runs
     the distributed two-pass variant: the triangle schedule's rows are
     curve-range partitioned across devices, per-shard counts give the
-    global pair count, and each shard emits the masks of its non-empty
+    global pair count, and each shard packs the hit rows of its non-empty
     tiles — the compacted result is identical to the single-core output
     (see :mod:`repro.kernels.sharded`).  Neither pass keeps a
     data-sized buffer in VMEM, so the join has no VMEM-budget fallback.

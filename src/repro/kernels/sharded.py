@@ -36,9 +36,9 @@ join, in two data-distribution modes.  Both share the schedule split:
 pass 1 counts hits over each shard's curve range of the triangle
 schedule; the host reads the per-step totals (the single-core path
 already host-syncs here — output size is data-dependent) and keeps the
-non-empty rows; pass 2 has each shard write the hit masks of its
-non-empty rows, which one exact-size compaction (a host-side gather of
-mask positions in the halo case) turns into the global pair list **in
+non-empty rows; pass 2 has each shard write the packed hit rows of its
+non-empty rows, which one exact-size row flatten (reading them in the
+global row order in the halo case) turns into the global pair list **in
 exactly the single-core emission order** (shards hold contiguous
 schedule ranges of the global pruned triangle).
 
@@ -409,21 +409,22 @@ def _join_pass1_fn(mesh, axis, *, eps, bp, D, n_valid, interpret):
 @register_schedule_cache
 @functools.lru_cache(maxsize=64)
 def _join_pass2_fn(mesh, axis, *, eps, bp, D, n_valid, halo, interpret):
-    """Pass 2 on every shard: the hit masks of its live table rows
-    (``halo`` picks the 5-col slot/global table over a resident+halo
-    buffer instead of the 3-col table over the replicated points)."""
+    """Pass 2 on every shard: the packed hit rows and row counts of its
+    live table rows (``halo`` picks the 5-col slot/global table over a
+    resident+halo buffer instead of the 3-col table over the replicated
+    points)."""
 
     def body(table_l, x):
         program = simjoin_emit_program(
             table_l, eps=eps, bp=bp, D=D, n_valid=n_valid, halo=halo,
         )
-        return launch(program, x, x.T, interpret=interpret)
+        return tuple(launch(program, x, x.T, interpret=interpret))
 
     fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None) if halo else P(None, None)),
-        out_specs=P(axis, None, None),
+        out_specs=(P(axis, None, None), P(axis, None, None)),
         check_vma=False,
     )
     return jax.jit(fn)
@@ -587,8 +588,8 @@ def simjoin_pairs_sharded(
     names — a fixed-size halo buffer per shard, reused by pass 2.
     Per-shard hit counts → the non-empty rows and the exact pair count
     on the host (the inherent host sync of an exact-size join) →
-    per-shard hit masks of those rows → one compaction in the global
-    schedule order.
+    per-shard packed hit rows of those rows → one row flatten in the
+    global schedule order.
     Pruned rows contribute zero pairs by construction of the reach
     mask, so the result is array-equal (not just set-equal) to
     ``ops.simjoin_pairs`` on every mesh size.
@@ -657,7 +658,7 @@ def _join_replicated(
     if P_total == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P_total, bp)
-    # pass 2: each shard emits the masks of the non-empty tiles of its
+    # pass 2: each shard packs the hit rows of the non-empty tiles of its
     # own contiguous range, padded to a uniform per-shard row count
     tot_pad = np.concatenate([tot, np.zeros(pad_rows, np.int64)])
     live = (tot_pad > 0).reshape(num, per)
@@ -676,11 +677,11 @@ def _join_replicated(
     table_dev = jnp.asarray(table.reshape(num * per2, 3))
     if volume is not None:
         _acc_volume(volume, pass2, table_dev, xp, replicated=xp.nbytes)
-    masks = pass2(table_dev, xp)  # (num * per2, bp, bp)
+    ids, counts = pass2(table_dev, xp)  # (num * per2, bp, bp), (.., 1, bp)
     # shards hold contiguous ranges of the global schedule, so shard
     # order IS global order
     return pairs_from_masks(
-        masks, np.concatenate(rows), tri[tot > 0], P_total, bp
+        ids, counts, np.concatenate(rows), tri[tot > 0], P_total, bp
     )
 
 
@@ -728,12 +729,12 @@ def _join_halo(
     if P_total == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P_total, bp)
-    # pass 2: each shard emits the masks of its non-empty rows (slot +
+    # pass 2: each shard packs the tiles of its non-empty rows (slot +
     # global columns), padded to a uniform per-shard row count
     nz_rows = [r[tot[r] > 0] for r in row_ids]
     per2 = max(1, max(len(r) for r in nz_rows))
     table = np.zeros((num, per2, 5), np.int32)
-    pos = np.zeros(len(pruned), np.int64)  # row -> position in the masks
+    pos = np.zeros(len(pruned), np.int64)  # row -> position in pass 2
     for sh in range(num):
         local = np.searchsorted(row_ids[sh], nz_rows[sh])
         table[sh, : len(local), :4] = sched[sh * per_h + local]
@@ -746,12 +747,12 @@ def _join_halo(
     table_dev = jnp.asarray(table.reshape(num * per2, 5))
     if volume is not None:
         _acc_volume(volume, pass2, table_dev, buf)
-    masks = pass2(table_dev, buf)  # (num * per2, bp, bp)
-    # gather the shards' masks back into the GLOBAL pruned-row order —
+    ids, counts = pass2(table_dev, buf)  # (num * per2, bp, bp), (.., 1, bp)
+    # read the shards' rows in the GLOBAL pruned-row order —
     # which equals the full triangle order because pruned rows are
     # provably pair-free — so the result is array-equal to single-core
     nz = tot > 0
-    return pairs_from_masks(masks, pos[nz], pruned[nz], P_total, bp)
+    return pairs_from_masks(ids, counts, pos[nz], pruned[nz], P_total, bp)
 
 
 # ---------------------------------------------------------------------------

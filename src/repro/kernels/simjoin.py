@@ -25,12 +25,21 @@ lane-dense rows:
   ``simjoin_counts``, and their row-sum per step is the per-tile hit
   total that sizes pair emission.
 * :func:`simjoin_emit_swizzled` — pass 2: for the tiles pass 1 found
-  non-empty, each grid step writes its (bp, bp) int8 hit mask to its own
-  output block (write-once, order-free); :func:`pairs_from_masks`
-  compacts the masks into (i, j) pairs with one exact-size
-  ``jnp.nonzero`` — schedule-then-row-major order, the order of the
-  tiles' rows.  Compaction by sort inside the kernel has no TPU
-  lowering; compaction of masks is one XLA pass.
+  non-empty, each grid step packs its tile's rows in VMEM
+  (:func:`_pack_rows`: row r's hit columns, ascending, in lanes
+  ``[0, n_r)``) and writes them with the row counts ``n_r`` to its own
+  output blocks (write-once, order-free).
+
+The compaction is two-level and count-directed: the kernel packs each
+row, then :func:`pairs_from_masks` (:func:`simjoin_compact`) flattens
+whole rows — a scatter over rows and a cumsum over P give each output its
+row, one 1-D gather its column id — in schedule-then-row-major order,
+the order of the tiles' rows.  No cell-level ``jnp.nonzero``: in XLA it
+is a scatter-add with one update per mask cell (and a sort once its
+bins outgrow the chip's memory), while only 0.1–1% of the cells of a
+non-empty tile hold a pair.  Gathers take 1-D indices only: XLA's TPU
+compile of a gather indexed by ``(P, 2)`` takes minutes at millions of
+pairs.  Sorting inside the kernel has no TPU lowering.
 
 A diagonal tile counts each unordered pair once via a strict i<j mask; an
 off-diagonal (i_tile > j_tile) tile contributes row sums to the i side
@@ -45,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import as_choice
 from repro.core.program import CurveProgram
@@ -307,12 +317,51 @@ def simjoin_counts_swizzled(
 
 
 # ---------------------------------------------------------------------------
-# Pass 2: hit masks of the non-empty tiles, compacted into pairs
+# Pass 2: each hit tile's rows packed in the kernel, then flattened by rows
 # ---------------------------------------------------------------------------
 
+def _id_code(bp: int):
+    """(dtype, bias) of a packed column id: int8 ``j - 128`` while the
+    ids fit (bp <= 256, no larger than a hit mask), else int32 ``j``."""
+    return (jnp.int8, 128) if bp <= 256 else (jnp.int32, 0)
+
+
+def _pack_rows(hit):
+    """Each row's hit columns moved to its lanes ``[0, n_r)`` in
+    ascending order, as int32 column ids (junk beyond ``n_r``).
+
+    A hit at column j moves left by its count of preceding misses,
+    ``d = j - c``, where ``c`` (the hits before j) is one bf16 matmul
+    against strictly-upper ones, exact in f32 for counts <= bp.  The
+    move is a shift network: step b rolls the lanes left by 2^b and
+    takes the elements whose ``d`` has bit b set.  Taking the bits from
+    low to high, a moving element sits at lane >= 2^b, so nothing wraps
+    and no two elements meet.  Each lane carries its element's ``d``
+    (-1: empty), and the column id is its final lane plus ``d``."""
+    bp = hit.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, hit.shape, 1)
+    upper = (
+        jax.lax.broadcasted_iota(jnp.int32, (bp, bp), 0)
+        < jax.lax.broadcasted_iota(jnp.int32, (bp, bp), 1)
+    )
+    c = jnp.dot(
+        hit.astype(jnp.bfloat16), upper.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
+    d = jnp.where(hit, lane - c, -1)
+    b = 1
+    while b < bp:
+        moved = pltpu.roll(d, bp - b, 1)  # moved[l] = d[l + b]
+        arrives = (moved >= 0) & ((moved & b) != 0)
+        stays = (d >= 0) & ((d & b) == 0)
+        d = jnp.where(arrives, moved, jnp.where(stays, d, -1))
+        b *= 2
+    return lane + d
+
+
 def _emit_kernel(
-    sched_ref, xi_ref, xjT_ref, o_ref, *, eps2: float, n_valid: int | None,
-    gi_col: int, gj_col: int, live_col: int,
+    sched_ref, xi_ref, xjT_ref, ids_out, n_out, *, eps2: float,
+    n_valid: int | None, gi_col: int, gj_col: int, live_col: int,
 ):
     s = pl.program_id(0)
     hit = _hit_tile(
@@ -320,20 +369,23 @@ def _emit_kernel(
         eps2=eps2, n_valid=n_valid,
     )
     hit = jnp.logical_and(hit, sched_ref[s, live_col] == 1)
-    o_ref[0] = hit.astype(jnp.int32).astype(o_ref.dtype)
+    _, bias = _id_code(hit.shape[1])
+    ids_out[0] = (_pack_rows(hit) - bias).astype(ids_out.dtype)
+    n_out[0] = _hit_sums(hit)[0]
 
 
 def simjoin_emit_program(
     table, *, eps: float, bp: int, D: int, n_valid: int | None,
     halo: bool = False, choice=None,
 ) -> CurveProgram:
-    """Pass-2 declaration: one (bp, bp) int8 hit mask per table row,
-    each written once.  ``halo=False``: 3-col rows ``(i, j, live)``
-    over one point buffer; ``halo=True``: 5-col rows ``(i_slot, j_slot,
-    i, j, live)`` over a shard's resident+halo buffer (slots drive the
-    index maps, global ids the hit predicate).  ``live == 0`` rows are
-    SPMD / bucket padding and write all-zero masks.  Operands: the
-    points and their transpose."""
+    """Pass-2 declaration: per table row, its tile's hit rows packed
+    (``(bp, bp)`` column ids, :func:`_id_code`) and their hit counts
+    (a ``(1, bp)`` int32 row), each block written once.
+    ``halo=False``: 3-col rows ``(i, j, live)`` over one point buffer;
+    ``halo=True``: 5-col rows ``(i_slot, j_slot, i, j, live)`` over a
+    shard's resident+halo buffer (slots drive the index maps, global ids
+    the hit predicate).  ``live == 0`` rows are SPMD / bucket padding
+    and count no hits.  Operands: the points and their transpose."""
     if choice is not None:
         choice = as_choice(choice, kind="triangle").with_(block=(int(bp),))
     steps = table.shape[0]
@@ -347,8 +399,14 @@ def simjoin_emit_program(
             gi_col=gi_col, gj_col=gj_col, live_col=live_col,
         ),
         in_specs=_tile_pair_specs(bp, D, 0, 1),
-        out_specs=pl.BlockSpec((1, bp, bp), lambda s, sr: (s, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((steps, bp, bp), jnp.int8),
+        out_specs=[
+            pl.BlockSpec((1, bp, bp), lambda s, sr: (s, 0, 0)),
+            pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((steps, bp, bp), _id_code(bp)[0]),
+            jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
+        ],
         columns=(
             ("i_slot", "j_slot", "i", "j", "live") if halo
             else ("i", "j", "live")
@@ -365,58 +423,88 @@ def simjoin_emit_swizzled(
     bp: int,
     n_valid: int | None = None,
     interpret: bool = False,
-) -> jax.Array:
-    """Hit masks int8[rows, bp, bp] of the ``(i_tile, j_tile, live)``
-    rows of ``table`` (ops.py passes the tiles pass 1 found non-empty,
-    padded to a power-of-two row count with ``live=0``).
-    :func:`pairs_from_masks` turns them into pairs."""
+) -> tuple[jax.Array, jax.Array]:
+    """Packed hit rows ``(ids[rows, bp, bp], counts int32[rows, 1, bp])``
+    of the ``(i_tile, j_tile, live)`` rows of ``table`` (ops.py passes
+    the tiles pass 1 found non-empty, padded to a power-of-two row count
+    with ``live=0``).  :func:`pairs_from_masks` turns them into pairs."""
     N, D = x.shape
     assert N % bp == 0
     program = simjoin_emit_program(table, eps=eps, bp=bp, D=D, n_valid=n_valid)
-    return launch(program, x, x.T, interpret=interpret)
+    return tuple(launch(program, x, x.T, interpret=interpret))
 
 
 def _bucket(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
 
 
-@functools.partial(jax.jit, static_argnames=("bp", "size", "P"))
-def simjoin_compact(masks, rows, tiles, *, bp: int, size: int, P: int):
-    """The first ``P`` hits of ``masks[rows]`` as pairs, int32[P, 2]
-    (``size`` >= P bounds the ``nonzero``)."""
-    flat = masks[rows].reshape(-1)
-    (f,) = jnp.nonzero(flat, size=size, fill_value=0)
-    t = tiles[f // (bp * bp)]
-    r = f % (bp * bp)
-    out = jnp.stack([t[:, 0] * bp + r // bp, t[:, 1] * bp + r % bp], axis=1)
-    return out[:P]
+def _spread(starts, values, P: int):
+    """``values[..., q]`` at every k in ``[starts[q], starts[q + 1])``,
+    int[..., P], for sorted ``starts`` (those >= P drop out).  Each q
+    scatters its step from the value before it at its start, and a
+    cumsum adds the steps up: those up to q telescope to q's value."""
+    steps = jnp.diff(values, axis=-1, prepend=0)
+    out = jnp.zeros(values.shape[:-1] + (P,), values.dtype)
+    out = out.at[..., starts].add(steps, mode="drop", indices_are_sorted=True)
+    return jnp.cumsum(out, axis=-1)
 
 
-def pairs_from_masks(masks, rows, tiles, P: int, bp: int) -> jax.Array:
-    """int32[P, 2] pairs of the hit masks ``masks[rows]``, in row order
-    then row-major in-tile order.  ``tiles`` int[len(rows), 2] holds the
-    global (i_tile, j_tile) of each listed row; ``P`` is the pass-1
-    total, so the compaction has its exact size."""
+@functools.partial(jax.jit, static_argnames=("bp", "P"))
+def simjoin_compact(ids, counts, rows, tiles, *, bp: int, P: int):
+    """The ``P`` hits of the packed rows of table rows ``rows`` (-1:
+    padding) as pairs, int32[P, 2], walking tile rows, never cells.
+
+    Tile row q (row ``q % bp`` of listed table row ``t = q // bp``) holds
+    outputs ``[off_q, off_q + n_q)``, ``off`` the exclusive cumsum of the
+    row counts; output k is its hit ``p = k - off_q``.  One spread over
+    rows gives every output ``w = q·bp + p``; one over table rows gives
+    its tile's global bases and how far its ids lie from slot t in the
+    id table, which ``rows`` indexes in place.  The column id is then
+    one 1-D gather."""
+    _, bias = _id_code(bp)
+    n = rows.shape[0]
+    live = rows >= 0
+    rows = jnp.where(live, rows, 0)
+    cnt = jnp.where(live[:, None], counts.reshape(-1, bp)[rows], 0).reshape(-1)
+    off = jnp.cumsum(cnt) - cnt
+    q = jnp.arange(n * bp, dtype=jnp.int32)
+    w = _spread(off, q * bp - off, P) + jnp.arange(P, dtype=jnp.int32)
+    gi, gj, moved = _spread(off[::bp], jnp.stack([
+        tiles[:, 0] * bp,
+        tiles[:, 1] * bp,
+        (rows - jnp.arange(n, dtype=jnp.int32)) * (bp * bp),
+    ]), P)
+    col = ids.reshape(-1)[w + moved].astype(jnp.int32) + bias
+    return jnp.stack([gi + w // bp % bp, gj + col], axis=1)
+
+
+def pairs_from_masks(ids, counts, rows, tiles, P: int, bp: int) -> jax.Array:
+    """int32[P, 2] pairs of pass 2's hit masks in packed form (the
+    ``ids``, ``counts`` of :func:`simjoin_emit_swizzled`) at table rows
+    ``rows``, in row order then row-major in-tile order.  ``tiles``
+    int[len(rows), 2] holds the global (i_tile, j_tile) of each listed
+    row; ``P`` is the pass-1 total, so the compaction has its exact
+    size."""
     rows = np.asarray(rows, dtype=np.int32)
-    if len(rows) * bp * bp >= 2**31:
+    n = _bucket(len(rows))
+    if max(n, ids.shape[0]) * bp * bp >= 2**31:
         raise ValueError(
-            f"{len(rows)} non-empty tiles of {bp}x{bp} exceed int32 mask "
+            f"{max(n, ids.shape[0])} tiles of {bp}x{bp} exceed int32 "
             f"indexing; reduce eps or join in chunks"
         )
     with span("simjoin.compact"):
-        n = _bucket(len(rows))
         count("simjoin.pairs_out", P)
         count("simjoin.mask_rows_scanned", n)
         count("simjoin.mask_cells_scanned", n * bp * bp)
-        rows_p = np.zeros(n, np.int32)
+        count("simjoin.rows_flattened", n * bp)
+        # padding rows (-1) count no hits
+        rows_p = np.full(n, -1, np.int32)
         rows_p[: len(rows)] = rows
         tiles_p = np.zeros((n, 2), np.int32)
         tiles_p[: len(rows)] = tiles
-        # padded rows repeat mask 0, but the compaction stops at P hits —
-        # all of them in the listed rows, which come first
         return simjoin_compact(
-            masks, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp,
-            size=_bucket(P), P=P,
+            ids, counts, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp,
+            P=P,
         )
 
 
@@ -474,8 +562,8 @@ def simjoin_pairs_scheduled(
         table = jnp.asarray(emission_table(nz, np.ones(len(nz))))
     count("simjoin.tiles_live", len(nz))
     with span("simjoin.pass2"):
-        masks = simjoin_emit_swizzled(
+        ids, counts = simjoin_emit_swizzled(
             table, xp, eps=float(eps), bp=bp, n_valid=n_valid,
             interpret=interpret,
         )
-    return pairs_from_masks(masks, np.arange(len(nz)), nz, P, bp)
+    return pairs_from_masks(ids, counts, np.arange(len(nz)), nz, P, bp)

@@ -11,6 +11,7 @@ worker that describes it while collecting would starve the others.
 Every compile happens in this process; no child process is started.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,8 +183,10 @@ def test_fused_lloyd_compiles(one_chip):
     )
 
 
-@pytest.mark.parametrize("pass_", ["hits", "emit"])
+@pytest.mark.parametrize("pass_", ["hits", "emit", "flatten"])
 def test_simjoin_compiles(one_chip, pass_):
+    if pass_ == "flatten":
+        return _check_simjoin_flatten(one_chip)
     from repro.kernels.simjoin import (
         simjoin_emit_swizzled,
         simjoin_tile_hits_swizzled,
@@ -198,8 +201,37 @@ def test_simjoin_compiles(one_chip, pass_):
             s, x, eps=0.1, bp=bp, interpret=False
         )
     else:
-        sd = _spec((1024, 3), jnp.int32, one_chip)  # (i, j, live) rows
+        # packed rows and row counts of 8192 (i, j, live) table rows
+        sd = _spec((8192, 3), jnp.int32, one_chip)
         fn = lambda s, x: simjoin_emit_swizzled(  # noqa: E731
             s, x, eps=0.1, bp=bp, interpret=False
         )
     _compile(fn, sd, x)
+
+
+def _check_simjoin_flatten(one_chip):
+    """The row flatten at the eps-k100 cell's size: 8192 table rows of
+    256-point tiles, 5,361,808 pairs.  Every gather it holds takes a
+    1-D index (a gather indexed by (P, 2) compiles for minutes), and
+    nothing sorts."""
+    from repro.kernels.simjoin import simjoin_compact
+
+    n, bp, P = 8192, 256, 5_361_808
+    lowered = jax.jit(
+        lambda i, c, r, t: simjoin_compact(i, c, r, t, bp=bp, P=P)
+    ).lower(
+        _spec((n, bp, bp), jnp.int8, one_chip),
+        _spec((n, 1, bp), jnp.int32, one_chip),
+        _spec((n,), jnp.int32, one_chip),
+        _spec((n, 2), jnp.int32, one_chip),
+    )
+    gathers = [
+        line for line in lowered.as_text().splitlines()
+        if "stablehlo.gather" in line
+    ]
+    assert gathers
+    for line in gathers:
+        operands = line.split(") -> ")[0]
+        index = re.findall(r"tensor<([0-9x]+)xi32>", operands)[-1]
+        assert index.count("x") <= 1, line  # (k,) or (k, 1) indices
+    assert " sort(" not in lowered.compile().as_text()

@@ -72,6 +72,7 @@ def test_join_counters_match_the_inputs(points):
         "simjoin.tiles_live": want_live,
         "simjoin.mask_rows_scanned": 1 << (want_live - 1).bit_length(),
         "simjoin.mask_cells_scanned": (1 << (want_live - 1).bit_length()) * BP * BP,
+        "simjoin.rows_flattened": (1 << (want_live - 1).bit_length()) * BP,
     }
 
 
