@@ -12,7 +12,9 @@ and never an edit here:
   answers the check samples, and the limit of each compared number;
 * ``bench/metrics/<metric>.py``: one ``read(evidence)`` per metric,
   end-to-end or per layer, returning a number or ``None`` when it finds
-  nothing to read;
+  nothing to read; the evidence holds the window's times, the reduced
+  trace (``bench/trace.py``) and the program's counters as the window's
+  solves counted them;
 * ``bench/peaks.json``: the chip's peaks by ``device_kind``.
 
 A run is a closed loop, one job at a time, as a batch-job runner calls
@@ -142,15 +144,14 @@ class CompileCounter:
             self.secs += secs
 
 
-def order_cache():
-    """(hits, misses) of the program's Hilbert point-order cache, or
-    None where the program has none."""
+def program_counters() -> dict:
+    """A copy of the program's counters (``repro.core.tracing``), or {}
+    where the program keeps none."""
     try:
-        from repro.kernels.kmeans import _cached_order
+        from repro.core.tracing import counters
     except ImportError:
-        return None
-    info = _cached_order.cache_info()
-    return info.hits, info.misses
+        return {}
+    return counters()
 
 
 # ---------------------------------------------------------------------------
@@ -175,6 +176,25 @@ def device_ms_per_solve(ev, needle: str):
         return None
     ns = tr.device_ns_matching(needle)
     return ns / 1e6 / ev["solves"] if ns > 0 else None
+
+
+def module_ms_per_solve(ev, stage: str):
+    """Device ms per solve in the program's stage ``stage`` (its jit's
+    modules in the trace's ``XLA Modules`` line)."""
+    tr = ev.get("trace")
+    if tr is None or not ev.get("solves"):
+        return None
+    ns = tr.module_ns.get(stage, 0)
+    return ns / 1e6 / ev["solves"] if ns > 0 else None
+
+
+def counter_percent(ev, part: str, whole: str):
+    """100 × the window's count of ``part`` over its count of ``whole``;
+    None where the window counted no ``whole``."""
+    c = ev.get("counters") or {}
+    if not c.get(whole):
+        return None
+    return 100.0 * c.get(part, 0) / c[whole]
 
 
 # ---------------------------------------------------------------------------
@@ -241,7 +261,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             app = app_mod.Cell(cfg, traffic, seeds)
         with span("solve"):
             jax.block_until_ready(app.solve(app.job("warmup")))
-        cache0 = order_cache()
         setup_compiles, setup_compile_s = counter.count, counter.secs
         gc.collect()
 
@@ -259,6 +278,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         keep_n = int(traffic["check_solves"])
         kept, sizes = [], []
         with span("window"):
+            counts0 = program_counters()
             t_start = time.perf_counter()
             i = 0
             while True:
@@ -281,6 +301,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                 i += 1
                 if t_end - t_start >= seconds:
                     break
+            counts1 = program_counters()
+        counts = {k: v - counts0.get(k, 0) for k, v in counts1.items()}
         compiles_in_window = counter.count - n0
         summary = None
         if trace:
@@ -291,7 +313,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             shutil.rmtree(log_dir, ignore_errors=True)
         off_fused = sum("VMEM" in str(w.message) for w in caught
                         if issubclass(w.category, RuntimeWarning))
-    cache1 = order_cache()
     device["memory_peak_bytes"] = _memory_peak(devs)
     n = len(sizes)
     setup_s = t_start - t0
@@ -299,15 +320,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
            "compiles": setup_compiles, "compile_s": setup_compile_s}, out)
     _emit({"record": "window", "solves": n, "window_s": t_end - t_start,
            "compiles_in_window": compiles_in_window, "solves_off_fused_path": off_fused,
-           "order_cache": None if cache0 is None else
-           {"hits": cache1[0] - cache0[0], "misses": cache1[1] - cache0[1]},
            "output_sizes": sorted({s for s in sizes if s is not None}),
+           "counters": counts,
            "memory_peak_bytes": device["memory_peak_bytes"]}, out)
 
     ev = {
         "setup_s": setup_s, "window_s": t_end - t_start, "solves": n,
         "solve_s": (t_end - t_start) / n, "compiles_in_window": compiles_in_window,
         "work": app.work(sizes), "peaks": peaks(device["kind"]), "trace": summary,
+        "counters": counts,
     }
     metrics = {}
     for m in cell["per_layer"] if trace else cell["end_to_end"]:

@@ -1,7 +1,9 @@
 """Plain dense ε-self-join and the numbers that compare a join with it.
 
 Nothing here imports the program.  The reference visits every pair
-(i, j), i > j, in row chunks, forms the squared distance
+(i, j), i > j, in tiles of a row chunk by a column block that hold a
+pair of the lower triangle, so that its peak memory is a few tiles at
+any N; it forms the squared distance
 ||x_i||² − 2·x_i·x_j + ||x_j||² with the product at ``precision``
 (``bench.reference.kmeans.dot``), and
 keeps the pair when it is at most ε².  On the integer-grid points of
@@ -25,69 +27,114 @@ import jax.numpy as jnp
 from bench.reference.kmeans import dot
 
 
-def _chunks(x, chunk: int):
-    n = x.shape[0]
-    chunk = min(chunk, n)
-    pad = (-n) % chunk
-    return jnp.pad(x, ((0, pad), (0, 0))), chunk
+# rows per chunk and columns per block of a tile: 64 MB of f32 distances
+# at most, so a count's peak memory is bounded at any N
+CHUNK, BLOCK = 1024, 16384
 
 
-def _hits(x, xp, lo, chunk: int, eps2: float, precision: str):
-    """(chunk, N) mask of the pairs (lo + r, j) with j < lo + r, within ε."""
-    n = x.shape[0]
-    xi = jax.lax.dynamic_slice_in_dim(xp, lo, chunk)
-    xn = jnp.sum(x * x, axis=1)
+def _layout(n: int, chunk: int, block: int):
+    """Rows per chunk ``r``, columns per block ``c`` (a multiple of ``r``)
+    and the padded point count (a multiple of ``c``)."""
+    r = min(chunk, n)
+    c = min(max(block // r, 1) * r, -(-n // r) * r)
+    return r, c, -(-n // c) * c
+
+
+def _hits(xp, xn, lo, co, r: int, c: int, n: int, eps2: float, precision: str):
+    """(r, c) mask of the pairs (lo + a, co + b) with co + b < lo + a < n,
+    within ε, of the padded points ``xp`` and their squared norms ``xn``."""
+    xi = jax.lax.dynamic_slice_in_dim(xp, lo, r)
+    xj = jax.lax.dynamic_slice_in_dim(xp, co, c)
     d2 = (
-        jnp.sum(xi * xi, axis=1)[:, None]
-        - 2.0 * dot(xi, x.T, precision)
-        + xn[None, :]
+        jax.lax.dynamic_slice_in_dim(xn, lo, r)[:, None]
+        - 2.0 * dot(xi, xj.T, precision)
+        + jax.lax.dynamic_slice_in_dim(xn, co, c)[None, :]
     )
-    i = lo + jnp.arange(chunk)[:, None]
-    j = jnp.arange(n)[None, :]
+    i = lo + jnp.arange(r)[:, None]
+    j = co + jnp.arange(c)[None, :]
     return (d2 <= eps2) & (j < i) & (i < n)
 
 
-@functools.partial(jax.jit, static_argnames=("eps2", "precision", "chunk"))
-def _count(x, *, eps2: float, precision: str, chunk: int):
-    xp, chunk = _chunks(x, chunk)
+def _padded(x, n_pad: int):
+    xp = jnp.pad(x, ((0, n_pad - x.shape[0]), (0, 0)))
+    return xp, jnp.sum(xp * xp, axis=1)
 
-    def body(s, tot):
-        h = _hits(x, xp, s * chunk, chunk, eps2, precision)
+
+def _blocks(lo, r: int, c: int):
+    """Column blocks that hold a column left of row chunk ``lo``'s last row."""
+    return (lo + r - 2) // c + 1
+
+
+def _chunk_total(xp, xn, lo, r: int, c: int, n: int, eps2: float, precision: str):
+    """Pairs of row chunk ``lo``, over the column blocks left of its last row."""
+    def col(b, tot):
+        h = _hits(xp, xn, lo, b * c, r, c, n, eps2, precision)
         return tot + jnp.sum(h, dtype=jnp.int32)
 
-    return jax.lax.fori_loop(0, xp.shape[0] // chunk, body, jnp.int32(0))
+    return jax.lax.fori_loop(0, _blocks(lo, r, c), col, jnp.int32(0))
 
 
-def pair_count(x, eps2: float, *, precision: str = "highest", chunk: int = 1024) -> int:
-    """Number of unordered pairs within ε."""
-    return int(_count(x, eps2=float(eps2), precision=precision, chunk=chunk))
+@functools.partial(jax.jit, static_argnames=("eps2", "precision", "chunk", "block"))
+def _count(x, *, eps2: float, precision: str, chunk: int, block: int):
+    n = x.shape[0]
+    r, c, n_pad = _layout(n, chunk, block)
+    xp, xn = _padded(x, n_pad)
+
+    def row(s, tot):
+        return tot + _chunk_total(xp, xn, s * r, r, c, n, eps2, precision)
+
+    return jax.lax.fori_loop(0, -(-n // r), row, jnp.int32(0))
 
 
-@functools.partial(jax.jit, static_argnames=("eps2", "precision", "chunk"))
-def _chunk_count(x, lo, *, eps2, precision, chunk):
-    xp, chunk = _chunks(x, chunk)
-    return jnp.sum(_hits(x, xp, lo, chunk, eps2, precision), dtype=jnp.int32)
+def pair_count(x, eps2: float, *, precision: str = "highest", chunk: int = CHUNK,
+               block: int = BLOCK) -> int:
+    """Number of unordered pairs within ε, counted over (chunk × block)
+    tiles of the lower triangle."""
+    return int(_count(x, eps2=float(eps2), precision=precision, chunk=chunk, block=block))
 
 
-@functools.partial(jax.jit, static_argnames=("eps2", "precision", "chunk", "size"))
-def _chunk_pairs(x, lo, *, eps2, precision, chunk, size):
-    xp, chunk = _chunks(x, chunk)
-    h = _hits(x, xp, lo, chunk, eps2, precision)
-    r, j = jnp.nonzero(h, size=size, fill_value=0)
-    return jnp.stack([lo + r, j], axis=1).astype(jnp.int32)
+@functools.partial(jax.jit, static_argnames=("eps2", "precision", "chunk", "block"))
+def _chunk_count(x, lo, *, eps2, precision, chunk, block):
+    n = x.shape[0]
+    r, c, n_pad = _layout(n, chunk, block)
+    xp, xn = _padded(x, n_pad)
+    return _chunk_total(xp, xn, lo, r, c, n, eps2, precision)
 
 
-def pairs(x, eps2: float, *, precision: str = "highest", chunk: int = 1024):
-    """The pair list itself, int32[P, 2] with i > j (the control's output)."""
-    n_pad = _chunks(x, chunk)[0].shape[0]
-    chunk = min(chunk, x.shape[0])
+@functools.partial(jax.jit, static_argnames=("eps2", "precision", "chunk", "block", "size"))
+def _chunk_pairs(x, lo, *, eps2, precision, chunk, block, size):
+    """Row chunk ``lo``'s pairs, block after block, in the first of
+    ``2 * size`` rows (``size`` at least the chunk's pair count): each
+    block writes ``size`` rows where the last one's pairs end."""
+    n = x.shape[0]
+    r, c, n_pad = _layout(n, chunk, block)
+    xp, xn = _padded(x, n_pad)
+
+    def col(b, carry):
+        out, at = carry
+        h = _hits(xp, xn, lo, b * c, r, c, n, eps2, precision)
+        a, j = jnp.nonzero(h, size=size, fill_value=0)
+        p = jnp.stack([lo + a, b * c + j], axis=1).astype(jnp.int32)
+        out = jax.lax.dynamic_update_slice_in_dim(out, p, at, axis=0)
+        return out, at + jnp.sum(h, dtype=jnp.int32)
+
+    out = jnp.zeros((2 * size, 2), jnp.int32)
+    return jax.lax.fori_loop(0, _blocks(lo, r, c), col, (out, jnp.int32(0)))[0]
+
+
+def pairs(x, eps2: float, *, precision: str = "highest", chunk: int = CHUNK,
+          block: int = BLOCK):
+    """The pair list itself, int32[P, 2] with i > j (the control's output),
+    found over the same tiles as :func:`pair_count`."""
+    n = x.shape[0]
+    r = _layout(n, chunk, block)[0]
+    kw = {"eps2": float(eps2), "precision": precision, "chunk": chunk, "block": block}
     out = []
-    for lo in range(0, n_pad, chunk):
-        m = int(_chunk_count(x, lo, eps2=float(eps2), precision=precision, chunk=chunk))
+    for lo in range(0, n, r):
+        m = int(_chunk_count(x, lo, **kw))
         if m:
             size = 1 << (m - 1).bit_length()
-            out.append(_chunk_pairs(x, lo, eps2=float(eps2), precision=precision,
-                                    chunk=chunk, size=size)[:m])
+            out.append(_chunk_pairs(x, lo, size=size, **kw)[:m])
     if not out:
         return jnp.zeros((0, 2), jnp.int32)
     return jnp.concatenate(out)

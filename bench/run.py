@@ -10,7 +10,8 @@ of standard output is one JSON object: ``correct``, ``attempted``,
 reference beside its limit, also printed as the last lines of standard
 error.  Earlier lines record set-up (compiles and their seconds), the
 window (solves, compiles inside it, solves that left the fused path,
-Hilbert-order cache hits, peak device memory) and the check's readings.
+the program's counters over the window, peak device memory) and the
+check's readings.
 
 It measures the chip it runs on and nothing else: without a TPU, with
 fewer chips than the cell asks for, on a device kind missing from
