@@ -150,6 +150,32 @@ class _OrderCache:
 _cached_order = register_schedule_cache(_OrderCache("order_cache"))
 
 
+def _fmix32(h):
+    """MurmurHash3's 32-bit finaliser: every input bit moves every
+    output bit."""
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(0x85EBCA6B)
+    h = h ^ (h >> 13)
+    h = h * jnp.uint32(0xC2B2AE35)
+    return h ^ (h >> 16)
+
+
+@jax.jit
+def grid_digest(q: jax.Array) -> jax.Array:
+    """A 128-bit fingerprint of the grid ``q`` (int[N, d]), on the
+    device: uint32[4], each the wrapping sum over the grid's cells of a
+    mix of the cell's value and its position under its own seed.  Two
+    grids of one shape that differ share it with probability about
+    2^-128."""
+    v = q.reshape(-1).astype(jnp.uint32)
+    k = jnp.arange(v.shape[0], dtype=jnp.uint32)
+    seeds = (0x9E3779B9, 0x7F4A7C15, 0x94D049BB, 0xBF58476D)
+    return jnp.stack([
+        jnp.sum(_fmix32(v ^ _fmix32(k + jnp.uint32(s))), dtype=jnp.uint32)
+        for s in seeds
+    ])
+
+
 def hilbert_point_order_cached(
     x: jax.Array, *, nbits: int = 8, dims: int | None = None
 ) -> jax.Array:
@@ -158,18 +184,17 @@ def hilbert_point_order_cached(
     The O(N log N) sort-key + argsort pipeline is a pure function of the
     quantised integer grid, so repeated calls on the same point set (every
     Lloyd iteration used to pay it; repeated ε-joins on one dataset still
-    would) hit an LRU cache keyed on a sha256 digest of the grid bytes.
-    Falls back to the uncached computation under tracing (no concrete
-    bytes to key on); bit-identical either way — same keys, same stable
-    argsort.
+    would) hit an LRU cache keyed on the grid's :func:`grid_digest`, so a
+    lookup downloads 16 bytes, not the grid, and hashes nothing on the
+    host.  Two grids sharing a digest would share a permutation, still
+    one of each caller's N points, whose results stay exact.  Falls back
+    to the uncached computation under tracing (no concrete digest to key
+    on); bit-identical either way — same keys, same stable argsort.
     """
     if isinstance(x, jax.core.Tracer):
         return hilbert_point_order(x, nbits=nbits, dims=dims)
-    import hashlib
-
     q, nbits = _quantise_points(x, nbits=nbits, dims=dims)
-    qh = np.ascontiguousarray(np.asarray(q))
-    key = (hashlib.sha256(qh.tobytes()).digest(), qh.shape, nbits)
+    key = (np.asarray(grid_digest(q)).tobytes(), q.shape, nbits)
     return _cached_order.get(key, lambda: hilbert_sort(q, nbits=nbits))
 
 

@@ -67,8 +67,8 @@ from .kmeans import (
 from .launch import resolve_interpret as _interpret
 from .matmul import matmul_swizzled, matmul_swizzled_3d
 from .simjoin import (
+    pruned_schedule,
     simjoin_counts_swizzled,
-    simjoin_map_back,
     simjoin_pairs_scheduled,
     simjoin_permute,
 )
@@ -613,29 +613,34 @@ def simjoin_pairs(
     :class:`repro.core.ScheduleChoice`) overrides ``curve`` and ``bp``
     as one tunable value (autotuner contract; defaults on a miss).
 
-    Two-pass emission, both passes FGF-Hilbert tile-scheduled: pass 1
-    is the count kernel (:func:`simjoin_tile_hits_swizzled`), whose
-    per-tile totals give the exact pair count and the non-empty tiles;
-    pass 2 (:func:`simjoin_emit_swizzled`) packs each non-empty tile's
-    hit rows and counts them, and a row flatten of exact size turns the
-    packed rows into pairs in schedule-then-row-major order.  Ragged N
-    is handled by the same zero-pad + index-mask rule as the counts.  With
-    ``hilbert_order=True`` the join runs on Hilbert-sorted points and the
-    emitted indices are mapped back through the (cached) permutation, so
-    pairs always refer to the original point order.
+    Two-pass emission over a pruned tile-pair schedule, built on the
+    device (:func:`repro.kernels.simjoin.pruned_schedule`): the
+    lower-triangle tile pairs whose bounding boxes lie within ε, in the
+    order ``curve`` visits them.  Pass 1 is the count kernel
+    (:func:`simjoin_tile_hits_swizzled`), whose per-tile totals give the
+    exact pair count and the non-empty tiles; pass 2
+    (:func:`simjoin_emit_swizzled`) packs each non-empty tile's hit rows
+    and counts them, and a row flatten of exact size turns the packed
+    rows into pairs in schedule-then-row-major order.  Both run in fixed
+    blocks, so one code path serves every N, up to the int32 pair
+    offsets.  Ragged N is handled by the same zero-pad + index-mask rule
+    as the counts.  With ``hilbert_order=True`` the join runs on
+    Hilbert-sorted points and the flatten maps the emitted indices back
+    through the (cached) permutation, so pairs always refer to the
+    original point order.
 
     ``mesh=`` (a 1-D mesh from ``repro.launch.mesh.make_app_mesh``) runs
     the distributed two-pass variant: the triangle schedule's rows are
     curve-range partitioned across devices, per-shard counts give the
     global pair count, and each shard packs the hit rows of its non-empty
     tiles — the compacted result is identical to the single-core output
-    (see :mod:`repro.kernels.sharded`).  Neither pass keeps a
-    data-sized buffer in VMEM, so the join has no VMEM-budget fallback.
+    (see :mod:`repro.kernels.sharded`).  No pass keeps a data-sized
+    buffer in VMEM, so the join has no VMEM-budget fallback.
 
     The output size is data-dependent, so this wrapper host-syncs the
-    pass-1 totals between the two dispatches — it cannot run under an
-    outer ``jax.jit`` (P must be concrete), which is inherent to any
-    exact-size join output.
+    kept tile-pair count and the pass-1 totals between dispatches — it
+    cannot run under an outer ``jax.jit`` (P must be concrete), which is
+    inherent to any exact-size join output.
     """
     ch = _app_choice(choice, "simjoin_pairs", x)
     if ch is not None:
@@ -663,19 +668,18 @@ def simjoin_pairs(
         xp = jnp.pad(x, ((0, pn), (0, 0))) if pn else x
         pt = xp.shape[0] // bp
         n_valid = N if pn else None
-        interp = _interpret(interpret)
         with span("simjoin.schedule"):
-            tri = triangle_schedule(curve, pt, strict=False)
+            sched, kept = pruned_schedule(
+                xp, eps=float(eps), bp=bp, n_valid=n_valid, curve=curve
+            )
+        count("simjoin.tri_pairs", pt * (pt + 1) // 2)
         # the two-pass hits → non-empty tiles → emit machinery is the
-        # shared driver (kernels/simjoin.py), reused verbatim by the
-        # streaming join's per-tick probe dispatch (serve/apps.py)
-        pairs = simjoin_pairs_scheduled(
-            tri, xp, eps=float(eps), bp=bp, n_valid=n_valid, interpret=interp
+        # shared driver (kernels/simjoin.py), reused by the streaming
+        # join's per-tick probe dispatch (serve/apps.py)
+        return simjoin_pairs_scheduled(
+            sched, xp, eps=float(eps), bp=bp, n_valid=n_valid,
+            interpret=_interpret(interpret), steps=kept, perm=perm,
         )
-        if perm is not None:
-            with span("simjoin.map_back"):
-                pairs = simjoin_map_back(pairs, perm)
-        return pairs
 
 
 def floyd_warshall(

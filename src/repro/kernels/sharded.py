@@ -93,7 +93,6 @@ from .simjoin import (
     pairs_from_masks,
     simjoin_emit_program,
     simjoin_hits_rows_program,
-    simjoin_map_back,
 )
 
 __all__ = [
@@ -615,23 +614,19 @@ def simjoin_pairs_sharded(
     axis, num = mesh_axis(mesh)
     tri = np.asarray(triangle_schedule(curve, pt, strict=False))
     if halo:
-        pairs = _join_halo(
+        return _join_halo(
             x, xp, float(eps), mesh=mesh, axis=axis, num=num, bp=bp, D=D,
             pt=pt, n_valid=n_valid, tri=tri, sorted_keys=hilbert_order,
-            interp=interp, volume=_volume,
+            interp=interp, volume=_volume, perm=perm,
         )
-    else:
-        pairs = _join_replicated(
-            x, xp, float(eps), mesh=mesh, axis=axis, num=num, bp=bp, D=D,
-            n_valid=n_valid, tri=tri, interp=interp, volume=_volume,
-        )
-    if perm is not None:
-        pairs = simjoin_map_back(pairs, perm)
-    return pairs
+    return _join_replicated(
+        x, xp, float(eps), mesh=mesh, axis=axis, num=num, bp=bp, D=D,
+        n_valid=n_valid, tri=tri, interp=interp, volume=_volume, perm=perm,
+    )
 
 
 def _join_replicated(
-    x, xp, eps, *, mesh, axis, num, bp, D, n_valid, tri, interp, volume
+    x, xp, eps, *, mesh, axis, num, bp, D, n_valid, tri, interp, volume, perm
 ):
     steps = len(tri)
     # SPMD-uniform curve-range partition of the triangle schedule's rows
@@ -681,13 +676,13 @@ def _join_replicated(
     # shards hold contiguous ranges of the global schedule, so shard
     # order IS global order
     return pairs_from_masks(
-        ids, counts, np.concatenate(rows), tri[tot > 0], P_total, bp
+        ids, counts, np.concatenate(rows), tri[tot > 0], P_total, bp, perm
     )
 
 
 def _join_halo(
     x, xp, eps, *, mesh, axis, num, bp, D, pt, n_valid, tri, sorted_keys,
-    interp, volume
+    interp, volume, perm
 ):
     # uniform resident layout: every shard owns ptl tiles (tail pure pad;
     # pad tiles never appear in the schedule, so n_valid is untouched)
@@ -752,7 +747,7 @@ def _join_halo(
     # which equals the full triangle order because pruned rows are
     # provably pair-free — so the result is array-equal to single-core
     nz = tot > 0
-    return pairs_from_masks(ids, counts, pos[nz], pruned[nz], P_total, bp)
+    return pairs_from_masks(ids, counts, pos[nz], pruned[nz], P_total, bp, perm)
 
 
 # ---------------------------------------------------------------------------

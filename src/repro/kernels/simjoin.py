@@ -1,17 +1,20 @@
 """ε-similarity-join kernels with FGF jump-over scheduling (paper §7, [20]).
 
 The join enumerates unordered point pairs with ‖x_i − x_j‖ ≤ ε.  Only the
-lower-triangular (i_tile ≥ j_tile) half of the tile grid carries work —
-the FGF-Hilbert walker (paper §6.2) enumerates exactly those tiles in
-Hilbert order, keeping the true Hilbert order value of every tile for
-work-range accounting, and skipping the empty half at O(log) cost instead
-of masking it.
+lower-triangular (i_tile ≥ j_tile) half of the tile grid carries work,
+and of it only the tile pairs whose bounding boxes lie within ε:
+:func:`simjoin_schedule` tests every such pair on the device and lists
+the ones it keeps in the order the curve visits them (for ``hilbert``
+the true Hilbert order value of each tile pair, as the FGF-Hilbert
+walker enumerates the triangle), so no host loop over tile pairs is
+left on the join's path.
 
 Point ordering: the join benefits doubly from Hilbert machinery — the
-FGF walker orders the *tiles*, and :func:`repro.kernels.kmeans.
+curve orders the *tiles*, and :func:`repro.kernels.kmeans.
 hilbert_point_order` (d-dimensional ``hilbert_sort_key``) can pre-sort
 the *points* so ε-neighbours concentrate near the tile-grid diagonal
-(``hilbert_order=True`` in ops.py).
+(``hilbert_order=True`` in ops.py), which is also what makes the
+tiles' boxes small and the pruned schedule short.
 
 Two passes, one hit predicate (:func:`_hit_tile`, shared so counts and
 emitted pairs can never disagree).  The kernels read the i tile
@@ -30,13 +33,22 @@ lane-dense rows:
   ``[0, n_r)``) and writes them with the row counts ``n_r`` to its own
   output blocks (write-once, order-free).
 
+Both passes run in fixed blocks (:func:`simjoin_pairs_scheduled`): pass
+1 in dispatches of one compiled size of at most :data:`PASS1_STEPS`
+steps, whose schedule slice fits SMEM and whose grid runs only the
+block's live steps, and pass 2 in dispatches of at most
+:data:`PASS2_CELLS` mask cells, each flattened into its share of the
+one output before the next, so the join has no size limit short of its
+int32 pair offsets.
+
 The compaction is two-level and count-directed: the kernel packs each
-row, then :func:`pairs_from_masks` (:func:`simjoin_compact`) flattens
-whole rows — a scatter over rows and a cumsum over P give each output its
-row, one 1-D gather its column id — in schedule-then-row-major order,
-the order of the tiles' rows.  No cell-level ``jnp.nonzero``: in XLA it
-is a scatter-add with one update per mask cell (and a sort once its
-bins outgrow the chip's memory), while only 0.1–1% of the cells of a
+row, then :func:`simjoin_compact` flattens whole rows — a scatter over
+rows and a cumsum over the pairs give each output its row, one 1-D
+gather its column id — in schedule-then-row-major order, the order of
+the tiles' rows, and maps the ids back through the point order with two
+more 1-D gathers.  No cell-level ``jnp.nonzero``: in XLA it is a
+scatter-add with one update per mask cell (and a sort once its bins
+outgrow the chip's memory), while only 0.1–1% of the cells of a
 non-empty tile hold a pair.  Gathers take 1-D indices only: XLA's TPU
 compile of a gather indexed by ``(P, 2)`` takes minutes at millions of
 pairs.  Sorting inside the kernel has no TPU lowering.
@@ -48,6 +60,7 @@ global_i > global_j always.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -56,11 +69,19 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import as_choice
+from repro.core import as_choice, register_schedule_cache, tile_schedule_nd
+from repro.core.jax_hilbert import hilbert_encode_jax
 from repro.core.program import CurveProgram
 from repro.core.tracing import count, span
 
 from .launch import launch
+
+# Pass-1 steps a dispatch: the schedule slice is scalar-prefetched into
+# SMEM (1 MiB) at 8 bytes a step, so 2^16 steps take 512 KiB.
+PASS1_STEPS = 1 << 16
+# Mask cells a pass-2 dispatch packs: 2^29 (8192 tiles of 256², 512 MB
+# of int8 ids) keeps the flatten's flat indices well inside int32.
+PASS2_CELLS = 1 << 29
 
 
 def check_pair_offsets(P_total: int, bp: int) -> None:
@@ -80,20 +101,6 @@ def check_pair_offsets(P_total: int, bp: int) -> None:
 def simjoin_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
     """The points in the order ``perm`` (the join's Hilbert order)."""
     return x[perm]
-
-
-@jax.jit
-def simjoin_map_back(pairs: jax.Array, perm: jax.Array) -> jax.Array:
-    """Map (i, j) pairs emitted on Hilbert-sorted points back to the
-    original point ids, re-canonicalised to i > j (sorting can flip the
-    order within a pair).  Shared by every emission path — single-core
-    kernel, dense-oracle fallback, sharded two-pass — so the canonical
-    form can never diverge between them."""
-    pp = perm[pairs]
-    return jnp.stack(
-        [jnp.maximum(pp[:, 0], pp[:, 1]), jnp.minimum(pp[:, 0], pp[:, 1])],
-        axis=1,
-    )
 
 
 _LANES = (((1,), (1,)), ((), ()))  # contract the lane axes: A @ B^T
@@ -164,6 +171,7 @@ def simjoin_tile_hits_swizzled(
     bp: int = 256,
     n_valid: int | None = None,
     interpret: bool = False,
+    live=None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-step partial hit sums: (row_hits, col_hits), each int32[steps, bp].
 
@@ -171,12 +179,16 @@ def simjoin_tile_hits_swizzled(
     pairs (any order; FGF-Hilbert by default via ops.py).
     x: (N, D) with N % bp == 0.  ``row_hits[s].sum()`` is the number of
     unordered pairs found in step ``s``'s tile — pass 1 of pair emission.
+    ``live`` (traced, optional): the grid runs the first ``live`` steps
+    only, and the rows past them are never written.
     """
     N, D = x.shape
     assert N % bp == 0
     program = simjoin_hits_program(
         schedule, eps=eps, bp=bp, D=D, n_valid=n_valid
     )
+    if live is not None:
+        program = dataclasses.replace(program, grid=(live,))
     hi, hj = launch(program, x, x.T, interpret=interpret)
     return hi[:, 0], hj[:, 0]
 
@@ -271,22 +283,33 @@ def simjoin_hits_rows_program(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("steps", "eps", "bp", "n_valid", "interpret")
+)
 def simjoin_totals(
     schedule: jax.Array,
+    start,
+    live,
     x: jax.Array,
     *,
+    steps: int,
     eps: float,
     bp: int,
     n_valid: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Pass 1 of pair emission: the number of unordered pairs in each
-    schedule step's tile, int32[steps]."""
+    """One block of pass 1 of pair emission: the number of unordered
+    pairs in the tiles of schedule rows ``[start, start + live)``,
+    int32[steps], zero past ``live`` (1 <= live <= steps).  The block
+    prefetches ``steps`` rows and its grid runs ``live`` steps: both
+    ``start`` and ``live`` are traced, so every block of one size is one
+    compiled program, and a short last block costs only its own steps."""
+    block = jax.lax.dynamic_slice_in_dim(schedule, start, steps, axis=0)
     hits_i, _ = simjoin_tile_hits_swizzled(
-        schedule, x, eps=eps, bp=bp, n_valid=n_valid, interpret=interpret
+        block, x, eps=eps, bp=bp, n_valid=n_valid, interpret=interpret,
+        live=live,
     )
-    return jnp.sum(hits_i, axis=1)
+    return jnp.where(jnp.arange(steps) < live, jnp.sum(hits_i, axis=1), 0)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
@@ -314,6 +337,129 @@ def simjoin_counts_swizzled(
     counts = counts.at[schedule[:, 0]].add(hits_i)
     counts = counts.at[schedule[:, 1]].add(hits_j)
     return counts.reshape(N)
+
+
+# ---------------------------------------------------------------------------
+# The schedule: lower-triangle tile pairs whose boxes lie within ε
+# ---------------------------------------------------------------------------
+
+def _tile_boxes(xp, bp: int, n_valid: int | None):
+    """Per tile of ``bp`` rows: the bounding box ``(lo, hi)`` f32[pt, D]
+    of its valid rows (pad rows left out) and the largest squared norm
+    among them, f32[pt]."""
+    Np, D = xp.shape
+    xt = xp.astype(jnp.float32).reshape(Np // bp, bp, D)
+    valid = (jnp.arange(Np) < (Np if n_valid is None else n_valid)).reshape(
+        Np // bp, bp, 1
+    )
+    lo = jnp.min(jnp.where(valid, xt, jnp.inf), axis=1)
+    hi = jnp.max(jnp.where(valid, xt, -jnp.inf), axis=1)
+    sq = jnp.max(jnp.where(valid[..., 0], jnp.sum(xt * xt, axis=2), 0.0), axis=1)
+    return lo, hi, sq
+
+
+def _reach(xp, *, eps: float, bp: int, n_valid: int | None):
+    """bool[pt, pt]: lower-triangle tile pairs (i >= j) whose bounding
+    boxes lie within ε, and so every pair the kernels can count.
+
+    The box gap takes the relative f32 slack of the sharded reach test,
+    plus a bound on the rounding of the kernels' f32 distance ``|a|² −
+    2a·b + |b|²``, which grows with the points' squared norms: a pair
+    the kernel would count always lies in a kept tile pair."""
+    lo, hi, sq = _tile_boxes(xp, bp, n_valid)
+    pt, D = lo.shape
+    g2 = jnp.zeros((pt, pt), jnp.float32)
+    for d in range(D):
+        g = jnp.maximum(
+            jnp.maximum(lo[:, None, d] - hi[None, :, d], lo[None, :, d] - hi[:, None, d]),
+            0.0,
+        )
+        g2 = g2 + g * g
+    eps_eff = float(eps) * (1.0 + 1e-5) + 1e-6
+    thr = eps_eff * eps_eff + (2 * D + 6) * 2.0**-23 * (sq[:, None] + sq[None, :])
+    i = jax.lax.broadcasted_iota(jnp.int32, (pt, pt), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (pt, pt), 1)
+    return (i >= j) & (g2 <= thr)
+
+
+@register_schedule_cache
+@functools.lru_cache(maxsize=1)
+def _curve_rank(curve: str, pt: int) -> jax.Array:
+    """int32[pt·pt]: each tile pair's position on ``curve``'s walk of
+    the pt×pt grid, whose lower triangle is the triangle schedule.
+
+    For the curves other than ``hilbert``, which has a device encoder.
+    The walk is built on the host once per (curve, pt), O(pt²): 61 M
+    cells and 244 MB on the device at 2,000,000 points of bp = 256, so
+    only the latest table is kept."""
+    path = np.asarray(tile_schedule_nd(curve, (pt, pt)), dtype=np.int64)
+    rank = np.empty(pt * pt, np.int32)
+    rank[path[:, 0] * pt + path[:, 1]] = np.arange(pt * pt, dtype=np.int32)
+    return jnp.asarray(rank)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "cap"))
+def simjoin_schedule(xp, rank, *, eps: float, bp: int, n_valid: int | None, cap: int):
+    """The pruned schedule of the points ``xp``: ``(sched int32[cap, 2],
+    kept)``, the ``kept`` tile pairs of :func:`_reach` in curve order
+    (``rank``, or the Hilbert order value of ``(i, j)`` where ``rank`` is
+    None), then rows of zeros.  ``cap=0`` gives only ``kept``, which
+    sizes ``cap``.
+
+    Pair k lies in the row i whose kept pairs start at or before k, as
+    the kth hit past that start: a spread over the rows' offsets and a
+    binary search over each row's running count, never a compaction of
+    the pt² cells."""
+    keep = _reach(xp, eps=eps, bp=bp, n_valid=n_valid)
+    kept = jnp.sum(keep, dtype=jnp.int32)
+    if cap == 0:
+        return jnp.zeros((0, 2), jnp.int32), kept
+    pt = keep.shape[0]
+    run = jnp.cumsum(keep.astype(jnp.int32), axis=1)  # hits up to (i, j)
+    n_row = run[:, -1]
+    start = jnp.cumsum(n_row) - n_row
+    i = _spread(start, jnp.arange(pt, dtype=jnp.int32), cap)
+    want = jnp.arange(cap, dtype=jnp.int32) - start[i] + 1
+    # j = the count of columns whose running count is still below want
+    j = jnp.zeros(cap, jnp.int32)
+    for b in reversed(range(max(pt - 1, 1).bit_length())):
+        probe = j + (1 << b)
+        below = run.reshape(-1)[i * pt + jnp.minimum(probe, pt) - 1] < want
+        j = jnp.where((probe <= pt) & below, probe, j)
+    j = jnp.minimum(j, pt - 1)
+    if rank is None:
+        key = hilbert_encode_jax(i, j, max(pt - 1, 1).bit_length())
+    else:
+        key = rank[i * pt + j]
+    live = jnp.arange(cap, dtype=jnp.int32) < kept
+    key = jnp.where(live, key, jnp.iinfo(jnp.int32).max)
+    # the cell i·pt + j rides along (pt² < 2^31, as `run`'s flat index needs)
+    _, ij = jax.lax.sort((key, jnp.where(live, i * pt + j, 0)), num_keys=1)
+    return jnp.stack([ij // pt, ij % pt], axis=1), kept
+
+
+def _schedule_cap(kept: int) -> int:
+    """Rows of the pruned schedule: the power of two at or above 5/4 of
+    ``kept``, each size a compile.  A join's kept count moves with the
+    order of its points by a fraction of a percent, so a count just under
+    a power of two (a 2,000,000-point join keeps 130-131 k tile pairs;
+    2^17 = 131,072) would switch sizes from join to join; with the
+    headroom it takes the larger one every time."""
+    return _bucket(kept + kept // 4)
+
+
+def pruned_schedule(
+    xp: jax.Array, *, eps: float, bp: int, n_valid: int | None, curve: str,
+) -> tuple[jax.Array, int]:
+    """The tile pairs of ``xp`` (Np % bp == 0) a join has to visit, on
+    the device: ``(sched, kept)`` as :func:`simjoin_schedule` gives them,
+    with ``cap`` from :func:`_schedule_cap` (``kept`` is the one sync)."""
+    pt = xp.shape[0] // bp
+    rank = None if curve == "hilbert" else _curve_rank(curve, pt)
+    kw = dict(eps=float(eps), bp=bp, n_valid=n_valid)
+    kept = int(simjoin_schedule(xp, rank, cap=0, **kw)[1])
+    sched, _ = simjoin_schedule(xp, rank, cap=_schedule_cap(kept), **kw)
+    return sched, kept
 
 
 # ---------------------------------------------------------------------------
@@ -449,10 +595,10 @@ def _spread(starts, values, P: int):
     return jnp.cumsum(out, axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("bp", "P"))
-def simjoin_compact(ids, counts, rows, tiles, *, bp: int, P: int):
-    """The ``P`` hits of the packed rows of table rows ``rows`` (-1:
-    padding) as pairs, int32[P, 2], walking tile rows, never cells.
+def _flatten_rows(ids, counts, rows, tiles, *, bp: int, P: int):
+    """The first ``P`` hits of the packed rows of table rows ``rows``
+    (-1: padding) as global ids ``(i, j)``, two int32[P], walking tile
+    rows, never cells.
 
     Tile row q (row ``q % bp`` of listed table row ``t = q // bp``) holds
     outputs ``[off_q, off_q + n_q)``, ``off`` the exclusive cumsum of the
@@ -475,22 +621,36 @@ def simjoin_compact(ids, counts, rows, tiles, *, bp: int, P: int):
         (rows - jnp.arange(n, dtype=jnp.int32)) * (bp * bp),
     ]), P)
     col = ids.reshape(-1)[w + moved].astype(jnp.int32) + bias
-    return jnp.stack([gi + w // bp % bp, gj + col], axis=1)
+    return gi + w // bp % bp, gj + col
 
 
-def pairs_from_masks(ids, counts, rows, tiles, P: int, bp: int) -> jax.Array:
-    """int32[P, 2] pairs of pass 2's hit masks in packed form (the
-    ``ids``, ``counts`` of :func:`simjoin_emit_swizzled`) at table rows
-    ``rows``, in row order then row-major in-tile order.  ``tiles``
-    int[len(rows), 2] holds the global (i_tile, j_tile) of each listed
-    row; ``P`` is the pass-1 total, so the compaction has its exact
-    size."""
+@functools.partial(
+    jax.jit, static_argnames=("bp", "cap"), donate_argnames=("out",)
+)
+def simjoin_compact(out, ids, counts, rows, tiles, perm, start, *, bp: int, cap: int):
+    """``out`` (int32[M, 2]) with rows ``[start, start + cap)`` replaced
+    by the first ``cap`` hits of the packed rows (:func:`_flatten_rows`)
+    as pairs ``i > j``, their ids mapped through ``perm`` (the points'
+    order; None: the ids as they are).  Hits past the rows' own count
+    are junk that a later block, or the caller's final slice, drops."""
+    i, j = _flatten_rows(ids, counts, rows, tiles, bp=bp, P=cap)
+    if perm is not None:
+        # two 1-D gathers: the map back takes no (P, 2) index
+        i, j = perm[i], perm[j]
+        i, j = jnp.maximum(i, j), jnp.minimum(i, j)
+    return jax.lax.dynamic_update_slice(out, jnp.stack([i, j], axis=1), (start, 0))
+
+
+def _compact_block(out, ids, counts, rows, tiles, *, P: int, cap: int, bp: int,
+                   start: int = 0, perm=None) -> jax.Array:
+    """:func:`simjoin_compact` of ``P`` pairs in the listed rows, with the
+    rows padded to a power of two and the compaction's counters."""
     rows = np.asarray(rows, dtype=np.int32)
     n = _bucket(len(rows))
     if max(n, ids.shape[0]) * bp * bp >= 2**31:
         raise ValueError(
             f"{max(n, ids.shape[0])} tiles of {bp}x{bp} exceed int32 "
-            f"indexing; reduce eps or join in chunks"
+            f"indexing; flatten fewer tiles at a time"
         )
     with span("simjoin.compact"):
         count("simjoin.pairs_out", P)
@@ -503,9 +663,24 @@ def pairs_from_masks(ids, counts, rows, tiles, P: int, bp: int) -> jax.Array:
         tiles_p = np.zeros((n, 2), np.int32)
         tiles_p[: len(rows)] = tiles
         return simjoin_compact(
-            ids, counts, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp,
-            P=P,
+            out, ids, counts, jnp.asarray(rows_p), jnp.asarray(tiles_p), perm,
+            start, bp=bp, cap=cap,
         )
+
+
+def pairs_from_masks(ids, counts, rows, tiles, P: int, bp: int,
+                     perm=None) -> jax.Array:
+    """int32[P, 2] pairs of pass 2's hit masks in packed form (the
+    ``ids``, ``counts`` of :func:`simjoin_emit_swizzled`) at table rows
+    ``rows``, in row order then row-major in-tile order.  ``tiles``
+    int[len(rows), 2] holds the global (i_tile, j_tile) of each listed
+    row; ``P`` is the pass-1 total, so the compaction has its exact
+    size.  With ``perm`` the ids are mapped through it (the points'
+    order), as :func:`simjoin_compact` does."""
+    return _compact_block(
+        jnp.zeros((P, 2), jnp.int32), ids, counts, rows, tiles, P=P, cap=P,
+        bp=bp, perm=perm,
+    )
 
 
 def emission_table(tiles, live) -> np.ndarray:
@@ -528,42 +703,80 @@ def simjoin_pairs_scheduled(
     bp: int,
     n_valid: int | None = None,
     interpret: bool = False,
+    steps: int | None = None,
+    perm: jax.Array | None = None,
 ) -> jax.Array:
     """Two-pass pair emission over an ARBITRARY lower-triangle tile-pair
-    schedule: int32[P, 2] local-index pairs, i > j, in schedule-then-
-    row-major order.
+    schedule: int32[P, 2] pairs, i > j, in schedule-then-row-major order.
 
-    ``schedule`` is any int32[steps, 2] set of (i_tile >= j_tile) pairs
-    — the FGF-Hilbert triangle for the one-shot join (ops.py), or the
+    ``schedule`` is any int32[steps, 2] set of distinct (i_tile >=
+    j_tile) pairs — the pruned schedule the one-shot join builds on the
+    device (ops.py: ``steps`` live rows, then padding rows), or the
     halo-pruned cohort×resident restriction the streaming service
-    builds each tick (serve/apps.py).  This function owns the host step
-    BETWEEN the two kernel dispatches (pass-1 totals → the non-empty
-    tiles and the exact pair count), so the batch and streaming joins
-    cannot diverge on it.  ``xp``: (Np, D) with Np % bp == 0 (callers
-    pad; ``n_valid`` is the true row count when padding exists).
+    builds on the host each tick (serve/apps.py).  This function owns the
+    host step BETWEEN the two kernel dispatches (pass-1 totals → the
+    non-empty tiles and the exact pair count), so the batch and
+    streaming joins cannot diverge on it.  ``xp``: (Np, D) with Np % bp
+    == 0 (callers pad; ``n_valid`` is the true row count when padding
+    exists).  With ``perm`` the pairs' ids are mapped through it (the
+    points' order) and re-canonicalised.
+
+    Both passes run in fixed blocks, so every compiled shape is a power
+    of two of the schedule's or the data's counts: pass 1 in dispatches
+    of the same ``min(PASS1_STEPS, bucket(rows of schedule))`` steps,
+    each running its live steps only; pass 2 in dispatches of
+    ``min(PASS2_CELLS / bp², bucket(live tiles))`` table rows, each
+    flattened into its own rows of the one output.
     """
-    tri = np.asarray(schedule, dtype=np.int32)
-    if tri.shape[0] == 0:
+    if steps is None:
+        schedule = np.asarray(schedule, dtype=np.int32)
+        steps = len(schedule)
+    if steps == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
-    with span("simjoin.pass1"):
-        tot = simjoin_totals(
-            jnp.asarray(tri), xp, eps=float(eps), bp=bp, n_valid=n_valid,
-            interpret=interpret,
-        )
+    block = min(PASS1_STEPS, _bucket(schedule.shape[0]))
+    n_blocks = -(-steps // block)
+    if schedule.shape[0] < n_blocks * block:  # a host schedule
+        schedule = np.pad(schedule, ((0, n_blocks * block - schedule.shape[0]), (0, 0)))
+    sched = jnp.asarray(schedule)
+    count("simjoin.tile_pairs", steps)
+    count("simjoin.pass1_steps", steps)
+    count("simjoin.pass1_blocks", n_blocks)
+    tots = []
+    for b in range(n_blocks):
+        with span("simjoin.pass1", block=b):
+            tots.append(simjoin_totals(
+                sched, b * block, min(block, steps - b * block), xp,
+                steps=block, eps=float(eps), bp=bp, n_valid=n_valid,
+                interpret=interpret,
+            ))
     with span("simjoin.sync"):
-        tot = np.asarray(tot).astype(np.int64)
+        tots, tri = jax.device_get((tots, sched))
+    tot = np.concatenate(tots)[:steps].astype(np.int64)
     P = int(tot.sum())
-    count("simjoin.tile_pairs", len(tri))
     if P == 0:
         return jnp.zeros((0, 2), dtype=jnp.int32)
     check_pair_offsets(P, bp)
     with span("simjoin.table"):
-        nz = tri[tot > 0]
-        table = jnp.asarray(emission_table(nz, np.ones(len(nz))))
-    count("simjoin.tiles_live", len(nz))
-    with span("simjoin.pass2"):
-        ids, counts = simjoin_emit_swizzled(
-            table, xp, eps=float(eps), bp=bp, n_valid=n_valid,
-            interpret=interpret,
+        live = np.flatnonzero(tot)
+        rows = min(max(PASS2_CELLS // (bp * bp), 1), _bucket(len(live)))
+        firsts = np.arange(0, len(live), rows)
+        p_blk = np.add.reduceat(tot[live], firsts)
+        off = np.cumsum(p_blk) - p_blk
+        cap = min(P, _bucket(int(p_blk.max())))
+        # bucket(live) rows: a whole number of blocks
+        table = emission_table(tri[live], np.ones(len(live)))
+    count("simjoin.tiles_live", len(live))
+    count("simjoin.pass2_blocks", len(firsts))
+    out = jnp.zeros((P + cap, 2), jnp.int32)
+    for b, first in enumerate(firsts):
+        blk = table[first : first + rows]
+        with span("simjoin.pass2", block=b):
+            ids, counts = simjoin_emit_swizzled(
+                jnp.asarray(blk), xp, eps=float(eps), bp=bp, n_valid=n_valid,
+                interpret=interpret,
+            )
+        out = _compact_block(
+            out, ids, counts, np.arange(rows), blk[:, :2], P=int(p_blk[b]),
+            cap=cap, bp=bp, start=int(off[b]), perm=perm,
         )
-    return pairs_from_masks(ids, counts, np.arange(len(nz)), nz, P, bp)
+    return out[:P]

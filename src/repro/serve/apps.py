@@ -671,12 +671,6 @@ class StreamSimJoin:
             sched, xp, eps=self.eps, bp=bp,
             n_valid=P_N if pn else None, interpret=self.interpret,
         )
-        if pairs is None:
-            # emission buffer over the VMEM budget: dense host oracle on
-            # the (small) probe buffer — same hit predicate, same filter
-            from repro.kernels import ref
-
-            pairs = ref.simjoin_pairs(jnp.asarray(X), self.eps)
         return np.asarray(pairs, dtype=np.int64), c_start, cand
 
     # -- handlers -------------------------------------------------------

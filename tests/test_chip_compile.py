@@ -23,7 +23,6 @@ from repro.core import (
     phased_schedule,
     tile_schedule,
     tile_schedule_nd,
-    triangle_schedule,
 )
 from repro.core.schedule import mark_first_visits
 
@@ -183,53 +182,78 @@ def test_fused_lloyd_compiles(one_chip):
     )
 
 
-@pytest.mark.parametrize("pass_", ["hits", "emit", "flatten"])
+# the simjoin-syn3d-2m.eps-k6 cell: 2,000,000 points (7,813 tiles of 256,
+# the last ragged), ~131 k kept tile pairs (a 2^18-row schedule), pass 1 in
+# blocks of PASS1_STEPS, pass 2 in blocks of 8192 table rows, 5,922,084
+# pairs flattened 2^20 at a time
+SJ_N, SJ_D, SJ_BP, SJ_P = 2_000_000, 3, 256, 5_922_084
+SJ_NP = -(-SJ_N // SJ_BP) * SJ_BP
+
+
+@pytest.mark.parametrize("pass_", ["hits", "emit", "flatten", "schedule"])
 def test_simjoin_compiles(one_chip, pass_):
     if pass_ == "flatten":
         return _check_simjoin_flatten(one_chip)
     from repro.kernels.simjoin import (
+        PASS1_STEPS,
         simjoin_emit_swizzled,
-        simjoin_tile_hits_swizzled,
+        simjoin_schedule,
+        simjoin_totals,
     )
 
-    N, D, bp = 2**16, 8, 256
-    tri = triangle_schedule("hilbert", N // bp, strict=False)
-    x = _spec((N, D), jnp.float32, one_chip)
+    x = _spec((SJ_NP, SJ_D), jnp.float32, one_chip)
+    if pass_ == "schedule":
+        # the prune of all 30.5 M lower-triangle tile pairs, on the device
+        lowered = jax.jit(lambda x: simjoin_schedule(
+            x, None, eps=18.3, bp=SJ_BP, n_valid=SJ_N, cap=1 << 18
+        )).lower(x)
+        assert " sort(" in lowered.compile().as_text()
+        return
     if pass_ == "hits":
-        sd = _spec(tri.shape, jnp.int32, one_chip)
-        fn = lambda s, x: simjoin_tile_hits_swizzled(  # noqa: E731
-            s, x, eps=0.1, bp=bp, interpret=False
+        # one pass-1 block: PASS1_STEPS steps of the schedule in SMEM, a
+        # grid of the block's live steps
+        sd = _spec((1 << 18, 2), jnp.int32, one_chip)
+        fn = lambda s, n, x: simjoin_totals(  # noqa: E731
+            s, PASS1_STEPS, n, x, steps=PASS1_STEPS, eps=18.3, bp=SJ_BP,
+            n_valid=SJ_N, interpret=False,
         )
+        _compile(fn, sd, _spec((), jnp.int32, one_chip), x)
+        return
     else:
-        # packed rows and row counts of 8192 (i, j, live) table rows
+        # packed rows and row counts of one block of 8192 (i, j, live) rows
         sd = _spec((8192, 3), jnp.int32, one_chip)
         fn = lambda s, x: simjoin_emit_swizzled(  # noqa: E731
-            s, x, eps=0.1, bp=bp, interpret=False
+            s, x, eps=18.3, bp=SJ_BP, n_valid=SJ_N, interpret=False
         )
     _compile(fn, sd, x)
 
 
 def _check_simjoin_flatten(one_chip):
-    """The row flatten at the eps-k100 cell's size: 8192 table rows of
-    256-point tiles, 5,361,808 pairs.  Every gather it holds takes a
-    1-D index (a gather indexed by (P, 2) compiles for minutes), and
-    nothing sorts."""
+    """One block of the row flatten at the 2,000,000-point cell's size,
+    the map back through the points' order included: 8192 table rows of
+    256-point tiles, 2^20 pairs written into the 5,922,084-pair output.
+    Every gather it holds takes a 1-D index (a gather indexed by (P, 2)
+    compiles for minutes), and nothing sorts."""
     from repro.kernels.simjoin import simjoin_compact
 
-    n, bp, P = 8192, 256, 5_361_808
+    n, bp, cap = 8192, SJ_BP, 1 << 20
     lowered = jax.jit(
-        lambda i, c, r, t: simjoin_compact(i, c, r, t, bp=bp, P=P)
+        lambda o, i, c, r, t, p: simjoin_compact(
+            o, i, c, r, t, p, 0, bp=bp, cap=cap
+        )
     ).lower(
+        _spec((SJ_P + cap, 2), jnp.int32, one_chip),
         _spec((n, bp, bp), jnp.int8, one_chip),
         _spec((n, 1, bp), jnp.int32, one_chip),
         _spec((n,), jnp.int32, one_chip),
         _spec((n, 2), jnp.int32, one_chip),
+        _spec((SJ_N,), jnp.int32, one_chip),
     )
     gathers = [
         line for line in lowered.as_text().splitlines()
         if "stablehlo.gather" in line
     ]
-    assert gathers
+    assert len(gathers) >= 3  # the column ids, then both sides' map back
     for line in gathers:
         operands = line.split(") -> ")[0]
         index = re.findall(r"tensor<([0-9x]+)xi32>", operands)[-1]

@@ -317,6 +317,10 @@ class TestBenchHarness:
         from benchmarks import run as bench_run
 
         out = tmp_path / "snap.json"
+        # with the variable set, main() leaves JAX's cache directory as it
+        # is: else every later compile in this process writes to the
+        # checkout's .jax_cache, which test_compile_cache watches
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
         monkeypatch.setattr(
             "sys.argv", ["run.py", "nosuchbench", "--json", str(out)]
         )

@@ -79,6 +79,18 @@ def test_simjoin_pairs():
     same(lambda m: ops.simjoin_pairs(x, eps=0.5, bp=32, interpret=m))
 
 
+def test_simjoin_pairs_in_blocks(monkeypatch):
+    # pass 1 in blocks of 4 steps (the last running only its live steps)
+    # and pass 2 in blocks of 2 table rows, on Hilbert-sorted points
+    from repro.kernels import simjoin
+
+    monkeypatch.setattr(simjoin, "PASS1_STEPS", 4)
+    monkeypatch.setattr(simjoin, "PASS2_CELLS", 2 * 32 * 32)
+    x = jnp.asarray(RNG.normal(size=(150, 3)) * 0.5, jnp.float32)
+    same(lambda m: ops.simjoin_pairs(x, eps=0.5, bp=32, hilbert_order=True,
+                                     interpret=m))
+
+
 def test_paged_decode_and_prefill():
     from repro.kernels.attention import (
         decode_page_schedule,

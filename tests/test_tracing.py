@@ -18,7 +18,7 @@ from repro.kernels.kmeans import _cached_order, hilbert_point_order_cached
 
 N, SIDE, EPS, BP = 4096, 64, 3.5, 256
 STAGES = ("simjoin.order", "simjoin.schedule", "simjoin.pass1", "simjoin.sync",
-          "simjoin.table", "simjoin.pass2", "simjoin.compact", "simjoin.map_back")
+          "simjoin.table", "simjoin.pass2", "simjoin.compact")
 
 
 @pytest.fixture(scope="module")
@@ -48,6 +48,16 @@ def _delta(before):
     return {k: v - before.get(k, 0) for k, v in after.items() if v != before.get(k, 0)}
 
 
+def _box_pairs(x):
+    """Lower-triangle tile pairs whose bounding boxes lie within ε: the
+    join's pruned schedule (integer points, so the f32 slack of its box
+    test admits no further pair)."""
+    t = x.reshape(N // BP, BP, 3)
+    lo, hi = t.min(axis=1), t.max(axis=1)
+    g = np.maximum(np.maximum(lo[:, None] - hi[None], lo[None] - hi[:, None]), 0)
+    return int(np.tril((g * g).sum(-1) <= EPS * EPS).sum())
+
+
 def test_count_returns_the_total_and_counters_is_a_copy():
     n0 = counters().get("test.counter", 0)
     assert count("test.counter") == n0 + 1
@@ -64,10 +74,16 @@ def test_join_counters_match_the_inputs(points):
     before = counters()
     out = ops.simjoin_pairs(jnp.asarray(points), EPS, bp=BP)
     d = _delta(before)
+    kept = _box_pairs(points)
+    assert want_live <= kept < pt * (pt + 1) // 2
     assert out.shape == (want_pairs, 2)
     assert d == {
         "simjoin.joins": 1,
-        "simjoin.tile_pairs": pt * (pt + 1) // 2,
+        "simjoin.tri_pairs": pt * (pt + 1) // 2,
+        "simjoin.tile_pairs": kept,
+        "simjoin.pass1_steps": kept,
+        "simjoin.pass1_blocks": 1,
+        "simjoin.pass2_blocks": 1,
         "simjoin.pairs_out": want_pairs,
         "simjoin.tiles_live": want_live,
         "simjoin.mask_rows_scanned": 1 << (want_live - 1).bit_length(),
@@ -79,9 +95,9 @@ def test_join_counters_match_the_inputs(points):
 @pytest.mark.parametrize("halo", [False, True], ids=["replicated", "halo"])
 def test_sharded_join_counts_as_the_single_core_one(points, halo):
     """The sharded join's counters on a one-device mesh: the same pairs,
-    live tiles and compaction as one core; pass 1 visits the whole
-    triangle when replicated and no more than it under the halo's reach
-    pruning."""
+    live tiles and compaction as one core; its pass 1 visits the whole
+    triangle the single core prunes when replicated, and no more than
+    it under the halo's reach pruning."""
     from repro.kernels.sharded import simjoin_pairs_sharded
     from repro.launch.mesh import make_app_mesh
 
@@ -93,10 +109,13 @@ def test_sharded_join_counts_as_the_single_core_one(points, halo):
     simjoin_pairs_sharded(x, EPS, mesh=make_app_mesh(1), bp=BP, halo=halo)
     d = _delta(before)
     steps = d.pop("simjoin.tile_pairs")
-    assert steps <= single.pop("simjoin.tile_pairs")
+    tri = single.pop("simjoin.tri_pairs")
+    assert single.pop("simjoin.tile_pairs") <= steps <= tri
     if not halo:
-        assert steps == (N // BP) * (N // BP + 1) // 2
-    single.pop("simjoin.joins")
+        assert steps == (N // BP) * (N // BP + 1) // 2 == tri
+    for name in ("simjoin.joins", "simjoin.pass1_steps", "simjoin.pass1_blocks",
+                 "simjoin.pass2_blocks"):
+        single.pop(name)
     assert d == single
 
 
