@@ -620,7 +620,7 @@ def simjoin_pairs(
     (:func:`simjoin_tile_hits_swizzled`), whose per-tile totals give the
     exact pair count and the non-empty tiles; pass 2
     (:func:`simjoin_emit_swizzled`) packs each non-empty tile's hit rows
-    and counts them, and a row flatten of exact size turns the packed
+    and counts them, and a flatten kernel of exact size turns the packed
     rows into pairs in schedule-then-row-major order.  Both run in fixed
     blocks, so one code path serves every N, up to the int32 pair
     offsets.  Ragged N is handled by the same zero-pad + index-mask rule

@@ -37,7 +37,7 @@ pass 1 counts hits over each shard's curve range of the triangle
 schedule; the host reads the per-step totals (the single-core path
 already host-syncs here — output size is data-dependent) and keeps the
 non-empty rows; pass 2 has each shard write the packed hit rows of its
-non-empty rows, which one exact-size row flatten (reading them in the
+non-empty rows, which one exact-size flatten kernel (reading them in the
 global row order in the halo case) turns into the global pair list **in
 exactly the single-core emission order** (shards hold contiguous
 schedule ranges of the global pruned triangle).
@@ -587,7 +587,7 @@ def simjoin_pairs_sharded(
     names — a fixed-size halo buffer per shard, reused by pass 2.
     Per-shard hit counts → the non-empty rows and the exact pair count
     on the host (the inherent host sync of an exact-size join) →
-    per-shard packed hit rows of those rows → one row flatten in the
+    per-shard packed hit rows of those rows → one flatten kernel in the
     global schedule order.
     Pruned rows contribute zero pairs by construction of the reach
     mask, so the result is array-equal (not just set-equal) to

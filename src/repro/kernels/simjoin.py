@@ -41,17 +41,23 @@ block's live steps, and pass 2 in dispatches of at most
 one output before the next, so the join has no size limit short of its
 int32 pair offsets.
 
-The compaction is two-level and count-directed: the kernel packs each
-row, then :func:`simjoin_compact` flattens whole rows — a scatter over
-rows and a cumsum over the pairs give each output its row, one 1-D
-gather its column id — in schedule-then-row-major order, the order of
-the tiles' rows, and maps the ids back through the point order with two
-more 1-D gathers.  No cell-level ``jnp.nonzero``: in XLA it is a
-scatter-add with one update per mask cell (and a sort once its bins
-outgrow the chip's memory), while only 0.1–1% of the cells of a
-non-empty tile hold a pair.  Gathers take 1-D indices only: XLA's TPU
-compile of a gather indexed by ``(P, 2)`` takes minutes at millions of
-pairs.  Sorting inside the kernel has no TPU lowering.
+The compaction is two-level and count-directed: the emit kernel packs
+each row, then the flatten kernel (:func:`simjoin_flatten_program`,
+inside :func:`simjoin_compact`) writes each listed tile's pairs at the
+tile's pair offset, in schedule-then-row-major order, the order of the
+tiles' rows.  A step lays its tile out as one run in VMEM with the
+shift network of the emit kernel, over the run's flat lanes, at the
+narrowest run width that holds the tile's fullest row (one (8, 128)
+register while no row holds more than four hits), moves it behind the partial
+128-lane row carried from the step before, and DMAs only whole, aligned
+rows to the output; the last step writes the carried row.  The ids are
+then mapped back through the point order with two 1-D gathers.  No
+cell-level ``jnp.nonzero``: in XLA it is a scatter-add with one update
+per mask cell (and a sort once its bins outgrow the chip's memory),
+while only 0.1–1% of the cells of a non-empty tile hold a pair.
+Gathers take 1-D indices only: XLA's TPU compile of a gather indexed by
+``(P, 2)`` takes minutes at millions of pairs.  Sorting inside the
+kernel has no TPU lowering.
 
 A diagonal tile counts each unordered pair once via a strict i<j mask; an
 off-diagonal (i_tile > j_tile) tile contributes row sums to the i side
@@ -74,13 +80,13 @@ from repro.core.jax_hilbert import hilbert_encode_jax
 from repro.core.program import CurveProgram
 from repro.core.tracing import count, span
 
-from .launch import launch
+from .launch import launch, resolve_interpret, sync_copy
 
 # Pass-1 steps a dispatch: the schedule slice is scalar-prefetched into
 # SMEM (1 MiB) at 8 bytes a step, so 2^16 steps take 512 KiB.
 PASS1_STEPS = 1 << 16
 # Mask cells a pass-2 dispatch packs: 2^29 (8192 tiles of 256², 512 MB
-# of int8 ids) keeps the flatten's flat indices well inside int32.
+# of int8 ids), which bounds the device memory a block's ids hold.
 PASS2_CELLS = 1 << 29
 
 
@@ -463,7 +469,7 @@ def pruned_schedule(
 
 
 # ---------------------------------------------------------------------------
-# Pass 2: each hit tile's rows packed in the kernel, then flattened by rows
+# Pass 2: each hit tile's rows packed in the kernel, then flattened by tiles
 # ---------------------------------------------------------------------------
 
 def _id_code(bp: int):
@@ -595,45 +601,249 @@ def _spread(starts, values, P: int):
     return jnp.cumsum(out, axis=-1)
 
 
-def _flatten_rows(ids, counts, rows, tiles, *, bp: int, P: int):
-    """The first ``P`` hits of the packed rows of table rows ``rows``
-    (-1: padding) as global ids ``(i, j)``, two int32[P], walking tile
-    rows, never cells.
+def _flatten_widths(bp: int) -> tuple[int, int, tuple[int, ...]]:
+    """``(bq, S, widths)`` of the flatten kernel for tiles of ``bp``
+    rows: ``bq`` the tile padded to a power of two of at least 128, ``S``
+    its bits (a column id's field), and the run widths ``M`` it compiles,
+    smallest first.  A tile whose rows hold at most ``M`` hits each is
+    laid out as ``bq`` rows of ``M`` lanes, ``bq·M/128`` rows of 128: the
+    smallest width fills one (8, 128) register, each next one is four
+    times as wide, and the last holds a full row."""
+    bq = max(128, _bucket(bp))
+    if bq > 1024:  # (d, flag, column) must fit one int32
+        raise ValueError(f"the flatten takes tiles of at most 1024 rows, not {bp}")
+    widths = [max(1, 1024 // bq)]
+    while widths[-1] < bp:
+        widths.append(min(4 * widths[-1], bq))
+    return bq, bq.bit_length() - 1, tuple(widths)
 
-    Tile row q (row ``q % bp`` of listed table row ``t = q // bp``) holds
-    outputs ``[off_q, off_q + n_q)``, ``off`` the exclusive cumsum of the
-    row counts; output k is its hit ``p = k - off_q``.  One spread over
-    rows gives every output ``w = q·bp + p``; one over table rows gives
-    its tile's global bases and how far its ids lie from slot t in the
-    id table, which ``rows`` indexes in place.  The column id is then
-    one 1-D gather."""
+
+def _tile_run(vals, v_ref, f_ref, M: int, S: int):
+    """The tile's pairs as one run in row-major order: ``(R, 128)``
+    int32, ``R = bq·M/128``, run position k at ``(k // 128, k % 128)``.
+
+    ``vals`` (bp, >= M lanes) holds row r's hits in lanes ``[0, M)``
+    packed as ``(d << S + 1) | 1 << S | column``, ``d = r·M - o_r`` (``o``
+    the rows' exclusive cumsum), zero past ``n_r``.  Laid out as ``bq``
+    rows of ``M`` lanes (``v_ref`` or ``f_ref``, zero past the tile),
+    hit ``(r, p)`` sits at ``r·M + p`` and belongs at ``o_r + p``, so it
+    moves left by ``d``, which never falls along the run: the shift
+    network of :func:`_pack_rows`, over the run's flat lanes, low bits
+    first, moves it without collisions."""
+    bp = vals.shape[0]
+    bq = v_ref.shape[0]
+    R = bq * M // 128
+    if M <= 128:
+        # run row q, lanes [g·M, g·M + M) <- tile row q·G + g
+        v_ref[:bp, :vals.shape[1]] = vals
+        G = 128 // M
+        run = v_ref[pl.ds(0, R, stride=G)]
+        for g in range(1, G):
+            run = run | pltpu.roll(v_ref[pl.ds(g, R, stride=G)], g * M, 1)
+    else:
+        # run rows r·H + h <- tile row r's lanes [h·128, h·128 + 128)
+        H = M // 128
+        for h in range(H):
+            w = min(128, bp - h * 128)
+            f_ref[pl.ds(h, bp, stride=H), :w] = vals[:, h * 128:h * 128 + w]
+        run = f_ref[:R]
+    lane = jax.lax.broadcasted_iota(jnp.int32, run.shape, 1)
+    k, b = 1, S + 1
+    while k < R * 128:
+        if k < 128:
+            # moved[k'] = run[k' + k]: across lanes, then the next row
+            y = pltpu.roll(run, 128 - k, 1)
+            moved = jnp.where(lane < 128 - k, y, pltpu.roll(y, R - 1, 0))
+        else:
+            moved = pltpu.roll(run, R - k // 128, 0)
+        bit = 1 << b
+        run = jnp.where(
+            (moved & bit) != 0, moved, jnp.where((run & bit) != 0, 0, run)
+        )
+        k, b = 2 * k, b + 1
+    return run
+
+
+def _flatten_kernel(
+    tab, ids_ref, cnt_ref, oi_ref, oj_ref, v_ref, low_ref, f_ref, st_i, st_j,
+    car_i, car_j, nf_ref, sem, *, bp: int,
+):
+    t = pl.program_id(0)
+    bq, S, widths = _flatten_widths(bp)
     _, bias = _id_code(bp)
-    n = rows.shape[0]
+    # whole rows a step sends: at most a run's rows, and the output's
+    n_bits = min(st_i.shape[1] - 1, oi_ref.shape[0]).bit_length()
+
+    @pl.when(t == 0)
+    def _():
+        v_ref[...] = jnp.zeros_like(v_ref)
+        f_ref[...] = jnp.zeros_like(f_ref)
+        r = jax.lax.broadcasted_iota(jnp.int32, (bp, bp), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (bp, bp), 1)
+        low_ref[...] = (c < r).astype(low_ref.dtype)
+
+    i0, j0, off, n, lvl = (tab[t, c] for c in range(1, 6))
+    f = off % 128  # the carried row's hits, lanes [0, f)
+    slot = t % 2
+    cnt = jnp.broadcast_to(cnt_ref[0], (8, bp))
+    n_col = jnp.transpose(cnt)[:, :1]
+    o_col = jax.lax.dot_general(
+        low_ref[...], cnt.astype(low_ref.dtype), _LANES,
+        precision=None if bp <= 256 else jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )[:, :1].astype(jnp.int32)
+    for w, M in enumerate(widths):
+
+        @pl.when(lvl == w)
+        def _(M=M):
+            ids = ids_ref[0, :, :min(bp, max(M, 128))].astype(jnp.int32) + bias
+            lane = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+            r = jax.lax.broadcasted_iota(jnp.int32, (bp, 1), 0)
+            vals = jnp.where(
+                lane < n_col, ((r * M - o_col) << (S + 1)) | (1 << S) | ids, 0
+            )
+            run = _tile_run(vals, v_ref, f_ref, M, S)
+            R = run.shape[0]
+            pos = (
+                jax.lax.broadcasted_iota(jnp.int32, run.shape, 0) * 128
+                + jax.lax.broadcasted_iota(jnp.int32, run.shape, 1)
+            )
+            row = (pos + (run >> (S + 1))) // M
+            lane = jax.lax.broadcasted_iota(jnp.int32, run.shape, 1)
+            first = jax.lax.broadcasted_iota(jnp.int32, run.shape, 0) == 0
+            for ids, st, car in (
+                (i0 + row, st_i, car_i), (j0 + (run & (bq - 1)), st_j, car_j)
+            ):
+                # the run moved right by f, behind the carried hits
+                y = pltpu.roll(ids, f, 1)
+                prev = jnp.where(first, car[...], pltpu.roll(y, 1, 0))
+                st[slot, :R] = jnp.where(lane < f, prev, y)
+                st[slot, R:R + 1] = y[R - 1:]
+
+    full = (f + n) // 128
+    car_i[...] = st_i[slot, pl.ds(full, 1)]
+    car_j[...] = st_j[slot, pl.ds(full, 1)]
+    def copies(s, rows, base):
+        """The DMAs of ``rows`` whole staged rows of slot ``s`` to output
+        row ``base``: one per set bit of ``rows``, so each has a fixed
+        size and none overlaps another."""
+        for b in reversed(range(n_bits)):
+            a = (rows >> (b + 1)) << (b + 1)
+            size = 1 << b
+            yield (rows & size) != 0, [
+                pltpu.make_async_copy(
+                    st.at[s, pl.ds(a, size)], o.at[pl.ds(base + a, size)],
+                    sem.at[s],
+                )
+                for st, o in ((st_i, oi_ref), (st_j, oj_ref))
+            ]
+
+    def wait(s, rows):
+        for on, cps in copies(s, rows, 0):
+            @pl.when(on)
+            def _(cps=cps):
+                for cp in cps:
+                    cp.wait()
+
+    for on, cps in copies(slot, full, off // 128):
+        @pl.when(on)
+        def _(cps=cps):
+            for cp in cps:
+                cp.start()
+
+    @pl.when(t > 0)
+    def _():
+        wait(1 - slot, nf_ref[0])  # the step before's, run behind this one
+
+    nf_ref[0] = full
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _():
+        wait(slot, full)
+
+        @pl.when((f + n) % 128 > 0)
+        def _():
+            row = off // 128 + full
+            for car, o in ((car_i, oi_ref), (car_j, oj_ref)):
+                sync_copy(car, o.at[pl.ds(row, 1)], sem.at[slot])
+
+
+def simjoin_flatten_program(table, *, bp: int, cap: int) -> CurveProgram:
+    """The flatten's declaration: one step per listed table row ``(slot,
+    i0, j0, off, pairs, width)`` — the id table's slot, the tile's global
+    bases, its first pair's place in the block's output, its pair count
+    and the index of its run width (:func:`_flatten_widths`).  Each step
+    reads the slot's packed ids and row counts, lays its pairs out as one
+    run in VMEM behind the row carried from the step before, and DMAs the
+    whole rows to the two int32 outputs ``(cap / 128, 128)`` (i, then j),
+    each row written once; the last step writes the carried row."""
+    bq, _, widths = _flatten_widths(bp)
+    run_rows = bq * widths[-1] // 128
+    slot = lambda s, sr: (sr[s, 0], 0, 0)  # noqa: E731
+    out = jax.ShapeDtypeStruct((-(-cap // 128), 128), jnp.int32)
+    return CurveProgram(
+        name="simjoin_flatten",
+        schedule=table,
+        kernel=functools.partial(_flatten_kernel, bp=bp),
+        in_specs=(pl.BlockSpec((1, bp, bp), slot), pl.BlockSpec((1, 1, bp), slot)),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+        out_shape=[out, out],
+        scratch_shapes=(
+            pltpu.VMEM((bq, 128), jnp.int32),  # the packed tile, M <= 128
+            # strictly-lower ones: the rows' exclusive cumsum on the MXU
+            pltpu.VMEM((bp, bp), jnp.bfloat16 if bp <= 256 else jnp.float32),
+            pltpu.VMEM((run_rows, 128), jnp.int32),  # a run, M > 128
+            pltpu.VMEM((2, run_rows + 1, 128), jnp.int32),  # staged i, two slots
+            pltpu.VMEM((2, run_rows + 1, 128), jnp.int32),  # staged j
+            pltpu.VMEM((1, 128), jnp.int32),  # carried row, i
+            pltpu.VMEM((1, 128), jnp.int32),  # carried row, j
+            pltpu.SMEM((1,), jnp.int32),  # whole rows the step before sent
+            pltpu.SemaphoreType.DMA((2,)),
+        ),
+        columns=("slot", "i0", "j0", "off", "pairs", "width"),
+    )
+
+
+def _flatten_tiles(ids, counts, rows, tiles, *, bp: int, cap: int, interpret):
+    """The first ``cap`` hits of the packed rows of table rows ``rows``
+    (-1: padding, after the live rows) as global ids ``(i, j)``, two
+    int32[cap], in listed-row then row-major order: the tiles' pair
+    offsets and run widths here, the pairs by :func:`simjoin_flatten_program`,
+    whose grid runs the live rows only."""
+    _, _, widths = _flatten_widths(bp)
     live = rows >= 0
     rows = jnp.where(live, rows, 0)
-    cnt = jnp.where(live[:, None], counts.reshape(-1, bp)[rows], 0).reshape(-1)
-    off = jnp.cumsum(cnt) - cnt
-    q = jnp.arange(n * bp, dtype=jnp.int32)
-    w = _spread(off, q * bp - off, P) + jnp.arange(P, dtype=jnp.int32)
-    gi, gj, moved = _spread(off[::bp], jnp.stack([
-        tiles[:, 0] * bp,
-        tiles[:, 1] * bp,
-        (rows - jnp.arange(n, dtype=jnp.int32)) * (bp * bp),
-    ]), P)
-    col = ids.reshape(-1)[w + moved].astype(jnp.int32) + bias
-    return gi + w // bp % bp, gj + col
+    cnt = counts.reshape(-1, bp)[rows]
+    tot = jnp.where(live, jnp.sum(cnt, axis=1), 0)
+    width = jnp.sum(
+        jnp.max(cnt, axis=1, keepdims=True) > jnp.asarray(widths[:-1]), axis=1
+    )
+    table = jnp.stack([
+        rows, tiles[:, 0] * bp, tiles[:, 1] * bp, jnp.cumsum(tot) - tot, tot,
+        width,
+    ], axis=1).astype(jnp.int32)
+    program = dataclasses.replace(
+        simjoin_flatten_program(table, bp=bp, cap=cap),
+        grid=(jnp.sum(live, dtype=jnp.int32),),
+    )
+    i, j = launch(program, ids, counts, interpret=interpret)
+    return i.reshape(-1)[:cap], j.reshape(-1)[:cap]
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bp", "cap"), donate_argnames=("out",)
+    jax.jit, static_argnames=("bp", "cap", "interpret"),
+    donate_argnames=("out",),
 )
-def simjoin_compact(out, ids, counts, rows, tiles, perm, start, *, bp: int, cap: int):
+def simjoin_compact(out, ids, counts, rows, tiles, perm, start, *, bp: int,
+                    cap: int, interpret: bool = False):
     """``out`` (int32[M, 2]) with rows ``[start, start + cap)`` replaced
-    by the first ``cap`` hits of the packed rows (:func:`_flatten_rows`)
+    by the first ``cap`` hits of the packed rows (:func:`_flatten_tiles`)
     as pairs ``i > j``, their ids mapped through ``perm`` (the points'
     order; None: the ids as they are).  Hits past the rows' own count
     are junk that a later block, or the caller's final slice, drops."""
-    i, j = _flatten_rows(ids, counts, rows, tiles, bp=bp, P=cap)
+    i, j = _flatten_tiles(
+        ids, counts, rows, tiles, bp=bp, cap=cap, interpret=interpret
+    )
     if perm is not None:
         # two 1-D gathers: the map back takes no (P, 2) index
         i, j = perm[i], perm[j]
@@ -642,34 +852,29 @@ def simjoin_compact(out, ids, counts, rows, tiles, perm, start, *, bp: int, cap:
 
 
 def _compact_block(out, ids, counts, rows, tiles, *, P: int, cap: int, bp: int,
-                   start: int = 0, perm=None) -> jax.Array:
+                   start: int = 0, perm=None, interpret=False) -> jax.Array:
     """:func:`simjoin_compact` of ``P`` pairs in the listed rows, with the
     rows padded to a power of two and the compaction's counters."""
     rows = np.asarray(rows, dtype=np.int32)
     n = _bucket(len(rows))
-    if max(n, ids.shape[0]) * bp * bp >= 2**31:
-        raise ValueError(
-            f"{max(n, ids.shape[0])} tiles of {bp}x{bp} exceed int32 "
-            f"indexing; flatten fewer tiles at a time"
-        )
     with span("simjoin.compact"):
         count("simjoin.pairs_out", P)
         count("simjoin.mask_rows_scanned", n)
         count("simjoin.mask_cells_scanned", n * bp * bp)
         count("simjoin.rows_flattened", n * bp)
-        # padding rows (-1) count no hits
+        # padding rows (-1) run no step
         rows_p = np.full(n, -1, np.int32)
         rows_p[: len(rows)] = rows
         tiles_p = np.zeros((n, 2), np.int32)
         tiles_p[: len(rows)] = tiles
         return simjoin_compact(
             out, ids, counts, jnp.asarray(rows_p), jnp.asarray(tiles_p), perm,
-            start, bp=bp, cap=cap,
+            start, bp=bp, cap=cap, interpret=interpret,
         )
 
 
 def pairs_from_masks(ids, counts, rows, tiles, P: int, bp: int,
-                     perm=None) -> jax.Array:
+                     perm=None, interpret=None) -> jax.Array:
     """int32[P, 2] pairs of pass 2's hit masks in packed form (the
     ``ids``, ``counts`` of :func:`simjoin_emit_swizzled`) at table rows
     ``rows``, in row order then row-major in-tile order.  ``tiles``
@@ -677,9 +882,11 @@ def pairs_from_masks(ids, counts, rows, tiles, P: int, bp: int,
     row; ``P`` is the pass-1 total, so the compaction has its exact
     size.  With ``perm`` the ids are mapped through it (the points'
     order), as :func:`simjoin_compact` does."""
+    if P == 0:
+        return jnp.zeros((0, 2), jnp.int32)
     return _compact_block(
         jnp.zeros((P, 2), jnp.int32), ids, counts, rows, tiles, P=P, cap=P,
-        bp=bp, perm=perm,
+        bp=bp, perm=perm, interpret=resolve_interpret(interpret),
     )
 
 
@@ -726,7 +933,8 @@ def simjoin_pairs_scheduled(
     of the same ``min(PASS1_STEPS, bucket(rows of schedule))`` steps,
     each running its live steps only; pass 2 in dispatches of
     ``min(PASS2_CELLS / bp², bucket(live tiles))`` table rows, each
-    flattened into its own rows of the one output.
+    flattened into its own rows of the one output, with the host at most
+    one block ahead of the device.
     """
     if steps is None:
         schedule = np.asarray(schedule, dtype=np.int32)
@@ -768,15 +976,21 @@ def simjoin_pairs_scheduled(
     count("simjoin.tiles_live", len(live))
     count("simjoin.pass2_blocks", len(firsts))
     out = jnp.zeros((P + cap, 2), jnp.int32)
+    counts = None
     for b, first in enumerate(firsts):
         blk = table[first : first + rows]
         with span("simjoin.pass2", block=b):
+            if counts is not None:
+                # at most one block ahead of the device: the block
+                # before's emit has run (its flatten is next), so no more
+                # than two blocks' packed ids are held at once
+                jax.block_until_ready(counts)
             ids, counts = simjoin_emit_swizzled(
                 jnp.asarray(blk), xp, eps=float(eps), bp=bp, n_valid=n_valid,
                 interpret=interpret,
             )
         out = _compact_block(
             out, ids, counts, np.arange(rows), blk[:, :2], P=int(p_blk[b]),
-            cap=cap, bp=bp, start=int(off[b]), perm=perm,
+            cap=cap, bp=bp, start=int(off[b]), perm=perm, interpret=interpret,
         )
     return out[:P]

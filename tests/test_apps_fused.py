@@ -18,6 +18,7 @@ from repro.kernels.kmeans import (
 )
 from repro.kernels.pallas_compat import PallasCallCounter
 from repro.kernels.simjoin import (
+    simjoin_compact,
     simjoin_emit_swizzled,
     simjoin_tile_hits_swizzled,
 )
@@ -204,6 +205,8 @@ class TestSimjoinPairs:
         x = jnp.asarray(RNG.normal(size=(128, 3)) * 0.5, jnp.float32)
         simjoin_tile_hits_swizzled.clear_cache()
         simjoin_emit_swizzled.clear_cache()
+        simjoin_compact.clear_cache()
         with PallasCallCounter() as spy:
             ops.simjoin_pairs(x, eps=0.6, bp=32, interpret=True)
-        assert spy.count == 2  # count pass + emit pass, nothing else
+        # count pass + emit pass + the flatten, nothing else
+        assert spy.count == 3

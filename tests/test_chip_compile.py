@@ -229,17 +229,18 @@ def test_simjoin_compiles(one_chip, pass_):
 
 
 def _check_simjoin_flatten(one_chip):
-    """One block of the row flatten at the 2,000,000-point cell's size,
-    the map back through the points' order included: 8192 table rows of
+    """One block of the flatten at the 2,000,000-point cell's size, the
+    map back through the points' order included: 8192 table rows of
     256-point tiles, 2^20 pairs written into the 5,922,084-pair output.
-    Every gather it holds takes a 1-D index (a gather indexed by (P, 2)
-    compiles for minutes), and nothing sorts."""
+    The flatten is one Mosaic kernel; the map back's two gathers take
+    1-D indices (a gather indexed by (P, 2) compiles for minutes), and
+    nothing sorts."""
     from repro.kernels.simjoin import simjoin_compact
 
     n, bp, cap = 8192, SJ_BP, 1 << 20
     lowered = jax.jit(
         lambda o, i, c, r, t, p: simjoin_compact(
-            o, i, c, r, t, p, 0, bp=bp, cap=cap
+            o, i, c, r, t, p, 0, bp=bp, cap=cap, interpret=False
         )
     ).lower(
         _spec((SJ_P + cap, 2), jnp.int32, one_chip),
@@ -253,9 +254,12 @@ def _check_simjoin_flatten(one_chip):
         line for line in lowered.as_text().splitlines()
         if "stablehlo.gather" in line
     ]
-    assert len(gathers) >= 3  # the column ids, then both sides' map back
+    # the rows' counts, then both sides' map back through the order
+    assert len(gathers) == 3, gathers
     for line in gathers:
         operands = line.split(") -> ")[0]
         index = re.findall(r"tensor<([0-9x]+)xi32>", operands)[-1]
         assert index.count("x") <= 1, line  # (k,) or (k, 1) indices
-    assert " sort(" not in lowered.compile().as_text()
+    compiled = lowered.compile().as_text()
+    assert "tpu_custom_call" in compiled  # the flatten kernel
+    assert " sort(" not in compiled

@@ -212,9 +212,10 @@ class TestVmemBudget:
 
     def test_simjoin_fallback_to_dense_oracle(self, no_budget):
         # the join keeps no data-sized buffer in VMEM, so even a 64-byte
-        # budget leaves it on the two-dispatch kernel path — and its
-        # pairs still equal the dense oracle's
+        # budget leaves it on its kernel path (two passes and the
+        # flatten) — and its pairs still equal the dense oracle's
         from repro.kernels.simjoin import (
+            simjoin_compact,
             simjoin_emit_swizzled,
             simjoin_tile_hits_swizzled,
         )
@@ -225,10 +226,11 @@ class TestVmemBudget:
         try:
             simjoin_tile_hits_swizzled.clear_cache()
             simjoin_emit_swizzled.clear_cache()
+            simjoin_compact.clear_cache()
             with PallasCallCounter() as spy:
                 got = np.asarray(ops.simjoin_pairs(x, eps=0.8, bp=16,
                                                    interpret=True))
-            assert spy.count == 2
+            assert spy.count == 3
         finally:
             set_vmem_budget(old)
         got = got[np.lexsort((got[:, 1], got[:, 0]))]

@@ -1,21 +1,29 @@
-"""The ε-join's two-level compaction against numpy.
+"""The ε-join's two-level compaction against numpy and the XLA flatten.
 
 Pass 2 (:func:`simjoin_emit_swizzled`) packs each tile row's hit columns
-to the row's first lanes and counts them; :func:`pairs_from_masks`
-flattens the packed rows into pairs.  Both are held to numpy: the
-kernel's rows to ``np.nonzero`` of the hit mask row by row, the pairs to
-the cell-level order the join has always had (table row, then row-major
-in the tile).  Points lie on a small integer grid, so every squared
-distance is exact and numpy's hit masks are the kernel's.
+to the row's first lanes and counts them; the flatten kernel
+(:func:`simjoin_compact`, under :func:`pairs_from_masks`) writes the
+packed rows out as pairs.  The kernel's rows are held to ``np.nonzero``
+of the hit mask row by row, the pairs to the cell-level order the join
+has always had (table row, then row-major in the tile), and the flatten
+bit for bit to the XLA row flatten it replaced (:func:`_flatten_rows`
+below, the oracle).  Points lie on a small integer grid, so every
+squared distance is exact and numpy's hit masks are the kernel's.
 """
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels.simjoin import (
+    _flatten_widths,
     _id_code,
+    _spread,
     emission_table,
     pairs_from_masks,
+    simjoin_compact,
     simjoin_emit_swizzled,
 )
 
@@ -160,3 +168,112 @@ def test_pairs_from_masks_keeps_the_cell_order(bp, density, form):
     got = pairs_from_masks(ids, counts, rows, tiles, len(want), bp)
     assert got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@functools.partial(jax.jit, static_argnames=("bp", "P"))
+def _flatten_rows(ids, counts, rows, tiles, *, bp: int, P: int):
+    """The oracle: the XLA row flatten the kernel replaced.  The first
+    ``P`` hits of the packed rows of table rows ``rows`` (-1: padding) as
+    global ids ``(i, j)``, two int32[P], walking tile rows, never cells.
+
+    Tile row q (row ``q % bp`` of listed table row ``t = q // bp``) holds
+    outputs ``[off_q, off_q + n_q)``, ``off`` the exclusive cumsum of the
+    row counts; output k is its hit ``p = k - off_q``.  One spread over
+    rows gives every output ``w = q·bp + p``; one over table rows gives
+    its tile's global bases and how far its ids lie from slot t in the
+    id table, which ``rows`` indexes in place.  The column id is then
+    one 1-D gather."""
+    _, bias = _id_code(bp)
+    n = rows.shape[0]
+    live = rows >= 0
+    rows = jnp.where(live, rows, 0)
+    cnt = jnp.where(live[:, None], counts.reshape(-1, bp)[rows], 0).reshape(-1)
+    off = jnp.cumsum(cnt) - cnt
+    q = jnp.arange(n * bp, dtype=jnp.int32)
+    w = _spread(off, q * bp - off, P) + jnp.arange(P, dtype=jnp.int32)
+    gi, gj, moved = _spread(off[::bp], jnp.stack([
+        tiles[:, 0] * bp,
+        tiles[:, 1] * bp,
+        (rows - jnp.arange(n, dtype=jnp.int32)) * (bp * bp),
+    ]), P)
+    col = ids.reshape(-1)[w + moved].astype(jnp.int32) + bias
+    return gi + w // bp % bp, gj + col
+
+
+# hits per listed tile for "edges": runs that end on a 128-lane edge
+# (at 128, 256, 640 and 896), empty tiles, runs that start on an edge or
+# inside the carried row and cross one or more rows, and single hits
+EDGE_HITS = [128, 0, 100, 28, 300, 1, 83, 129, 1, 126, 0]
+CASES = ["none", "one", "1%", "30%", "all", "diagonal", "edges"]
+
+
+def _case_masks(bp, case, T=12):
+    """bool[T, bp, bp] hit masks of one case; slot ``T - 1`` is dead."""
+    rng = np.random.default_rng([bp, CASES.index(case)])
+    if case == "edges":
+        masks = np.zeros((T, bp, bp), bool)
+        for t, k in enumerate(EDGE_HITS[: T - 1]):
+            masks[t].flat[rng.choice(bp * bp, min(k, bp * bp), replace=False)] = True
+    elif case == "diagonal":
+        # a full diagonal tile (each pair once), a full tile, and 10% ones
+        masks = rng.uniform(size=(T, bp, bp)) < 0.1
+        masks[2] = np.tri(bp, k=-1, dtype=bool)
+        masks[7] = True
+    else:
+        p = {"none": 0.0, "one": 0.0, "1%": 0.01, "30%": 0.30, "all": 1.0}[case]
+        masks = rng.uniform(size=(T, bp, bp)) < p
+        if case == "one":
+            masks[5, bp // 2, bp // 3] = True
+    masks[T - 1] = False  # a dead row, as pass 2 writes it
+    return masks
+
+
+FORMS = {
+    # the single-chip join: every row, in table order
+    "single": np.arange(11),
+    # the sharded joins: a selection across shards, out of order, that
+    # skips rows and reads the id table in place
+    "sharded": np.array([6, 0, 9, 3, 10, 1, 5]),
+}
+
+
+@pytest.mark.parametrize("bp", BPS)
+def test_every_run_width_is_exercised(bp):
+    # the kernel lays a tile out at the narrowest run width that holds
+    # its fullest row; the cases below reach every width it compiles
+    _, _, widths = _flatten_widths(bp)
+    used = set()
+    for case in CASES:
+        fullest = _case_masks(bp, case).sum(axis=2).max(axis=1)
+        used |= {int(np.searchsorted(widths, m)) for m in fullest}
+    assert used == set(range(len(widths)))
+
+
+@pytest.mark.parametrize("bp", BPS)
+@pytest.mark.parametrize("case", CASES)
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_flatten_kernel_matches_the_xla_flatten(bp, case, form):
+    masks = _case_masks(bp, case)
+    rows = FORMS[form]
+    tiles = np.random.default_rng(bp).integers(0, 40, (len(rows), 2))
+    ids, counts = _packed(masks)
+    # one compiled shape per bp: 16 listed rows (padding -1), one cap
+    cap = 1 << (12 * bp * bp - 1).bit_length()
+    rows_p = np.full(16, -1, np.int32)
+    rows_p[: len(rows)] = rows
+    tiles_p = np.zeros((16, 2), np.int32)
+    tiles_p[: len(rows)] = tiles
+    P = int(masks[rows].sum())
+    if case == "edges" and form == "single":
+        ends = np.cumsum(masks[rows].sum(axis=(1, 2)))
+        assert {128, 256, 640, 896} <= set(ends.tolist())  # runs end on edges
+    out = simjoin_compact(
+        jnp.zeros((cap, 2), jnp.int32), ids, counts, jnp.asarray(rows_p),
+        jnp.asarray(tiles_p), None, 0, bp=bp, cap=cap, interpret=True,
+    )
+    i, j = _flatten_rows(
+        ids, counts, jnp.asarray(rows_p), jnp.asarray(tiles_p), bp=bp, P=cap
+    )
+    want = np.stack([np.asarray(i), np.asarray(j)], axis=1)[:P]
+    np.testing.assert_array_equal(np.asarray(out)[:P], want)
+    np.testing.assert_array_equal(want, _cell_order(masks, rows, tiles, bp))

@@ -91,6 +91,25 @@ def test_simjoin_pairs_in_blocks(monkeypatch):
                                      interpret=m))
 
 
+def test_simjoin_flatten_reads_rows_out_of_order():
+    # the sharded joins' flatten: listed rows across shards, out of
+    # order, skipping some, with runs that cross the carried row
+    from repro.kernels.simjoin import _id_code, pairs_from_masks
+
+    bp = 32
+    masks = RNG.uniform(size=(6, bp, bp)) < 0.1
+    rows, tiles = np.array([4, 0, 2, 5]), np.array([[3, 1], [0, 0], [2, 2], [7, 5]])
+    dtype, bias = _id_code(bp)
+    ids = RNG.integers(0, bp, (6, bp, bp))
+    for t, r in np.ndindex(6, bp):
+        nz = np.flatnonzero(masks[t, r])
+        ids[t, r, :len(nz)] = nz
+    ids = jnp.asarray((ids - bias).astype(dtype))
+    counts = jnp.asarray(masks.sum(axis=2).astype(np.int32)[:, None, :])
+    P = int(masks[rows].sum())
+    same(lambda m: pairs_from_masks(ids, counts, rows, tiles, P, bp, interpret=m))
+
+
 def test_paged_decode_and_prefill():
     from repro.kernels.attention import (
         decode_page_schedule,
