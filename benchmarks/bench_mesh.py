@@ -45,11 +45,12 @@ def run(n: int = 16, m: int = 16) -> list[dict]:
 
 def _halo_bytes_rows() -> list[dict]:
     """Halo-exchange traffic of the sharded ε-join as the mesh widens:
-    bytes per shard for boundary strips vs full replication at every
-    simulable mesh size.  More shards → narrower resident curve ranges →
-    more boundary per shard; replication is flat (every shard always
-    receives all of x).  jax is imported lazily so the hop-count rows
-    above stay numpy-only."""
+    bytes per shard for boundary strips at every simulable mesh size,
+    beside the point buffer's bytes that replicating x would move per
+    pass.  More shards → narrower resident curve ranges → more boundary
+    per shard; replication is flat (every shard always receives all of
+    x).  jax is imported lazily so the hop-count rows above stay
+    numpy-only."""
     import jax
     import jax.numpy as jnp
 
@@ -64,13 +65,14 @@ def _halo_bytes_rows() -> list[dict]:
             continue
         mesh = make_app_mesh(s)
         kw = dict(mesh=mesh, bp=64, hilbert_order=True, interpret=True)
-        vh = simjoin_sharded_volume(x, 0.04, halo=True, **kw)
-        vr = simjoin_sharded_volume(x, 0.04, halo=False, **kw)
+        vh = simjoin_sharded_volume(x, 0.04, **kw)
         rows.append({
             "bench": "mesh_halo", "name": f"simjoin_halo_bytes_mesh{s}",
             "value": int(vh["bytes_per_shard"]),
             "bytes_per_shard": int(vh["bytes_per_shard"]),
+            "point_bytes": int(x.nbytes),
             "derived": f"bytes/shard boundary strips (replicated "
-                       f"{vr['bytes_per_shard']}); N=1024 uniform 2-D",
+                       f"{2 * x.nbytes}: the point buffer once per pass); "
+                       f"N=1024 uniform 2-D",
         })
     return rows

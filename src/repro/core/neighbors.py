@@ -7,16 +7,17 @@ that set exactly at cell granularity, reusing the subcube-state algebra
 of :mod:`repro.core.hilbert_nd` — the same machinery the FGF jump-over
 walker (:mod:`repro.core.fgf_nd`, paper §6.2) uses to skip EMPTY
 subcubes — applied to a *distance* classifier instead of a region
-membership classifier.  It is what turns the sharded ε-join's full
-point replication into boundary-strip halo exchange
-(:mod:`repro.kernels.sharded`).
+membership classifier.  The streaming ε-join
+(:class:`repro.serve.apps.StreamSimJoin`) takes each cohort tile's
+resident candidates from :func:`halo_ranges`; :func:`neighbor_tile_mask`
+lifts the calculus to a tile×tile reach mask.
 
 Cell metric.  Coordinates are cells of the quantised 2^nbits grid
 (:func:`repro.kernels.kmeans._quantise_points`); a cell is the unit box
 at its integer coordinate.  Two cells may contain points within ε of
 each other iff the box gap ``sum_k max(|a_k - b_k| - 1, 0)^2 <= r^2``
 where ``r`` is ε in cell widths (callers add the quantisation slack —
-see :func:`repro.kernels.sharded._tile_reach`).  The gap of a cell pair
+see ``StreamSimJoin._freeze_grid`` in :mod:`repro.serve.apps`).  The gap of a cell pair
 is exact; subcube-level classification uses separable min/max bounds
 (per-axis extrema co-occur at a single corner cell, so the bounds are
 tight) and descends only through PARTIAL nodes — the identical

@@ -290,9 +290,7 @@ def _eqn_bytes(eqn) -> int:
     return in_b  # ppermute, all_to_all
 
 
-def collective_volume(
-    fn, *args, replicated_bytes: int = 0, **kwargs
-) -> dict:
+def collective_volume(fn, *args, **kwargs) -> dict:
     """Collective *volume* accountant: executed primitive counts plus a
     bytes-per-shard model, from ``fn``'s jaxpr (traced, not run).
 
@@ -300,12 +298,11 @@ def collective_volume(
     contract of the structure tests), this walks with an execution
     multiplier — a collective inside a ``scan`` of length L counts L
     times — and prices each equation from its per-shard avals
-    (:func:`_eqn_bytes`).  ``replicated_bytes`` adds caller-declared
-    operand replication (a ``P(None, None)`` in_spec moves bytes per
-    shard without any collective in the jaxpr — the replicated ε-join's
-    entire cost).  Returns ``{"counts", "bytes", "replicated_bytes",
+    (:func:`_eqn_bytes`).  Returns ``{"counts", "bytes",
     "bytes_per_shard"}`` with ``bytes_per_shard`` the grand total the
-    ``bench_apps``/``bench_mesh`` rows record and CI gates on.
+    ``bench_apps``/``bench_mesh`` rows record and CI gates on.  An
+    operand a ``P(None, None)`` in_spec replicates moves bytes per shard
+    with no collective in the jaxpr: the caller adds those.
     """
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     counts: dict[str, int] = {}
@@ -325,10 +322,4 @@ def collective_volume(
                     walk(sub, inner)
 
     walk(closed.jaxpr, 1)
-    total = sum(bts.values()) + int(replicated_bytes)
-    return {
-        "counts": counts,
-        "bytes": bts,
-        "replicated_bytes": int(replicated_bytes),
-        "bytes_per_shard": total,
-    }
+    return {"counts": counts, "bytes": bts, "bytes_per_shard": sum(bts.values())}

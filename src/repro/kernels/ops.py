@@ -67,10 +67,9 @@ from .kmeans import (
 from .launch import resolve_interpret as _interpret
 from .matmul import matmul_swizzled, matmul_swizzled_3d
 from .simjoin import (
-    pruned_schedule,
     simjoin_counts_swizzled,
+    simjoin_inputs,
     simjoin_pairs_scheduled,
-    simjoin_permute,
 )
 
 DEFAULT_CURVE = "fur"  # overlay-grid Hilbert: native n×m, unit steps
@@ -616,9 +615,12 @@ def simjoin_pairs(
     Two-pass emission over a pruned tile-pair schedule, built on the
     device (:func:`repro.kernels.simjoin.pruned_schedule`): the
     lower-triangle tile pairs whose bounding boxes lie within ε, in the
-    order ``curve`` visits them.  Pass 1 is the count kernel
-    (:func:`simjoin_tile_hits_swizzled`), whose per-tile totals give the
-    exact pair count and the non-empty tiles; pass 2
+    order ``curve`` visits them.  The point order, the ``bp`` clamp, the
+    padding and this schedule are decided in one place,
+    :func:`repro.kernels.simjoin.simjoin_inputs`, for both the
+    single-chip and the sharded join.  Pass 1 (:func:`simjoin_totals`,
+    the ``simjoin_hits`` program) gives each tile's row hit sums, whose
+    totals give the exact pair count and the non-empty tiles; pass 2
     (:func:`simjoin_emit_swizzled`) packs each non-empty tile's hit rows
     and counts them, and a flatten kernel of exact size turns the packed
     rows into pairs in schedule-then-row-major order.  Both run in fixed
@@ -630,10 +632,10 @@ def simjoin_pairs(
     original point order.
 
     ``mesh=`` (a 1-D mesh from ``repro.launch.mesh.make_app_mesh``) runs
-    the distributed two-pass variant: the triangle schedule's rows are
-    curve-range partitioned across devices, per-shard counts give the
-    global pair count, and each shard packs the hit rows of its non-empty
-    tiles — the compacted result is identical to the single-core output
+    the halo join over the devices: the points are sharded by tile, each
+    kept tile pair runs on the shard owning its i tile, and the foreign
+    tiles it reads arrive as boundary strips by ``ppermute``.  The result
+    is array-equal to the single-chip output
     (see :mod:`repro.kernels.sharded`).  No pass keeps a data-sized
     buffer in VMEM, so the join has no VMEM-budget fallback.
 
@@ -655,24 +657,11 @@ def simjoin_pairs(
                 x, eps, mesh=mesh, curve=curve, bp=bp,
                 hilbert_order=hilbert_order, interpret=interpret,
             )
-        N, D = x.shape
-        if N == 0:
+        if x.shape[0] == 0:
             return jnp.zeros((0, 2), dtype=jnp.int32)
-        perm = None
-        if hilbert_order:
-            with span("simjoin.order"):
-                perm = hilbert_point_order_cached(x)
-                x = simjoin_permute(x, perm)
-        bp = min(bp, N)
-        pn = (-N) % bp
-        xp = jnp.pad(x, ((0, pn), (0, 0))) if pn else x
-        pt = xp.shape[0] // bp
-        n_valid = N if pn else None
-        with span("simjoin.schedule"):
-            sched, kept = pruned_schedule(
-                xp, eps=float(eps), bp=bp, n_valid=n_valid, curve=curve
-            )
-        count("simjoin.tri_pairs", pt * (pt + 1) // 2)
+        xp, bp, n_valid, perm, sched, kept = simjoin_inputs(
+            x, eps, curve=curve, bp=bp, hilbert_order=hilbert_order
+        )
         # the two-pass hits → non-empty tiles → emit machinery is the
         # shared driver (kernels/simjoin.py), reused by the streaming
         # join's per-tick probe dispatch (serve/apps.py)

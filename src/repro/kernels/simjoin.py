@@ -22,11 +22,13 @@ point-major (bp, D) and the j tile feature-major (D, bp), so the
 distance tile needs no transpose and per-point sums come out as
 lane-dense rows:
 
-* :func:`simjoin_tile_hits_swizzled` — per-step partial row/column hit
-  sums (each output block written exactly once → safe under any
-  schedule); ops.py scatter-adds them onto the point axis for
-  ``simjoin_counts``, and their row-sum per step is the per-tile hit
-  total that sizes pair emission.
+* :func:`simjoin_hits_program` — pass 1 of every join, single-chip
+  (:func:`simjoin_totals`) or sharded: per step its tile's row hit sums,
+  each output block written exactly once (safe under any schedule),
+  whose total per step is the tile's hit count that sizes pair
+  emission.  ``simjoin_counts`` alone needs column sums too:
+  :func:`simjoin_counts_swizzled` scatter-adds both partials of
+  :func:`simjoin_hit_sums_program` onto the point axis.
 * :func:`simjoin_emit_swizzled` — pass 2: for the tiles pass 1 found
   non-empty, each grid step packs its tile's rows in VMEM
   (:func:`_pack_rows`: row r's hit columns, ascending, in lanes
@@ -75,11 +77,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import as_choice, register_schedule_cache, tile_schedule_nd
+from repro.core import register_schedule_cache, tile_schedule_nd
 from repro.core.jax_hilbert import hilbert_encode_jax
 from repro.core.program import CurveProgram
 from repro.core.tracing import count, span
 
+from .kmeans import hilbert_point_order_cached
 from .launch import launch, resolve_interpret, sync_copy
 
 # Pass-1 steps a dispatch: the schedule slice is scalar-prefetched into
@@ -94,7 +97,7 @@ def check_pair_offsets(P_total: int, bp: int) -> None:
     """Raise if the join's pair total would overflow int32 (``P + bp²``
     must stay int32-addressable).  A raised :class:`ValueError`, not
     ``assert`` — the guard must survive ``python -O``.  Shared by the
-    single-core and both sharded emission paths."""
+    single-core and sharded emission paths."""
     if P_total + bp * bp >= 2**31:
         raise ValueError(
             f"pair count {P_total} overflows the int32 offsets "
@@ -146,26 +149,59 @@ def _hit_tile(xiv, xjTv, ti, tj, *, eps2: float, n_valid):
     return hit
 
 
-def _hit_sums(hit):
-    """(row sums over j, column sums over i) of a hit tile as (1, bp)
-    int32 rows — ones-vector matmuls on the MXU, exact for 0/1 terms."""
+def _hit_rows(hit):
+    """Row sums over j of a hit tile as a (1, bp) int32 row: a
+    ones-vector matmul on the MXU, exact for 0/1 terms."""
     h = hit.astype(jnp.float32)
     ones = jnp.ones((1, h.shape[0]), jnp.float32)
     rows = jax.lax.dot_general(ones, h, _LANES, preferred_element_type=jnp.float32)
-    cols = jnp.dot(ones, h, preferred_element_type=jnp.float32)
-    return rows.astype(jnp.int32), cols.astype(jnp.int32)
+    return rows.astype(jnp.int32)
 
 
-def _join_kernel(
-    sched_ref, xi_ref, xjT_ref, hi_out, hj_out, *, eps2: float,
-    n_valid: int | None,
-):
+def _tile_pair_specs(bp: int, D: int):
+    """Block specs of the (x, xT) operand pair: the i tile (bp, D) from
+    schedule column 0, the feature-major j tile (D, bp) from column 1."""
+    return (
+        pl.BlockSpec((bp, D), lambda s, sr: (sr[s, 0], 0)),
+        pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, 1])),
+    )
+
+
+def _hit_sums_kernel(sched_ref, xi_ref, xjT_ref, hi_out, hj_out, *, eps2: float,
+                     n_valid: int | None):
     s = pl.program_id(0)
     hit = _hit_tile(
         xi_ref[...], xjT_ref[...], sched_ref[s, 0], sched_ref[s, 1],
         eps2=eps2, n_valid=n_valid,
     )
-    hi_out[0], hj_out[0] = _hit_sums(hit)
+    hi_out[0] = _hit_rows(hit)
+    ones = jnp.ones((1, hit.shape[0]), jnp.float32)
+    hj_out[0] = jnp.dot(
+        ones, hit.astype(jnp.float32), preferred_element_type=jnp.float32
+    ).astype(jnp.int32)
+
+
+def simjoin_hit_sums_program(
+    schedule, *, eps: float, bp: int, D: int, n_valid: int | None,
+) -> CurveProgram:
+    """Per-point neighbour counts' declaration: one (1, bp) row and one
+    column partial per ``(i, j)`` schedule step (operands: points (N, D)
+    and their transpose (D, N)), each written exactly once — safe under
+    any order.  Only :func:`simjoin_counts_swizzled` needs the columns;
+    the joins' pass 1 is :func:`simjoin_hits_program`."""
+    steps = schedule.shape[0]
+    row = jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32)
+    return CurveProgram(
+        name="simjoin_hit_sums",
+        schedule=schedule,
+        kernel=functools.partial(
+            _hit_sums_kernel, eps2=float(eps) ** 2, n_valid=n_valid
+        ),
+        in_specs=_tile_pair_specs(bp, D),
+        out_specs=[pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0))] * 2,
+        out_shape=[row, row],
+        columns=("i", "j"),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
@@ -177,115 +213,60 @@ def simjoin_tile_hits_swizzled(
     bp: int = 256,
     n_valid: int | None = None,
     interpret: bool = False,
-    live=None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-step partial hit sums: (row_hits, col_hits), each int32[steps, bp].
 
     schedule: int32[steps, 2] of lower-triangle (i_tile >= j_tile) tile
     pairs (any order; FGF-Hilbert by default via ops.py).
     x: (N, D) with N % bp == 0.  ``row_hits[s].sum()`` is the number of
-    unordered pairs found in step ``s``'s tile — pass 1 of pair emission.
-    ``live`` (traced, optional): the grid runs the first ``live`` steps
-    only, and the rows past them are never written.
+    unordered pairs found in step ``s``'s tile.
     """
     N, D = x.shape
     assert N % bp == 0
-    program = simjoin_hits_program(
+    program = simjoin_hit_sums_program(
         schedule, eps=eps, bp=bp, D=D, n_valid=n_valid
     )
-    if live is not None:
-        program = dataclasses.replace(program, grid=(live,))
     hi, hj = launch(program, x, x.T, interpret=interpret)
     return hi[:, 0], hj[:, 0]
 
 
+def _hits_kernel(sched_ref, xi_ref, xjT_ref, hi_out, *, eps2: float,
+                 n_valid: int | None, gi_col: int):
+    s = pl.program_id(0)
+    hit = _hit_tile(
+        xi_ref[...], xjT_ref[...], sched_ref[s, gi_col],
+        sched_ref[s, gi_col + 1], eps2=eps2, n_valid=n_valid,
+    )
+    hi_out[0] = _hit_rows(hit)
+
+
 def simjoin_hits_program(
     schedule, *, eps: float, bp: int, D: int, n_valid: int | None,
-    choice=None,
 ) -> CurveProgram:
-    """Pass-1 declaration: one (1, bp) row/col partial pair per schedule
-    step (operands: points (N, D) and their transpose (D, N)), each
-    written exactly once — safe under any order, so the SAME
-    program serves the single-core triangle schedule and each shard's
-    curve-range slice of it (kernels/sharded.py).  ``choice`` (a
-    ``triangle``-kind :class:`repro.core.ScheduleChoice` or curve name)
-    records which curve ordered the tile pairs — metadata for the
-    program signature; the join's curve axis is resolved upstream in
-    ops.py because the two-pass driver host-syncs between dispatches."""
-    if choice is not None:
-        choice = as_choice(choice, kind="triangle").with_(block=(int(bp),))
+    """Pass 1 of every join: per schedule step, its tile's row hit sums
+    as one (1, bp) int32 row, written once — their totals pick the tiles
+    pair emission visits.  Operands: the points and their transpose.
+
+    A 2-column ``(i, j)`` schedule runs over one global point buffer (the
+    single-chip blocks of :func:`simjoin_totals`); a 4-column ``(i_slot,
+    j_slot, i, j)`` one over a shard's resident+halo buffer
+    (kernels/sharded.py): the slot columns drive the BlockSpec index maps
+    (where a tile lives in the local buffer), the global tile ids
+    :func:`_hit_tile`'s diagonal strictness and ragged-N masking, which
+    are defined on global point indices.
+    """
     steps = schedule.shape[0]
     return CurveProgram(
         name="simjoin_hits",
         schedule=schedule,
-        choice=choice,
-        kernel=functools.partial(
-            _join_kernel, eps2=float(eps) ** 2, n_valid=n_valid
+        kernel=functools.partial(  # the global ids: the last two columns
+            _hits_kernel, eps2=float(eps) ** 2, n_valid=n_valid,
+            gi_col=schedule.shape[1] - 2,
         ),
-        in_specs=_tile_pair_specs(bp, D, 0, 1),
-        out_specs=[
-            pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
-            pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
-            jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
-        ],
-        columns=("i", "j"),
-    )
-
-
-def _tile_pair_specs(bp: int, D: int, ci: int, cj: int):
-    """Block specs of the (x, xT) operand pair: the i tile (bp, D) from
-    schedule column ``ci``, the feature-major j tile (D, bp) from ``cj``."""
-    return (
-        pl.BlockSpec((bp, D), lambda s, sr: (sr[s, ci], 0)),
-        pl.BlockSpec((D, bp), lambda s, sr: (0, sr[s, cj])),
-    )
-
-
-def _join_rows_kernel(
-    sched_ref, xi_ref, xjT_ref, hi_out, *, eps2: float, n_valid: int | None,
-    gi_col: int, gj_col: int,
-):
-    s = pl.program_id(0)
-    hit = _hit_tile(
-        xi_ref[...], xjT_ref[...], sched_ref[s, gi_col], sched_ref[s, gj_col],
-        eps2=eps2, n_valid=n_valid,
-    )
-    hi_out[0] = _hit_sums(hit)[0]
-
-
-def simjoin_hits_rows_program(
-    schedule, *, eps: float, bp: int, D: int, n_valid: int | None,
-    halo: bool = False,
-) -> CurveProgram:
-    """Pass-1 declaration emitting ONLY the per-step row sums — whose
-    totals pick the tiles pair emission visits.  The sharded wrapper uses this instead
-    of :func:`simjoin_hits_program` so the shard_map never materialises
-    (or transfers) the unused column partials.
-
-    Operands: the points and their transpose.  ``halo=False``: 2-col
-    ``(i, j)`` schedule over one global point buffer.  ``halo=True``:
-    4-col ``(i_slot, j_slot, i, j)`` schedule over a shard's
-    resident+halo buffer — the *slot* columns drive the
-    BlockSpec index maps (where a tile lives in the local buffer), the
-    *global* tile ids drive :func:`_hit_tile`'s diagonal strictness and
-    ragged-N masking, which are defined on global point indices.
-    """
-    steps = schedule.shape[0]
-    gi_col, gj_col = (2, 3) if halo else (0, 1)
-    return CurveProgram(
-        name="simjoin_hits_rows",
-        schedule=schedule,
-        kernel=functools.partial(
-            _join_rows_kernel, eps2=float(eps) ** 2, n_valid=n_valid,
-            gi_col=gi_col, gj_col=gj_col,
-        ),
-        in_specs=_tile_pair_specs(bp, D, 0, 1),
+        in_specs=_tile_pair_specs(bp, D),
         out_specs=pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
-        columns=("i_slot", "j_slot", "i", "j") if halo else ("i", "j"),
+        columns=("i_slot", "j_slot", "i", "j")[4 - schedule.shape[1]:],
     )
 
 
@@ -311,11 +292,12 @@ def simjoin_totals(
     ``start`` and ``live`` are traced, so every block of one size is one
     compiled program, and a short last block costs only its own steps."""
     block = jax.lax.dynamic_slice_in_dim(schedule, start, steps, axis=0)
-    hits_i, _ = simjoin_tile_hits_swizzled(
-        block, x, eps=eps, bp=bp, n_valid=n_valid, interpret=interpret,
-        live=live,
+    program = dataclasses.replace(
+        simjoin_hits_program(block, eps=eps, bp=bp, D=x.shape[1], n_valid=n_valid),
+        grid=(live,),
     )
-    return jnp.where(jnp.arange(steps) < live, jnp.sum(hits_i, axis=1), 0)
+    hits = launch(program, x, x.T, interpret=interpret)[:, 0]
+    return jnp.where(jnp.arange(steps) < live, jnp.sum(hits, axis=1), 0)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "bp", "n_valid", "interpret"))
@@ -468,6 +450,37 @@ def pruned_schedule(
     return sched, kept
 
 
+def simjoin_inputs(x: jax.Array, eps: float, *, curve: str, bp: int,
+                   hilbert_order: bool):
+    """What every ε-join decides before its passes, single-chip or
+    sharded: ``(xp, bp, n_valid, perm, sched, kept)``.
+
+    With ``hilbert_order`` the points are put in their (cached) Hilbert
+    order ``perm`` (else ``perm`` is None).  ``bp`` is clamped to N, and
+    the points are zero-padded to whole tiles, ``xp``; the kernels mask
+    the pad rows by global index below ``n_valid`` (None: no padding),
+    since magic coordinates overflow f32 squared distances and zero rows
+    would ε-join each other.  ``(sched, kept)`` is the device-pruned
+    schedule of :func:`pruned_schedule`.  N must be positive."""
+    N = x.shape[0]
+    perm = None
+    if hilbert_order:
+        with span("simjoin.order"):
+            perm = hilbert_point_order_cached(x)
+            x = simjoin_permute(x, perm)
+    bp = min(bp, N)
+    pn = (-N) % bp
+    xp = jnp.pad(x, ((0, pn), (0, 0))) if pn else x
+    pt = xp.shape[0] // bp
+    n_valid = N if pn else None
+    with span("simjoin.schedule"):
+        sched, kept = pruned_schedule(
+            xp, eps=float(eps), bp=bp, n_valid=n_valid, curve=curve
+        )
+    count("simjoin.tri_pairs", pt * (pt + 1) // 2)
+    return xp, bp, n_valid, perm, sched, kept
+
+
 # ---------------------------------------------------------------------------
 # Pass 2: each hit tile's rows packed in the kernel, then flattened by tiles
 # ---------------------------------------------------------------------------
@@ -513,44 +526,39 @@ def _pack_rows(hit):
 
 def _emit_kernel(
     sched_ref, xi_ref, xjT_ref, ids_out, n_out, *, eps2: float,
-    n_valid: int | None, gi_col: int, gj_col: int, live_col: int,
+    n_valid: int | None, gi_col: int,
 ):
     s = pl.program_id(0)
     hit = _hit_tile(
-        xi_ref[...], xjT_ref[...], sched_ref[s, gi_col], sched_ref[s, gj_col],
-        eps2=eps2, n_valid=n_valid,
+        xi_ref[...], xjT_ref[...], sched_ref[s, gi_col],
+        sched_ref[s, gi_col + 1], eps2=eps2, n_valid=n_valid,
     )
-    hit = jnp.logical_and(hit, sched_ref[s, live_col] == 1)
+    hit = jnp.logical_and(hit, sched_ref[s, gi_col + 2] == 1)
     _, bias = _id_code(hit.shape[1])
     ids_out[0] = (_pack_rows(hit) - bias).astype(ids_out.dtype)
-    n_out[0] = _hit_sums(hit)[0]
+    n_out[0] = _hit_rows(hit)
 
 
 def simjoin_emit_program(
     table, *, eps: float, bp: int, D: int, n_valid: int | None,
-    halo: bool = False, choice=None,
 ) -> CurveProgram:
     """Pass-2 declaration: per table row, its tile's hit rows packed
     (``(bp, bp)`` column ids, :func:`_id_code`) and their hit counts
-    (a ``(1, bp)`` int32 row), each block written once.
-    ``halo=False``: 3-col rows ``(i, j, live)`` over one point buffer;
-    ``halo=True``: 5-col rows ``(i_slot, j_slot, i, j, live)`` over a
-    shard's resident+halo buffer (slots drive the index maps, global ids
-    the hit predicate).  ``live == 0`` rows are SPMD / bucket padding
-    and count no hits.  Operands: the points and their transpose."""
-    if choice is not None:
-        choice = as_choice(choice, kind="triangle").with_(block=(int(bp),))
+    (a ``(1, bp)`` int32 row), each block written once.  A 3-column
+    table ``(i, j, live)`` runs over one point buffer, a 5-column one
+    ``(i_slot, j_slot, i, j, live)`` over a shard's resident+halo buffer
+    (slots drive the index maps, global ids the hit predicate).
+    ``live == 0`` rows are SPMD / bucket padding and count no hits.
+    Operands: the points and their transpose."""
     steps = table.shape[0]
-    gi_col, gj_col, live_col = (2, 3, 4) if halo else (0, 1, 2)
     return CurveProgram(
-        name="simjoin_emit_halo" if halo else "simjoin_emit",
+        name="simjoin_emit",
         schedule=table,
-        choice=choice,
-        kernel=functools.partial(
+        kernel=functools.partial(  # ..., i, j, live
             _emit_kernel, eps2=float(eps) ** 2, n_valid=n_valid,
-            gi_col=gi_col, gj_col=gj_col, live_col=live_col,
+            gi_col=table.shape[1] - 3,
         ),
-        in_specs=_tile_pair_specs(bp, D, 0, 1),
+        in_specs=_tile_pair_specs(bp, D),
         out_specs=[
             pl.BlockSpec((1, bp, bp), lambda s, sr: (s, 0, 0)),
             pl.BlockSpec((1, 1, bp), lambda s, sr: (s, 0, 0)),
@@ -559,10 +567,7 @@ def simjoin_emit_program(
             jax.ShapeDtypeStruct((steps, bp, bp), _id_code(bp)[0]),
             jax.ShapeDtypeStruct((steps, 1, bp), jnp.int32),
         ],
-        columns=(
-            ("i_slot", "j_slot", "i", "j", "live") if halo
-            else ("i", "j", "live")
-        ),
+        columns=("i_slot", "j_slot", "i", "j", "live")[5 - table.shape[1]:],
     )
 
 

@@ -402,8 +402,8 @@ class StreamSimJoin:
     2. per cohort tile, the resident candidate rows are the tile's own
        key interval plus the foreign intervals of
        :func:`~repro.core.neighbors.halo_ranges` (radius = ε in cell
-       widths + quantisation slack, coarsened like the sharded join's
-       ``_tile_reach``) — located in the sorted resident index by
+       widths + quantisation slack, coarsened d levels at a time until
+       it spans a few cells) — located in the sorted resident index by
        ``searchsorted``;
     3. a bbox-pruned lower-triangle tile-pair schedule restricted to
        tiles that touch the cohort feeds
@@ -533,8 +533,8 @@ class StreamSimJoin:
         nb = min(self.nbits0, cap)
         lo, hi = lo[:d], hi[:d]
         span = np.maximum(hi - lo, 1e-9)
-        # ε in cell widths + half-cell quantisation slack — the sharded
-        # join's _tile_reach radius, on the service's fixed grid
+        # ε in cell widths + half-cell quantisation slack, on the
+        # service's fixed grid
         radius = self.eps * float((((1 << nb) - 1) / span).max()) + 0.5
         s = 0
         while nb - s > d and radius / (1 << s) > 4.0:

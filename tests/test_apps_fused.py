@@ -20,7 +20,7 @@ from repro.kernels.pallas_compat import PallasCallCounter
 from repro.kernels.simjoin import (
     simjoin_compact,
     simjoin_emit_swizzled,
-    simjoin_tile_hits_swizzled,
+    simjoin_totals,
 )
 
 RNG = np.random.default_rng(99)
@@ -203,7 +203,7 @@ class TestSimjoinPairs:
 
     def test_two_pass_dispatch_count(self):
         x = jnp.asarray(RNG.normal(size=(128, 3)) * 0.5, jnp.float32)
-        simjoin_tile_hits_swizzled.clear_cache()
+        simjoin_totals.clear_cache()
         simjoin_emit_swizzled.clear_cache()
         simjoin_compact.clear_cache()
         with PallasCallCounter() as spy:

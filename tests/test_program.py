@@ -217,14 +217,14 @@ class TestVmemBudget:
         from repro.kernels.simjoin import (
             simjoin_compact,
             simjoin_emit_swizzled,
-            simjoin_tile_hits_swizzled,
+            simjoin_totals,
         )
 
         x = jnp.asarray(RNG.normal(size=(50, 3)) * 0.6, jnp.float32)
         want = ref.simjoin_pairs(x, 0.8)
         old = set_vmem_budget(64)
         try:
-            simjoin_tile_hits_swizzled.clear_cache()
+            simjoin_totals.clear_cache()
             simjoin_emit_swizzled.clear_cache()
             simjoin_compact.clear_cache()
             with PallasCallCounter() as spy:
@@ -281,7 +281,7 @@ class TestCacheRegistry:
         from repro.kernels import sharded
 
         assert sharded._lloyd_fn in _REGISTERED_CACHES
-        assert sharded._join_pass1_fn in _REGISTERED_CACHES
+        assert sharded._halo_pass1_fn in _REGISTERED_CACHES
         assert sharded._join_pass2_fn in _REGISTERED_CACHES
 
     def test_register_rejects_non_caches(self):

@@ -92,12 +92,10 @@ def test_join_counters_match_the_inputs(points):
     }
 
 
-@pytest.mark.parametrize("halo", [False, True], ids=["replicated", "halo"])
-def test_sharded_join_counts_as_the_single_core_one(points, halo):
-    """The sharded join's counters on a one-device mesh: the same pairs,
-    live tiles and compaction as one core; its pass 1 visits the whole
-    triangle the single core prunes when replicated, and no more than
-    it under the halo's reach pruning."""
+def test_sharded_join_counts_as_the_single_core_one(points):
+    """The sharded join's counters on a one-device mesh: the same
+    triangle, kept tile pairs, pairs, live tiles and compaction as one
+    core, whose order, padding and pruned schedule it takes."""
     from repro.kernels.sharded import simjoin_pairs_sharded
     from repro.launch.mesh import make_app_mesh
 
@@ -106,13 +104,9 @@ def test_sharded_join_counts_as_the_single_core_one(points, halo):
     ops.simjoin_pairs(x, EPS, bp=BP)
     single = _delta(before)
     before = counters()
-    simjoin_pairs_sharded(x, EPS, mesh=make_app_mesh(1), bp=BP, halo=halo)
+    simjoin_pairs_sharded(x, EPS, mesh=make_app_mesh(1), bp=BP)
     d = _delta(before)
-    steps = d.pop("simjoin.tile_pairs")
-    tri = single.pop("simjoin.tri_pairs")
-    assert single.pop("simjoin.tile_pairs") <= steps <= tri
-    if not halo:
-        assert steps == (N // BP) * (N // BP + 1) // 2 == tri
+    assert d["simjoin.tile_pairs"] == single["simjoin.tile_pairs"] == _box_pairs(points)
     for name in ("simjoin.joins", "simjoin.pass1_steps", "simjoin.pass1_blocks",
                  "simjoin.pass2_blocks"):
         single.pop(name)
